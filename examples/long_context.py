@@ -30,7 +30,7 @@ def main():
     except RuntimeError:
         pass
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.parallel.ring_attention import (
@@ -67,7 +67,7 @@ def main():
         lambda a, b, c: ulysses_attention(a, b, c, axis_name="sp",
                                           causal=True),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-        check_rep=False)(q, k, v))
+        check_vma=False)(q, k, v))
     run("zigzag", lambda: zigzag_ring_attention_sharded(q, k, v, mesh))
     print(f"OK: three sequence-parallel modes agree at S={S} "
           f"across {n} devices")

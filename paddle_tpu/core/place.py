@@ -22,10 +22,20 @@ class Place:
         return self._device_id
 
     def jax_device(self):
+        """The jax device this place names.  A place is something the
+        caller asked for explicitly, so a kind this host does not have,
+        or an index past its last device, is an error — never another
+        device handed back quietly."""
         devs = [d for d in jax.devices() if self._matches(d)]
         if not devs:
-            devs = jax.devices()
-        return devs[min(self._device_id, len(devs) - 1)]
+            raise RuntimeError(
+                f"{self!r}: this host has no {self._kind} device (jax "
+                f"found {sorted({d.platform for d in jax.devices()})})")
+        if not 0 <= self._device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: device index {self._device_id} is past the "
+                f"last of this host's {len(devs)} {self._kind} device(s)")
+        return devs[self._device_id]
 
     def _matches(self, d) -> bool:
         return True
@@ -76,17 +86,19 @@ def set_device(device):
     """paddle.set_device: 'cpu', 'tpu', 'tpu:0', 'gpu:0' (alias of tpu)."""
     global _current_device
     if isinstance(device, Place):
-        _current_device = device
-        return device
-    name, _, idx = str(device).partition(":")
-    idx = int(idx) if idx else 0
-    if name in ("cpu",):
-        _current_device = CPUPlace()
-    elif name in ("tpu", "gpu", "cuda", "xpu", "npu"):
-        _current_device = TPUPlace(idx)
+        place = device
     else:
-        raise ValueError(f"unknown device {device!r}")
-    return _current_device
+        name, _, idx = str(device).partition(":")
+        idx = int(idx) if idx else 0
+        if name in ("cpu",):
+            place = CPUPlace()
+        elif name in ("tpu", "gpu", "cuda", "xpu", "npu"):
+            place = TPUPlace(idx)
+        else:
+            raise ValueError(f"unknown device {device!r}")
+    place.jax_device()  # an explicitly named device must exist: raises
+    _current_device = place
+    return place
 
 
 def get_device():

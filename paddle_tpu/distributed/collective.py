@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.mesh import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 import numpy as np
 
 from ..core.tensor import Tensor
@@ -185,10 +185,10 @@ def _collective_1d(x, op):
     """
     mesh = _mesh_1d()
     axis = mesh.axis_names[0]
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     f = shard_map(op, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     return f(x)
 
 
@@ -354,10 +354,10 @@ def barrier(group=None):
     entered the collective, which IS the barrier on ICI."""
     mesh = _mesh_1d()
     axis = mesh.axis_names[0]
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     f = shard_map(lambda x: jax.lax.psum(x, axis), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+                  in_specs=P(), out_specs=P(), check_vma=False)
     jax.block_until_ready(f(jnp.zeros((), jnp.int32)))
 
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ... import optimizer as opt_mod
-from ...parallel.mesh import make_mesh, set_mesh
+from ...parallel.mesh import make_mesh, mesh_guard, set_mesh
 
 
 def wrap_optimizer(fleet_obj, optimizer, strategy):
@@ -146,6 +146,12 @@ def build_hybrid_train_step(strategy, loss_fn, optimizer, mesh=None,
         if strategy.gradient_merge else 1
 
     def step(params, opt_state, batch, key):
+        # tracing under the mesh lets the attention op shard_map its
+        # Pallas kernel over (dp, mp): GSPMD cannot partition a kernel
+        with mesh_guard(mesh):
+            return _step(params, opt_state, batch, key)
+
+    def _step(params, opt_state, batch, key):
         if k_steps > 1:
             # micro-batch accumulation via scan (gradient_merge)
             def micro(accum, mb):
@@ -206,10 +212,15 @@ def build_hybrid_train_step(strategy, loss_fn, optimizer, mesh=None,
             lambda x: NamedSharding(mesh, P("dp", *([None] * (x.ndim - 1)))),
             batch)
         s_sh = None
-        if opt_state is not None and slot_sharding_fn is not None:
-            s_sh = jax.tree_util.tree_map(slot_sharding_fn, opt_state)
-        # pin outputs to the stage contract — otherwise XLA may propagate
-        # the slot sharding onto the (donated) replicated params
+        if opt_state is not None:  # slots: the ZeRO spec, else replicated
+            s_sh = jax.tree_util.tree_map(
+                slot_sharding_fn
+                or (lambda v: NamedSharding(mesh, P())), opt_state)
+        # pin outputs to the input contract, so the step can be fed its
+        # own outputs — otherwise XLA picks output shardings (on four
+        # chips it split out_proj.weight over mp after a shard_mapped
+        # attention) and the next call re-compiles or, compiled ahead of
+        # time, refuses its arguments
         out_sh = None if s_sh is None else (None, p_sh, s_sh)
         return jax.jit(step,
                        in_shardings=(p_sh, s_sh, b_sh, None),
@@ -232,7 +243,7 @@ def _build_explicit_dp_step(strategy, loss_fn, optimizer, mesh):
     allreduce moves only the top (1-sparsity) gradient entries; the residual
     stays in a per-worker error buffer folded into the next step.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     wrapped_loss = apply_strategy(strategy, loss_fn)
     dp = mesh.shape["dp"]
@@ -332,7 +343,7 @@ def _build_explicit_dp_step(strategy, loss_fn, optimizer, mesh):
             in_specs=(pi_spec(params), pi_spec(inner), err_spec, rep,
                       b_spec, rep),
             out_specs=(rep, pi_spec(params), pi_spec(inner), err_spec),
-            check_rep=False)(params, inner, err, ct, batch, key)
+            check_vma=False)(params, inner, err, ct, batch, key)
         return loss, new_p, {"inner": new_s, "dgc_err": new_err,
                              "step": ct + 1}
 

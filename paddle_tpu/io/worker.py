@@ -62,6 +62,10 @@ def _worker_loop(dataset, collate_fn, index_queue, result_queue, worker_id,
     None sentinel until loader shutdown); iterable-style waits for an
     epoch token per epoch instead of exiting after one pass."""
     global _worker_info
+    # a worker never takes the accelerator: the parent owns the chip, so
+    # any array a collate_fn builds here lives on the CPU backend
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     _worker_info = WorkerInfo(id=worker_id, num_workers=num_workers,
                               seed=base_seed + worker_id, dataset=dataset)
     _seed_worker(worker_id, base_seed)
@@ -76,11 +80,15 @@ def _worker_loop(dataset, collate_fn, index_queue, result_queue, worker_id,
         from .native_loader import _serialize_batch
         data = _serialize_batch(batch)
         if use_shared_memory:
-            from multiprocessing import shared_memory
+            from multiprocessing import resource_tracker, shared_memory
             shm = shared_memory.SharedMemory(create=True, size=len(data))
+            # the parent attaches + unlinks: hand it the ownership, or
+            # this worker's resource tracker unlinks the segment when the
+            # worker exits ahead of a slow consumer (a first-step compile)
+            resource_tracker.unregister(shm._name, "shared_memory")
             shm.buf[:len(data)] = data
             result_queue.put(("shm", batch_idx, shm.name, len(data)))
-            shm.close()  # parent attaches + unlinks
+            shm.close()
         else:
             result_queue.put(("data", batch_idx, data))
 
@@ -199,17 +207,30 @@ class _ByteChannel:
             self._lib.ptq_destroy(self._q)
 
 
+def _parent_holds_accelerator():
+    """True once this process has initialised a JAX backend other than
+    the CPU's.  The device client's threads are then live, a forked child
+    inherits their locks mid-flight and wedges, and a chip belongs to one
+    process anyway."""
+    import sys
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge  # no public spelling in jax 0.9
+    return xla_bridge.backends_are_initialized() \
+        and jax.default_backend() != "cpu"
+
+
 def _mp_context():
     import multiprocessing as mp
     method = os.environ.get("PADDLE_TPU_MP_START")
     if method:
         return mp.get_context(method)
-    # fork is fast and fine for numpy datasets; spawn-safe code paths are
-    # kept (everything pickled is module-level)
-    try:
-        return mp.get_context("fork")
-    except ValueError:  # pragma: no cover - non-posix
-        return mp.get_context("spawn")
+    # fork is fast and fine for numpy datasets under a CPU-only parent;
+    # a parent that holds an accelerator spawns (everything pickled is
+    # module-level, and `_worker_loop` pins the child to the CPU)
+    return mp.get_context(
+        "spawn" if _parent_holds_accelerator() else "fork")
 
 
 class MultiprocessLoaderIter:

@@ -183,7 +183,7 @@ def build_hybrid_gpt2_loss(mesh, num_microbatches=2, ring_impl=None,
     `pp_schedule`: "gpipe" or "interleaved" (circular; each pp rank holds
     `num_virtual` non-adjacent layer chunks — parallel/pipeline.py).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ..parallel.pipeline import (pipeline_apply,
                                      pipeline_apply_interleaved)
@@ -203,8 +203,7 @@ def build_hybrid_gpt2_loss(mesh, num_microbatches=2, ring_impl=None,
             # zigzag layout: this rank holds global chunks (i, 2n-1-i) of
             # 2n — position embeddings must follow the SAME permutation
             # the caller applied to the batch (zigzag_order)
-            from ..parallel.mesh import axis_size
-            n_sp = axis_size(sp_axis)
+            n_sp = jax.lax.axis_size(sp_axis)
             half = s_l // 2
             pos = jnp.concatenate(
                 [sp_idx * half + jnp.arange(half),
@@ -322,7 +321,7 @@ def build_hybrid_gpt2_loss(mesh, num_microbatches=2, ring_impl=None,
             inner, mesh=mesh,
             in_specs=(specs, data_spec, data_spec),
             out_specs=P(),
-            check_rep=False)(params_in, batch["input_ids"],
+            check_vma=False)(params_in, batch["input_ids"],
                              batch["labels"])
 
     return loss_fn

@@ -49,7 +49,7 @@ def build_pp_train_step(cfg: GPT2Config, mesh, num_microbatches=4,
     parallel/pipeline.py for the schedules and their bubble fractions)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core import rng as rng_mod
@@ -150,7 +150,7 @@ def build_pp_train_step(cfg: GPT2Config, mesh, num_microbatches=4,
             spec_stk = jax.tree_util.tree_map(lambda _: P(pp_axis), stacked)
         h = shard_map(inner, mesh=mesh,
                       in_specs=(spec_stk, P(), P()),
-                      out_specs=P(), check_rep=False)(
+                      out_specs=P(), check_vma=False)(
             stacked_in, x0, batch["labels"])
         return head_loss(other, h, batch["labels"])
 

@@ -120,12 +120,19 @@ class CompileTracker:
         return False
 
     # -- recording -------------------------------------------------------
-    def record(self, program, dur_s, shard="none", in_flight=None):
+    def record(self, program, dur_s, shard="none", in_flight=None,
+               lower=None):
+        """`lower`: zero-arg callable that re-lowers the program that
+        just compiled, from its argument SHAPES, to a
+        `jax.stages.Lowered` — how a chip run proves which kernels are IN
+        a dispatched program (count `tpu_custom_call` in
+        `ev["lower"]().compile().as_text()`) instead of inferring it from
+        a shape gate."""
         if in_flight is None:
             in_flight = self.in_flight()
         ev = {"program": program, "dur_s": float(dur_s),
               "in_flight": bool(in_flight), "shard": shard,
-              "ts": time.perf_counter()}
+              "ts": time.perf_counter(), "lower": lower}
         with self._lock:
             self._total += 1
             if ev["in_flight"]:
@@ -190,12 +197,35 @@ class CompileTracker:
             t0 = time.perf_counter()
             out = fn(*args, **kw)
             if cache_size() > n0:
-                tracker.record(program, time.perf_counter() - t0, shard)
+                tracker.record(program, time.perf_counter() - t0, shard,
+                               lower=_lowerer(fn, args, kw))
             return out
 
         wrapped.__name__ = getattr(fn, "__name__", program)
         wrapped.__wrapped__ = fn
         return wrapped
+
+
+def _lowerer(fn, args, kw):
+    """() -> Lowered for `fn` at the shapes (and shardings) of this
+    call.  Holds ShapeDtypeStructs, never the buffers: the call may
+    have donated them."""
+    import functools
+
+    import jax
+
+    def spec(a):
+        if isinstance(a, jax.Array):
+            # an uncommitted array's placement is the default one; naming
+            # it would change the module and miss the cache entry the
+            # dispatch just wrote
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if a.committed else None)
+        return a
+
+    args, kw = jax.tree.map(spec, (args, kw))
+    return functools.partial(fn.lower, *args, **kw)
 
 
 # ---- process-wide default tracker ---------------------------------------
@@ -224,3 +254,4 @@ def count_since(m, in_flight=None):
 
 def events_since(m):
     return TRACKER.events_since(m)
+

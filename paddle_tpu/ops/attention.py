@@ -12,30 +12,67 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec, get_abstract_mesh
 
 from ._registry import defop
 
 
 def _on_tpu():
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
-_flash_fallback_seen = set()
+def _manual_mesh(mesh):
+    """The mesh a Pallas call has to be `shard_map`ped over, or None
+    when the call already runs per device.  Mosaic kernels cannot be
+    partitioned by GSPMD, so under a multi-device `jit` the kernel runs
+    inside a shard_map with heads split over `mp` (and, in training,
+    batch over `dp`); inside an enclosing shard_map the axes are manual
+    already and the operands are the local shards."""
+    if mesh is None or mesh.size == 1 \
+            or get_abstract_mesh().manual_axes:
+        return None
+    return mesh
 
 
-def _warn_flash_fallback(e):
-    """A silent flash→XLA fallback hid a dead kernel path for three rounds;
-    warn once per exception type so it can never hide again."""
-    key = type(e).__name__
-    if key not in _flash_fallback_seen:
-        _flash_fallback_seen.add(key)
-        import warnings
-        warnings.warn(
-            f"flash attention fell back to XLA attention: {key}: "
-            f"{str(e)[:200]}", RuntimeWarning, stacklevel=3)
+def paged_attention_path(head_dim, block_size, num_heads,
+                         total_tokens=None, mesh=None):
+    """Which implementation the paged-pool attention ops take for these
+    shapes on this backend: "pallas", "pallas/shard_map" (the kernel
+    per device, heads split over the mesh's mp axis) or "xla" (the
+    gather path).  Chosen by the platform and the kernel's shape gate
+    alone — a lowering error in the chosen path raises, it never
+    selects another path."""
+    from .pallas.unified_attention import supported_shapes
+
+    if not _on_tpu():
+        return "xla"
+    mesh = _manual_mesh(mesh)
+    mp = 1 if mesh is None else dict(mesh.shape).get("mp", 1)
+    if num_heads % mp or not supported_shapes(
+            head_dim, block_size, num_heads // mp, total_tokens):
+        return "xla"
+    return "pallas" if mesh is None else "pallas/shard_map"
+
+
+def _paged_kernel(kernel, mesh, q, k_blocks, v_blocks, *rest, **kw):
+    """Run a paged-pool Pallas `kernel(q, k_blocks, v_blocks, *rest)`;
+    with a mesh, per device under shard_map: q/out [T, H, Dh] and the
+    pools split on heads over mp, the int32 steering arrays
+    replicated."""
+    mesh = _manual_mesh(mesh)
+    fn = functools.partial(kernel, **kw)
+    if mesh is None:
+        return fn(q, k_blocks, v_blocks, *rest)
+    heads = PartitionSpec(None, "mp", None)
+    # dense blocks / int8 codes [N, BS, H, Dh] and, for an int8 pool, the
+    # per-vector scales [N, BS, H]: the same pytree as the pool
+    pool = jax.tree.map(
+        lambda a: PartitionSpec(None, None, "mp", *[None] * (a.ndim - 3)),
+        k_blocks)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(heads, pool, pool) + (PartitionSpec(),) * len(rest),
+        out_specs=heads, check_vma=False)(q, k_blocks, v_blocks, *rest)
 
 
 def _xla_attention(q, k, v, mask=None, scale=None, causal=False):
@@ -85,31 +122,44 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                  and not return_weights and q.shape[-2] >= 128
                  and q.shape[-1] in (32, 64, 128, 256)
                  and q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0)
+    mesh = None
     if use_flash:
-        try:
-            from .pallas.flash_attention import (flash_attention,
-                                                 flash_attention_bias)
-            # prescale Q once ([B,H,S,D] pass) instead of scaling every
-            # score tile in fwd + bwd recompute (S^2-proportional VPU work);
-            # the chain rule through the prescale restores dq's scale
-            sc = (q.shape[-1] ** -0.5) if scale is None else scale
-            # pallas_call abstractification rejects Tensor wrappers (JAX
-            # dropped __jax_array__ support there), while plain jnp ops
-            # accept them — unwrap, or the grad trace silently loses the
-            # kernel (it did for three rounds: fwd had 12 tpu_custom_calls,
-            # fwd+bwd had ZERO)
-            from ._registry import raw
-            qv, kv, vv = raw(q), raw(k), raw(v)
-            if key_bias is None:
-                out = flash_attention((qv * sc).astype(qv.dtype), kv, vv,
-                                      causal=is_causal, scale=1.0)
-            else:
-                out = flash_attention_bias(
-                    (qv * sc).astype(qv.dtype), kv, vv, raw(key_bias),
-                    causal=is_causal, scale=1.0)
-            return out, None
-        except Exception as e:  # noqa: BLE001
-            _warn_flash_fallback(e)
+        from ..parallel.mesh import current_mesh
+        mesh = _manual_mesh(current_mesh())
+        if mesh is not None:
+            # the canonical (dp, mp) axes of parallel/mesh.py, and whole
+            # batches and heads per device; any other mesh takes the XLA
+            # path, which GSPMD can partition
+            use_flash = ({"dp", "mp"} <= set(mesh.axis_names)
+                         and q.shape[0] % mesh.shape["dp"] == 0
+                         and q.shape[1] % mesh.shape["mp"] == 0)
+    if use_flash:
+        from .pallas.flash_attention import (flash_attention,
+                                             flash_attention_bias)
+        # prescale Q once ([B,H,S,D] pass) instead of scaling every
+        # score tile in fwd + bwd recompute (S^2-proportional VPU work);
+        # the chain rule through the prescale restores dq's scale
+        sc = (q.shape[-1] ** -0.5) if scale is None else scale
+        # pallas_call abstractification rejects Tensor wrappers (JAX
+        # dropped __jax_array__ support there), while plain jnp ops
+        # accept them — unwrap, or the grad trace loses the kernel
+        from ._registry import raw
+        qv, kv, vv = raw(q), raw(k), raw(v)
+        args = ((qv * sc).astype(qv.dtype), kv, vv)
+        bh = PartitionSpec("dp", "mp", None, None)
+        if key_bias is None:
+            fn = functools.partial(flash_attention, causal=is_causal,
+                                   scale=1.0)
+            specs = (bh,) * 3
+        else:
+            fn = functools.partial(flash_attention_bias, causal=is_causal,
+                                   scale=1.0)
+            args += (raw(key_bias),)
+            specs = (bh,) * 3 + (PartitionSpec("dp", None),)
+        if mesh is not None:  # batch over dp, heads over mp, per device
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                               out_specs=specs[0], check_vma=False)
+        return fn(*args), None
     out, w = _xla_attention(q, k, v, attn_mask, scale, is_causal)
     if dropout_p > 0.0:
         keep = jax.random.bernoulli(key, 1.0 - dropout_p, w.shape)
@@ -125,7 +175,7 @@ def _is_quantized_kv(kv):
 
 
 def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
-                           scale=None):
+                           scale=None, mesh=None):
     """Single-token decode attention over a PAGED KV cache (the
     gather-by-block-table read half of inference/kv_cache.py).
 
@@ -141,28 +191,27 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
         everything at position >= ctx_len is masked by LENGTH, never by
         pad-token value.
 
+    mesh: the device mesh of a sharded engine whose pool is split on
+        heads over `mp` (see `_paged_kernel`); None on one device.
+
     Returns [B, H, Dh] in q's dtype. Dispatches to the Pallas ragged
-    kernel on TPU when shapes allow (head_dim lane-sized, block_size a
-    lane multiple, heads sublane-aligned); otherwise runs the XLA gather
-    path, which materializes the [B, M*BS] gathered keys — correct
-    everywhere, but it reads the padded table width instead of streaming
-    exactly the live blocks."""
+    kernel on TPU when shapes allow (`paged_attention_path`: head_dim
+    lane-sized, block_size a lane multiple, per-device heads
+    sublane-aligned); otherwise runs the XLA gather path, which
+    materializes the [B, M*BS] gathered keys — correct everywhere, but
+    it reads the padded table width instead of streaming exactly the
+    live blocks."""
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     B, H, Dh = q.shape
     _, BS, _, _ = kcodes.shape
     M = block_tables.shape[1]
     sc = (Dh ** -0.5) if scale is None else scale
-    if _on_tpu():
-        try:
-            from .pallas.paged_attention import (paged_decode_attention_kernel,
-                                                 supported_shapes)
-            if supported_shapes(Dh, BS, H):
-                return paged_decode_attention_kernel(
-                    q, k_blocks, v_blocks, block_tables, ctx_lens,
-                    scale=float(sc))
-        except Exception as e:  # noqa: BLE001
-            _warn_flash_fallback(e)
+    if paged_attention_path(Dh, BS, H, mesh=mesh) != "xla":
+        from .pallas.unified_attention import paged_decode_attention_kernel
+        return _paged_kernel(paged_decode_attention_kernel, mesh, q,
+                             k_blocks, v_blocks, block_tables, ctx_lens,
+                             scale=float(sc))
     if quant:
         # gather CODES + per-vector scales; the int8->dt convert fuses
         # into the einsum operand pipeline (the weight-dot ::w8c trick)
@@ -196,7 +245,7 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
 
 
 def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
-                             scale=None, allow_pallas=True):
+                             scale=None, allow_pallas=True, mesh=None):
     """Packed ragged prefill attention over a PAGED KV cache: every token
     of a token-packed multi-sequence stream attends its OWN sequence's
     cache positions [0, pos] — both the K/V this chunk just wrote and
@@ -214,7 +263,7 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
         packing-pad token (its output is garbage the caller discards).
 
     Returns [T, H, Dh] in q's dtype. On TPU with aligned shapes this
-    dispatches to the Pallas kernel (ops/pallas/ragged_prefill.py),
+    dispatches to the Pallas kernel (ops/pallas/unified_attention.py),
     which additionally requires the PACKING CONTRACT: each segment's
     packed region starts at a multiple of Q_TILE=128, so one query tile
     never mixes segments.
@@ -234,7 +283,9 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
     mask before a joint softmax over all rows — exactly the per-row
     softmax, because only the query's own row has unmasked columns.
 
-    allow_pallas=False forces the XLA fallback even on TPU: the
+    mesh: as in `paged_decode_attention`.
+
+    allow_pallas=False forces the XLA path even on TPU: the
     sequence-parallel packed trunk (long-context round) runs with
     sp-sharded queries under GSPMD, where a pallas_call is an opaque
     per-device program — the sp-local stream-kernel wiring (tile_base
@@ -246,16 +297,12 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
     _, BS, _, _ = kcodes.shape
     B, M = block_tables.shape
     sc = (Dh ** -0.5) if scale is None else scale
-    if allow_pallas and _on_tpu():
-        try:
-            from .pallas.unified_attention import (
-                Q_TILE, supported_shapes, unified_ragged_attention_kernel)
-            if supported_shapes(Dh, BS, H, T):
-                return unified_ragged_attention_kernel(
-                    q, k_blocks, v_blocks, block_tables,
-                    seg[::Q_TILE], pos[::Q_TILE], scale=float(sc))
-        except Exception as e:  # noqa: BLE001
-            _warn_flash_fallback(e)
+    if allow_pallas and paged_attention_path(Dh, BS, H, T, mesh) != "xla":
+        from .pallas.unified_attention import (
+            Q_TILE, unified_ragged_attention_kernel)
+        return _paged_kernel(unified_ragged_attention_kernel, mesh, q,
+                             k_blocks, v_blocks, block_tables,
+                             seg[::Q_TILE], pos[::Q_TILE], scale=float(sc))
     # row-gather, head-major, joint-row softmax
     if quant:
         k = kcodes[block_tables].reshape(B, M * BS, H, Dh) \
@@ -289,7 +336,7 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
 
 
 def unified_stream_attention(q, k_blocks, v_blocks, block_tables, seg,
-                             pos, scale=None):
+                             pos, scale=None, mesh=None):
     """Unified serving-round attention (one-kernel round, r16): score a
     single packed token stream containing MIXED prefill chunks, plain
     decode rows and speculative verify regions in one launch.
@@ -308,11 +355,11 @@ def unified_stream_attention(q, k_blocks, v_blocks, block_tables, seg,
     (`nn.decode` `unified_round`); the argument contract is exactly
     `ragged_prefill_attention`'s."""
     return ragged_prefill_attention(q, k_blocks, v_blocks, block_tables,
-                                    seg, pos, scale=scale)
+                                    seg, pos, scale=scale, mesh=mesh)
 
 
 def verify_window_attention(q, k_blocks, v_blocks, block_tables, pos,
-                            scale=None):
+                            scale=None, mesh=None):
     """Speculative-verification attention over a PAGED KV cache: a
     DENSE [P, W] window of queries per plan row (each row's last
     emitted token + its draft tokens, W pinned by the verify plan),
@@ -344,7 +391,7 @@ def verify_window_attention(q, k_blocks, v_blocks, block_tables, pos,
         seg = jnp.repeat(jnp.arange(P, dtype=jnp.int32), W)
         return ragged_prefill_attention(
             q.reshape(P * W, H, Dh), k_blocks, v_blocks, block_tables,
-            seg, pos.reshape(-1), scale=sc).reshape(P, W, H, Dh)
+            seg, pos.reshape(-1), scale=sc, mesh=mesh).reshape(P, W, H, Dh)
     if quant:
         k = kcodes[block_tables].reshape(P, M * BS, H, Dh) \
             .astype(q.dtype)

@@ -19,13 +19,7 @@ import os as _os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_TPU_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU_PALLAS = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -33,16 +27,8 @@ NEG_INF = -1e30
 
 
 def _compiler_params(semantics):
-    if not _HAS_TPU_PALLAS:
-        return {}
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return {}
-    try:
-        return {"compiler_params": cls(dimension_semantics=semantics)}
-    except Exception:
-        return {}
+    return {"compiler_params":
+            pltpu.CompilerParams(dimension_semantics=semantics)}
 
 
 LSE_LANES = 8  # lse/delta rows are broadcast over 8 sublanes to satisfy
@@ -244,7 +230,7 @@ def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret,
         kernel = functools.partial(kernel, has_bias=has_bias)
     ml_lanes = 128 if use_lanes else LSE_LANES
     mem_kwargs = {}
-    if _HAS_TPU_PALLAS and not interpret:
+    if not interpret:
         mem_kwargs = {"memory_space": pltpu.VMEM}
     in_specs = [
         pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0),
@@ -485,7 +471,7 @@ def _delta_rows(o3, do3, interpret):
     bh, sq, d = o3.shape
     bq = next((b for b in (512, 256, 128) if sq % b == 0), sq)
     mem_kwargs = {}
-    if _HAS_TPU_PALLAS and not interpret:
+    if not interpret:
         mem_kwargs = {"memory_space": pltpu.VMEM}
     row = pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0), **mem_kwargs)
     out = pl.BlockSpec((None, bq, LSE_LANES), lambda i, j: (i, j, 0),
@@ -510,7 +496,7 @@ def _flash_bwd_fused(q, k, v, o, lse, g, scale, causal, block_q, block_k,
     do3 = g.reshape(b * h, sq, d)
     delta3 = _delta_rows(o.reshape(b * h, sq, d), do3, interpret)
     mem_kwargs = {}
-    if _HAS_TPU_PALLAS and not interpret:
+    if not interpret:
         mem_kwargs = {"memory_space": pltpu.VMEM}
     scratch = [pltpu.VMEM((sq, d), jnp.float32)]
 
@@ -559,7 +545,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, block_q, block_k,
     lse3 = lse  # already [b*h, sq, LSE_LANES]
     delta3 = _delta_rows(o.reshape(b * h, sq, d), do3, interpret)
     mem_kwargs = {}
-    if _HAS_TPU_PALLAS and not interpret:
+    if not interpret:
         mem_kwargs = {"memory_space": pltpu.VMEM}
 
     nq, nk = sq // bq, sk // bk
@@ -671,7 +657,7 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
     # k/v/dk/dv column blocks. Budget the sq-proportional part (~10 bytes
     # per sq*d element) at 8MB of the ~16MB core; larger shapes take the
     # two-kernel path whose dkv pass pins only q/dO (no f32 accumulator).
-    if _HAS_TPU_PALLAS and q.shape[2] * q.shape[3] * 10 <= 8 * 1024 * 1024:
+    if q.shape[2] * q.shape[3] * 10 <= 8 * 1024 * 1024:
         return _flash_bwd_fused(q, k, v, out, lse, g, scale, causal, block_q,
                                 block_k, interpret)[:3]
     return _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
@@ -722,7 +708,7 @@ def _fab_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, bias, bias3, out, lse = res
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if _HAS_TPU_PALLAS and q.shape[2] * q.shape[3] * 10 <= 8 * 1024 * 1024:
+    if q.shape[2] * q.shape[3] * 10 <= 8 * 1024 * 1024:
         dq, dk, dv, db3 = _flash_bwd_fused(q, k, v, out, lse, g, scale,
                                            causal, block_q, block_k,
                                            interpret, bias3)

@@ -1,9 +1,7 @@
-"""Unified ragged paged attention — Pallas TPU kernels over the block
-pool, block-table driven (one-kernel serving round, r16).
+"""Unified ragged paged attention — the Pallas TPU kernel over the
+block pool, block-table driven (one-kernel serving round, r16).
 
-This module is the MERGE of the former `ragged_prefill.py` and
-`paged_attention.py` kernels (both files remain as thin re-export
-shims). It holds:
+It holds:
 
   * the STREAM kernel (`unified_ragged_attention_kernel`) — segment-
     causal attention for a token-packed multi-sequence stream where
@@ -14,13 +12,15 @@ shims). It holds:
     position) and a speculative verify region ([last_token,
     draft_1..k]) are all just ragged segments of the same stream, so a
     scheduler round mixing all three is ONE launch of this kernel;
-  * the DECODE kernel (`paged_decode_attention_kernel`) — the
-    one-token-per-sequence specialization (grid (B, M), heads on the
-    sublane axis) kept for the standalone `step`/offline paths, which
-    skips the stream kernel's query-tile alignment cost when every
-    sequence contributes exactly one token.
+  * the DECODE entry (`paged_decode_attention_kernel`) — the
+    one-token-per-sequence call of the standalone `step`/offline
+    paths: the same kernel body at a DECODE_TILE-row query tile.  (A
+    separate (B, M)-grid decode body passed interpret mode for twenty
+    PRs and was refused by the chip's compiler — Mosaic lowers no
+    head-batched dot whose left operand is a bare [H, Dh] — so it is
+    gone; PR 21.)
 
-Shared machinery (deduplicated here — the per-kernel copies are gone):
+Shared machinery:
 
   * `kv_operand_specs` — the scalar-prefetched block-index BlockSpec
     construction: the k/v (and int8 scale) index maps read
@@ -34,8 +34,6 @@ Shared machinery (deduplicated here — the per-kernel copies are gone):
     scales [N, BS, H]); dequantization happens HERE on the
     VMEM-resident block in flight, so a bf16 copy of the cache never
     exists in HBM.
-  * one online-softmax kernel body per query geometry instead of the
-    former dense/quant copy-pair per file (4 kernel bodies -> 2).
 
 Layout (matches inference/kv_cache.py):
     q:        [T, H, Dh] stream / [B, H, Dh] decode
@@ -68,8 +66,7 @@ are parity-tested against each other.
 Per (tile, kv-block) step the score tile is [H, QT, BS] from a
 head-batched dot over Dh; online-softmax state (m, l, acc) rides VMEM
 scratch across the M dimension exactly like flash_attention.py, with
-the extra QT query axis on the lanes (decode: QT folded away, row
-stats broadcast over STAT_LANES for (8, 128) tiling).
+the extra QT query axis on the lanes.
 """
 from __future__ import annotations
 
@@ -78,24 +75,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_TPU_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU_PALLAS = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-Q_TILE = 128    # stream query-tile (and packing alignment) size
-STAT_LANES = 8  # decode m/l row stats broadcast for (8, 128) tiling
+Q_TILE = 128     # stream query-tile (and packing alignment) size
+DECODE_TILE = 8  # query tile of a one-token decode row: one f32 sublane
+                 # group, the smallest tile Mosaic lowers the dots for
 
 
 def supported_shapes(head_dim, block_size, num_heads, total_tokens=None):
-    """Shape gate for the compiled TPU kernels (interpret mode takes
+    """Shape gate for the compiled TPU kernel (interpret mode takes
     any): head_dim lane-sized, block_size a lane multiple, heads
-    sublane-aligned; the stream kernel additionally requires the packed
-    length to be query-tile aligned."""
+    sublane-aligned; a packed stream additionally needs its length
+    query-tile aligned."""
     ok = (head_dim in (32, 64, 128, 256) and block_size % 128 == 0
           and num_heads % 8 == 0)
     if total_tokens is not None:
@@ -110,8 +102,8 @@ def is_quantized(kv):
 
 
 def kv_operand_specs(BS, H, Dh, quant, block_id):
-    """The ONE scalar-prefetched block-index construction both kernels
-    steer their DMA pipeline with (formerly copy-pasted per kernel):
+    """The scalar-prefetched block-index construction the kernel
+    steers its DMA pipeline with:
     `block_id(*grid_and_prefetch_refs) -> pool block` feeds the k/v
     BlockSpec index maps, and for int8 pools the per-vector scale tiles
     ride the SAME index as their codes.  Returns the in_specs list for
@@ -135,13 +127,16 @@ def kv_operands(k_blocks, v_blocks):
 
 def _load_kv(ref, sref, dt):
     """One pool block from VMEM, dequantized in place when the pool is
-    int8 (codes * per-vector scales — elementwise, lane-layout
-    friendly).  The int8->dt convert happens on the ONE block in
-    flight; no bf16 cache copy ever exists in HBM."""
+    int8 (codes * per-vector scales, elementwise).  The convert
+    happens on the ONE block in flight; no bf16 cache copy ever exists
+    in HBM.  The product is taken in f32: Mosaic refuses the
+    [BS, H] -> [BS, H, 1] shape cast of a bf16 scale tile, and the
+    v5e's VPU has no bf16 arithmetic to lose."""
     x = ref[0]
     if sref is None:
         return x
-    return x.astype(dt) * sref[0][..., None].astype(dt)
+    return (x.astype(jnp.float32)
+            * sref[0].astype(jnp.float32)[..., None]).astype(dt)
 
 
 # ---- stream kernel (prefill chunks / decode rows / verify regions) ----
@@ -271,90 +266,23 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
     return out.transpose(1, 0, 2)
 
 
-# ---- decode kernel (one token per sequence) ---------------------------
+# ---- decode (one token per sequence) --------------------------------
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, *refs, scale, nm, quant):
-    if quant:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    mi = pl.program_id(1)
-
-    @pl.when(mi == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    ctx = lens_ref[b]
-    bs = k_ref.shape[1]
-
-    @pl.when(mi * bs < ctx)
-    def _compute():
-        q = q_ref[0]  # [H, Dh] — input dtype feeds the MXU at full rate
-        k = _load_kv(k_ref, ks_ref, q.dtype)  # [BS, H, Dh]
-        v = _load_kv(v_ref, vs_ref, q.dtype)
-        # s[h, t] = sum_d q[h, d] * k[t, h, d]: batch over heads
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [H, BS]
-        pos = mi * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # o[h, d] += sum_t p[h, t] * v[t, h, d]: same head-batched form
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)  # [H, Dh]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(mi == nm - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret"))
 def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
                                   *, scale=None, interpret=False):
-    """Pallas ragged paged decode attention — the one-token-per-sequence
-    specialization of the stream kernel (grid (B, M), no query-tile
-    alignment cost).  Returns [B, H, Dh] in q's dtype; QuantizedKV
-    pools dequantize in VMEM exactly like the stream kernel."""
-    quant, operands = kv_operands(k_blocks, v_blocks)
-    B, H, Dh = q.shape
-    _, BS, _, _ = operands[0].shape
-    M = tables.shape[1]
-    scale = (Dh ** -0.5) if scale is None else float(scale)
-
-    q_spec = pl.BlockSpec((1, H, Dh), lambda b, m, tab, cl: (b, 0, 0))
-    in_specs = [q_spec] + kv_operand_specs(
-        BS, H, Dh, quant, lambda b, m, tab, cl: tab[b, m])
-    kernel = functools.partial(_decode_kernel, scale=scale, nm=M,
-                               quant=quant)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, ctx_lens steer the DMA pipeline
-        grid=(B, M),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, Dh), lambda b, m, tab, cl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, Dh), jnp.float32),
-            pltpu.VMEM((H, STAT_LANES), jnp.float32),
-            pltpu.VMEM((H, STAT_LANES), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), q, *operands)
+    """Ragged paged decode attention: q [B, H, Dh], one token per
+    sequence attending cache positions [0, ctx_len).  A decode row is a
+    one-token segment of the stream, so this IS the stream kernel at a
+    DECODE_TILE-row query tile: row b sits at stream row b*DECODE_TILE
+    with pos = ctx_len - 1 and the tile's other rows are zero padding
+    whose output is dropped (ctx_len == 0 makes a pad tile, which
+    flushes zeros).  Returns [B, H, Dh] in q's dtype."""
+    B = q.shape[0]
+    stream = jnp.pad(q[:, None], ((0, 0), (0, DECODE_TILE - 1),
+                                  (0, 0), (0, 0)))
+    out = unified_ragged_attention_kernel(
+        stream.reshape((B * DECODE_TILE,) + q.shape[1:]), k_blocks,
+        v_blocks, tables, jnp.arange(B, dtype=jnp.int32),
+        ctx_lens.astype(jnp.int32) - 1, scale=scale, q_tile=DECODE_TILE,
+        interpret=interpret)
+    return out[::DECODE_TILE]

@@ -17,29 +17,13 @@ from jax.sharding import Mesh
 _current_mesh = None
 
 
-def axis_size(axis_name):
-    """Static size of the named mesh axis inside a shard_map/jit trace.
-
-    Newer jax spells this `jax.lax.axis_size`; the pinned toolchain
-    (0.4.x) only has `jax.core.axis_frame(name)`, which returns the int
-    directly. Every collective in parallel/ and distributed/ goes
-    through this shim so a jax upgrade can't re-break the whole
-    distributed test family at once."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.core.axis_frame(axis_name)
-
-
 def pvary(x, axis_name):
-    """Mark `x` as device-varying over `axis_name` (the newer-jax
-    varying-axes type system). The pinned 0.4.x toolchain has no
-    `jax.lax.pvary` and no varying-axes tracking to satisfy — there the
-    annotation is a semantic no-op and the shim returns `x` unchanged."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axis_name)
-    return x
+    """Mark `x` as device-varying over `axis_name` inside a shard_map
+    (a scan carry that starts replicated and becomes per-device); a
+    no-op where it already varies."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 @dataclass

@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .mesh import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from .mesh import pvary as _pvary
 
 
@@ -174,7 +174,7 @@ def make_pipeline_loss(stage_fn, loss_head, mesh, num_microbatches,
         order — the reshape below produces the interleaved placement).
     schedule: "gpipe" | "interleaved" (strategy.pipeline_configs).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if schedule not in ("gpipe", "interleaved"):
@@ -220,7 +220,7 @@ def make_pipeline_loss(stage_fn, loss_head, mesh, num_microbatches,
             inner, mesh=mesh,
             in_specs=(spec_p, P(), P()),
             out_specs=P(),
-            check_rep=False)(params_in, x, labels)
+            check_vma=False)(params_in, x, labels)
 
     return loss_fn
 

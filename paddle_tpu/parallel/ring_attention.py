@@ -27,7 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .mesh import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from .mesh import pvary as _pvary
 
 NEG_INF = -1e30
@@ -288,7 +288,7 @@ def ring_attention_sharded(q, k, v, mesh, causal=False, scale=None,
     """User-facing entry: global [B, H, S, D] arrays, sharded over `mesh`'s
     `axis_name` on the sequence dim; does the shard_map itself (replaces the
     round-1 NotImplementedError stub)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, None, axis_name, None)
@@ -298,7 +298,7 @@ def ring_attention_sharded(q, k, v, mesh, causal=False, scale=None,
                               scale=scale, impl=impl, interpret=interpret)
 
     return shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+                     out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ----------------------------------------------------- zigzag (balanced) ring
@@ -435,7 +435,7 @@ def zigzag_ring_attention_sharded(q, k, v, mesh, scale=None,
     training keeps activations in zigzag layout end-to-end (the
     permutation commutes with every position-independent layer) and pays
     neither gather."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis_name]
@@ -450,5 +450,5 @@ def zigzag_ring_attention_sharded(q, k, v, mesh, scale=None,
                                      scale=scale)
 
     out = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_rep=False)(qz, kz, vz)
+                    out_specs=spec, check_vma=False)(qz, kz, vz)
     return out[:, :, inv]

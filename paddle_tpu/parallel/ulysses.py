@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .mesh import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 def _local_attention(q, k, v, causal, scale, interpret):

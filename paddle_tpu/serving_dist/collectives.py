@@ -293,10 +293,10 @@ class CollectiveQuant:
     # -- traced seams ---------------------------------------------------
 
     def _shard_map(self, body, in_specs, out_specs):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+                         out_specs=out_specs, check_vma=False)
 
     def _quantized_psum(self, x):
         """shard_map BODY helper: all_to_all + dequant-sum + all_gather

@@ -178,10 +178,10 @@ def build_sp_fresh_attention(mesh, mode, kv_quant, block_size, scale,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import axis_size as _axis_size
+    from jax.lax import axis_size as _axis_size
     from ..parallel.mesh import pvary as _pvary
 
     if mode not in ("ring", "ulysses"):
@@ -447,4 +447,4 @@ def build_sp_fresh_attention(mesh, mode, kv_quant, block_size, scale,
         in_specs=(stream, stream, stream, pool, pool, P(None, None),
                   P(None), P(None), P(None)),
         out_specs=(stream, pool, pool),
-        check_rep=False)
+        check_vma=False)

@@ -178,22 +178,23 @@ class OpLastCheckpointChecker:
         return []
 
 
-def enable_persistent_compilation_cache(path=None):
-    """Point jax at the repo-local persistent XLA compile cache so a
-    warm-up run skips the 20-40s TPU compiles. One definition for
-    bench.py and the perf/endurance scripts."""
+def enable_persistent_compilation_cache():
+    """Turn on jax's persistent XLA compile cache and return its
+    directory.  Where `JAX_COMPILATION_CACHE_DIR` is set, jax already
+    reads the directory from it and none is set in code; otherwise the
+    cache is `<checkout>/.jax_cache`, one fixed path (the path is part
+    of the cache key).  One definition for chip_smoke.py, bench.py and
+    the perf/endurance scripts; a directory that cannot be created is
+    an error."""
     import os as _os
 
     import jax as _jax
-    if path is None:
+    path = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
         path = _os.path.join(
             _os.path.dirname(_os.path.dirname(_os.path.dirname(
                 _os.path.abspath(__file__)))), ".jax_cache")
-    try:
         _os.makedirs(path, exist_ok=True)
         _jax.config.update("jax_compilation_cache_dir", path)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                           2.0)
-    except Exception:  # pragma: no cover - cache is best-effort
-        pass
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     return path

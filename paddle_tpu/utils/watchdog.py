@@ -3,7 +3,7 @@
 elastic/heartbeat monitoring, rebuilt host-side and device-agnostic).
 
 A TPU training job can wedge without crashing: a stuck collective, a
-dead data-loader worker, an unresponsive device tunnel. The watchdog is
+dead data-loader worker, an unresponsive device. The watchdog is
 a daemon thread armed with a step heartbeat; if no `beat()` arrives
 within `timeout` seconds it (1) dumps every Python thread's stack to
 stderr (or `dump_path`), (2) invokes `on_timeout` (e.g. an emergency

@@ -350,7 +350,7 @@ class TestHybridTP:
 
 class TestRingAttention:
     def test_matches_full_attention(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = make_mesh(dp=1, mp=1, pp=1, sp=8)
         b, h, s, d = 1, 2, 64, 8
         np.random.seed(0)
@@ -484,7 +484,7 @@ class TestRingAttention:
     def test_sp_attention_zigzag_impl(self):
         # the front door accepts impl="zigzag" (caller owns the layout)
         # and refuses the pointless non-causal case
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel.ring_attention import (
@@ -504,7 +504,7 @@ class TestRingAttention:
                                 impl="zigzag")
 
         out = shard_map(causal_fn, mesh=mesh, in_specs=(spec,) * 3,
-                        out_specs=spec, check_rep=False)(
+                        out_specs=spec, check_vma=False)(
             q[:, :, perm], q[:, :, perm], q[:, :, perm])[:, :, inv]
         np.testing.assert_allclose(
             np.asarray(out),
@@ -518,7 +518,7 @@ class TestRingAttention:
 
         with pytest.raises(ValueError, match="causal"):
             shard_map(noncausal_fn, mesh=mesh, in_specs=(spec,) * 3,
-                      out_specs=spec, check_rep=False)(q, q, q)
+                      out_specs=spec, check_vma=False)(q, q, q)
 
     def test_chunked_ring_long_shard(self):
         # chunked path: score tile is [S_local, 512], never S_local^2
@@ -565,7 +565,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         g = dist.new_group([0, 1, 2, 3])
@@ -583,7 +583,7 @@ class TestCollectivesAPI:
         with mesh_guard(mesh):
             xs = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
             out = shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                            check_rep=False)(xs)
+                            check_vma=False)(xs)
         out = np.asarray(out).reshape(-1)
         np.testing.assert_allclose(out[:4], [6.0] * 4)   # 0+1+2+3
         np.testing.assert_allclose(out[4:], [22.0] * 4)  # 4+5+6+7
@@ -593,7 +593,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -607,7 +607,7 @@ class TestCollectivesAPI:
         with mesh_guard(mesh):
             xs = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
             out = shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                            check_rep=False)(xs)
+                            check_vma=False)(xs)
         out = np.asarray(out).reshape(-1)
         np.testing.assert_allclose(out[:4], [2.0] * 4)  # group src rank 2
 
@@ -618,7 +618,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -629,7 +629,7 @@ class TestCollectivesAPI:
             out = shard_map(
                 lambda x: dist.all_reduce(Tensor(x), group=g3)._value,
                 mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                check_rep=False)(jnp.arange(8.0).reshape(8, 1))
+                check_vma=False)(jnp.arange(8.0).reshape(8, 1))
         np.testing.assert_allclose(np.asarray(out).ravel()[:3], [3.0] * 3)
         with pytest.raises(ValueError, match="equal-sized"):
             with mesh_guard(mesh):
@@ -637,14 +637,14 @@ class TestCollectivesAPI:
                     lambda x: dist.broadcast(Tensor(x), src=0,
                                              group=g3)._value,
                     mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                    check_rep=False)(jnp.arange(8.0).reshape(8, 1))
+                    check_vma=False)(jnp.arange(8.0).reshape(8, 1))
         # a group size that divides the world gets a uniform partition
         g2 = dist.new_group([0, 1])
         with mesh_guard(mesh):
             out = shard_map(
                 lambda x: dist.broadcast(Tensor(x), src=1, group=g2)._value,
                 mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                check_rep=False)(jnp.arange(8.0).reshape(8, 1))
+                check_vma=False)(jnp.arange(8.0).reshape(8, 1))
         assert float(np.asarray(out).ravel()[0]) == 1.0
 
     def test_ulysses_matches_full_attention(self):
@@ -654,7 +654,7 @@ class TestCollectivesAPI:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.parallel.ulysses import ulysses_attention
 
@@ -669,7 +669,7 @@ class TestCollectivesAPI:
             return ulysses_attention(q, k, v, axis_name="sp", causal=True)
 
         out = shard_map(inner, mesh=mesh, in_specs=(spec,) * 3,
-                        out_specs=spec, check_rep=False)(q, k, v)
+                        out_specs=spec, check_vma=False)(q, k, v)
         scale = d ** -0.5
         logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
         mask = jnp.tril(jnp.ones((s, s), bool))
@@ -683,7 +683,7 @@ class TestCollectivesAPI:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.parallel.ulysses import ulysses_attention
 
@@ -699,7 +699,7 @@ class TestCollectivesAPI:
                 o = ulysses_attention(q, k, v, axis_name="sp", causal=True)
                 return o
             o = shard_map(inner, mesh=mesh, in_specs=(spec,) * 3,
-                          out_specs=spec, check_rep=False)(q, k, v)
+                          out_specs=spec, check_vma=False)(q, k, v)
             return (o.astype(jnp.float32) ** 2).sum()
 
         def ref_loss(q, k, v):
@@ -722,7 +722,7 @@ class TestCollectivesAPI:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.parallel.ulysses import sp_attention
 
@@ -737,7 +737,7 @@ class TestCollectivesAPI:
             return sp_attention(q, k, v, axis_name="sp", causal=True)
 
         out = shard_map(inner, mesh=mesh, in_specs=(spec,) * 3,
-                        out_specs=spec, check_rep=False)(q, k, v)
+                        out_specs=spec, check_vma=False)(q, k, v)
         assert out.shape == q.shape
         assert np.isfinite(np.asarray(out)).all()
 
@@ -750,7 +750,7 @@ class TestCollectivesAPI:
         import numpy as np
         import paddle_tpu.nn as nn
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -775,7 +775,7 @@ class TestCollectivesAPI:
         mesh = Mesh(np.array(jax.devices()), ("dp",))
         with mesh_guard(mesh):
             g_dp = shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                             out_specs=P(), check_rep=False)(x, y)
+                             out_specs=P(), check_vma=False)(x, y)
         # full-batch reference gradient
         out = net(Tensor(x))
         loss = ((out - Tensor(y)) ** 2).mean()
@@ -824,7 +824,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -834,7 +834,7 @@ class TestCollectivesAPI:
             out = shard_map(
                 lambda x: dist.reduce(Tensor(x), dst=3)._value,
                 mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                check_rep=False)(jnp.arange(8.0).reshape(8, 1))
+                check_vma=False)(jnp.arange(8.0).reshape(8, 1))
         out = np.asarray(out).ravel()
         expected = np.arange(8.0)
         expected[3] = 28.0  # sum(0..7) lands on dst only
@@ -847,7 +847,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -857,7 +857,7 @@ class TestCollectivesAPI:
             out = shard_map(
                 lambda x: dist.reduce(Tensor(x), dst=5)._value,
                 mesh=mesh, in_specs=P(("a", "b")), out_specs=P(("a", "b")),
-                check_rep=False)(jnp.arange(8.0).reshape(8, 1))
+                check_vma=False)(jnp.arange(8.0).reshape(8, 1))
         out = np.asarray(out).ravel()
         expected = np.arange(8.0)
         expected[5] = 28.0  # only global rank 5 (a=1, b=1) gets the sum
@@ -870,7 +870,7 @@ class TestCollectivesAPI:
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu.distributed as dist
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.parallel.mesh import mesh_guard
@@ -885,7 +885,7 @@ class TestCollectivesAPI:
 
         with mesh_guard(mesh):
             out = shard_map(f, mesh=mesh, in_specs=P("dp"),
-                            out_specs=P("dp"), check_rep=False)(
+                            out_specs=P("dp"), check_vma=False)(
                 jnp.zeros((8, 1)))
         np.testing.assert_allclose(np.asarray(out).ravel(),
                                    [10.0 * i for i in range(8)])
@@ -900,7 +900,7 @@ class TestCollectivesAPI:
 
         with mesh_guard(mesh):
             out = shard_map(fg, mesh=mesh, in_specs=P("dp"),
-                            out_specs=P("dp"), check_rep=False)(
+                            out_specs=P("dp"), check_vma=False)(
                 jnp.full((8, 1), -1.0))
         out = np.asarray(out).ravel()
         np.testing.assert_allclose(out[:4], [100.0, 101.0, 102.0, 103.0])
@@ -970,7 +970,7 @@ class TestQuantizedAllReduce:
     one quantization error per phase, not per hop."""
 
     def test_matches_psum_within_quant_error(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed.collective import quantized_all_reduce
         n = 8
@@ -984,7 +984,7 @@ class TestQuantizedAllReduce:
 
             out = np.asarray(shard_map(
                 body, mesh=mesh, in_specs=P("dp", None),
-                out_specs=P("dp", None), check_rep=False)(g))
+                out_specs=P("dp", None), check_vma=False)(g))
             exact = np.asarray(g).sum(0)
             # result replicated across ranks
             for r in range(1, n):
@@ -1025,7 +1025,7 @@ class TestQuantizedAllReduce:
         """code-review r4: leaves below n*block must use plain psum (no
         padding blow-up), and bits=16 must produce int16 codes, not int8
         wraparound."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed.collective import quantized_all_reduce
         n = 8
@@ -1038,7 +1038,7 @@ class TestQuantizedAllReduce:
 
         out = np.asarray(shard_map(body, mesh=mesh, in_specs=P("dp", None),
                                    out_specs=P("dp", None),
-                                   check_rep=False)(small))
+                                   check_vma=False)(small))
         np.testing.assert_allclose(out[0], np.asarray(small).sum(0),
                                    rtol=1e-6)  # exact: psum path
         big = jnp.asarray((rs.randn(n, 4096) * 100).astype(np.float32))
@@ -1049,7 +1049,7 @@ class TestQuantizedAllReduce:
         out16 = np.asarray(shard_map(body16, mesh=mesh,
                                      in_specs=P("dp", None),
                                      out_specs=P("dp", None),
-                                     check_rep=False)(big))
+                                     check_vma=False)(big))
         exact = np.asarray(big).sum(0)
         rel = np.abs(out16[0] - exact).max() / np.abs(exact).max()
         assert rel < 1e-4, rel  # 16-bit codes: ~256x tighter than int8

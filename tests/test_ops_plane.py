@@ -116,7 +116,8 @@ class TestFlightRecorderRing:
             assert not wd.stalled  # idle engine is healthy
             state["pending"] = True
             assert _wait_for(lambda: wd.stalled, timeout=5)
-            assert state["stalls"] == 1
+            # the flag is set just before the callback runs
+            assert _wait_for(lambda: state["stalls"] == 1, timeout=5)
             state["progress"] += 1  # dispatch progress clears the stall
             assert _wait_for(lambda: not wd.stalled, timeout=5)
             assert state["stalls"] == 1  # one episode, one dump

@@ -434,7 +434,7 @@ class TestPagedAttentionKernel:
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import paged_decode_attention
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.pallas.unified_attention import (
             paged_decode_attention_kernel)
 
         rs = np.random.RandomState(0)

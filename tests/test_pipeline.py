@@ -33,14 +33,14 @@ class TestPipeline:
             ref = np.tanh(ref @ ws[i])
 
         mesh = _mesh_pp(s)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def run(ws, x):
             def inner(w_local, x):
                 return pipeline_apply(stage_fn, w_local[0], x, "pp")
             return shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
-                             out_specs=P(), check_rep=False)(ws, x)
+                             out_specs=P(), check_vma=False)(ws, x)
 
         out = jax.jit(run)(jnp.asarray(ws), jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
@@ -98,7 +98,7 @@ class TestInterleavedPipeline:
         return ref
 
     def test_forward_matches_sequential_and_gpipe(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel.pipeline import pipeline_apply_interleaved
@@ -117,7 +117,7 @@ class TestInterleavedPipeline:
                     self._stage_fn, w_local[:, 0], x, "pp")
             return shard_map(inner, mesh=mesh,
                              in_specs=(P(None, "pp"), P()),
-                             out_specs=P(), check_rep=False)(wr, x)
+                             out_specs=P(), check_vma=False)(wr, x)
 
         def run_gpipe(ws, x):
             # same 8 groups as 4 stages of 2 consecutive layers each
@@ -132,7 +132,7 @@ class TestInterleavedPipeline:
             def inner(w_local, x):
                 return pipeline_apply(stage2, w_local[0], x, "pp")
             return shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
-                             out_specs=P(), check_rep=False)(wr, x)
+                             out_specs=P(), check_vma=False)(wr, x)
 
         out_i = jax.jit(run_inter)(jnp.asarray(ws), jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(out_i), ref,
@@ -218,7 +218,7 @@ class TestInterleavedPipeline:
                         < bubble_fraction("gpipe", s, m)
 
     def test_rejects_indivisible_microbatches(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel.pipeline import pipeline_apply_interleaved
@@ -234,7 +234,7 @@ class TestInterleavedPipeline:
                     self._stage_fn, w_local[:, 0], x, "pp")
             return shard_map(inner, mesh=mesh,
                              in_specs=(P(None, "pp"), P()),
-                             out_specs=P(), check_rep=False)(wr, x)
+                             out_specs=P(), check_vma=False)(wr, x)
 
         with pytest.raises(ValueError, match="divisible"):
             jax.jit(run)(wr, jnp.asarray(x))

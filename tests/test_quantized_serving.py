@@ -494,7 +494,7 @@ class TestQuantizedPallasKernels:
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import paged_decode_attention
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.pallas.unified_attention import (
             paged_decode_attention_kernel)
 
         rs = np.random.RandomState(0)
@@ -515,7 +515,8 @@ class TestQuantizedPallasKernels:
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import ragged_prefill_attention
-        from paddle_tpu.ops.pallas.ragged_prefill import (
+        from paddle_tpu.ops.pallas.unified_attention import (
+            unified_ragged_attention_kernel as
             ragged_prefill_attention_kernel)
 
         rs = np.random.RandomState(2)

@@ -88,7 +88,8 @@ class TestRaggedPrefillAttention:
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import ragged_prefill_attention
-        from paddle_tpu.ops.pallas.ragged_prefill import (
+        from paddle_tpu.ops.pallas.unified_attention import (
+            unified_ragged_attention_kernel as
             ragged_prefill_attention_kernel)
 
         rs = np.random.RandomState(2)

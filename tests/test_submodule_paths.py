@@ -52,6 +52,33 @@ class TestModulePaths:
         from paddle_tpu.tensor.stat import mean  # noqa: F401
         assert isinstance(get_device(), str)
 
+    def test_explicit_place_must_exist(self, monkeypatch):
+        """A device the caller names explicitly is an error when this host
+        lacks the kind, or the index is past its last device — never
+        another device handed back quietly (the default place may still
+        choose by what exists)."""
+        import pytest
+
+        from paddle_tpu.core import place
+
+        before = place._expected_place()
+        with pytest.raises(RuntimeError, match="no tpu device"):
+            place.set_device("tpu")  # this test host is CPU-only
+        with pytest.raises(RuntimeError, match="no tpu device"):
+            place.TPUPlace(0).jax_device()
+
+        class FakeChip:
+            platform = "tpu"
+
+        chips = [FakeChip(), FakeChip()]
+        monkeypatch.setattr(place.jax, "devices", lambda: chips)
+        assert place.TPUPlace(1).jax_device() is chips[1]
+        with pytest.raises(ValueError, match="past the last"):
+            place.TPUPlace(2).jax_device()
+        with pytest.raises(ValueError, match="past the last"):
+            place.set_device("tpu:3")
+        assert place._expected_place() is before  # a refused device sticks nowhere
+
     def test_nest_utils(self):
         from paddle_tpu.fluid.layers.utils import flatten, map_structure, \
             pack_sequence_as

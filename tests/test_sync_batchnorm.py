@@ -5,7 +5,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -35,7 +35,7 @@ def test_sync_bn_matches_global_batch_stats():
     with mesh_guard(mesh):
         out = jax.jit(shard_map(shard_fn, mesh=mesh,
                                 in_specs=P("dp"), out_specs=P("dp"),
-                                check_rep=False))(jnp.asarray(x))
+                                check_vma=False))(jnp.asarray(x))
 
     # reference: plain BN over the FULL batch on one device
     ref_bn = paddle.nn.BatchNorm2D(3)
@@ -105,7 +105,7 @@ def test_non_dp_axes_not_synced():
         return sbn(paddle.Tensor(xs[0]))._value[None]
 
     out = jax.jit(shard_map(shard_fn, mesh=mesh, in_specs=P("mp"),
-                            out_specs=P("mp"), check_rep=False))(
+                            out_specs=P("mp"), check_vma=False))(
         jnp.asarray(x))
     # each shard normalized by its OWN stats -> every shard has mean ~0
     per_shard_means = np.asarray(out).mean(axis=(1, 2))

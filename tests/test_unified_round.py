@@ -86,18 +86,33 @@ class TestUnifiedKernel:
         valid = pos >= 0
         np.testing.assert_allclose(out[valid], ref[valid], atol=2e-4)
 
-    def test_shims_reexport_the_merged_kernels(self):
-        """The historical module paths must keep working (satellite:
-        the dedup deleted the per-kernel copies, not the API)."""
-        from paddle_tpu.ops.pallas import paged_attention, ragged_prefill
+    def test_decode_entry_is_the_stream_kernel_at_decode_tile(self):
+        """One kernel body: the one-token decode call must equal the
+        stream kernel fed the same rows as DECODE_TILE-row segments
+        (PR 21 deleted the separate decode body the chip's compiler
+        refused, and the two re-export shim modules with it)."""
+        import jax.numpy as jnp
+
         from paddle_tpu.ops.pallas import unified_attention as ua
 
-        assert ragged_prefill.ragged_prefill_attention_kernel \
-            is ua.unified_ragged_attention_kernel
-        assert paged_attention.paged_decode_attention_kernel \
-            is ua.paged_decode_attention_kernel
-        assert ragged_prefill.supported_shapes is ua.supported_shapes
-        assert paged_attention.supported_shapes is ua.supported_shapes
+        rs = np.random.RandomState(3)
+        b, h, dh, n, bs = 3, 4, 8, 9, 4
+        q = jnp.asarray(rs.randn(b, h, dh).astype(np.float32))
+        kb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
+        vb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
+        tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0],
+                                       [6, 7, 8, 2]], np.int32))
+        lens = jnp.asarray(np.array([11, 0, 16], np.int32))
+        out = ua.paged_decode_attention_kernel(q, kb, vb, tables, lens,
+                                               interpret=True)
+        qt = ua.DECODE_TILE
+        stream = jnp.zeros((b, qt, h, dh), q.dtype).at[:, 0].set(q)
+        ref = ua.unified_ragged_attention_kernel(
+            stream.reshape(b * qt, h, dh), kb, vb, tables,
+            jnp.arange(b), lens - 1, q_tile=qt, interpret=True)[::qt]
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        assert not np.asarray(out[1]).any()  # ctx_len 0: a pad tile
+        assert not hasattr(ua, "_decode_kernel")
 
 
 def _serve(model, prompts, sampling_fn=None, timeout=300, **kw):
