@@ -1,0 +1,7 @@
+"""Device: 1 - union of device-op intervals over the traced whole steps."""
+
+
+def read(obs):
+    if obs["trace"] is None:
+        return None
+    return 100 * (1 - obs["busy_s"] / obs["trace_window_s"])
