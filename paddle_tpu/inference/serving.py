@@ -238,6 +238,147 @@ STOP_REASONS = ("eos", "stop_token", "stop_string", "budget")
 
 HEALTH_CODES = {"ok": 0.0, "degraded": 1.0, "stalled": 2.0}
 
+# What the paged engine's thread does in a round, one vocabulary for
+# every loop (split, unified, unified async):
+#   admit      taking the lock at the round boundary, host ops,
+#              timeouts, admission
+#   idle_wait  waiting on the lock with no slot occupied: no work
+#   plan       the host arrays of a dispatch: slot scan, chunk packing,
+#              sampling args, table growth, the table matrix
+#   dispatch   uploading the inputs and the jitted call
+#   read_back  np.asarray of the outputs: the host blocked on the device
+#   emit       pool swap, per-token callbacks, futures, slot release,
+#              latency bookkeeping
+# and "other": whatever of the thread's time no phase covers.
+ROUND_PHASES = ("admit", "idle_wait", "plan", "dispatch", "read_back",
+                "emit")
+
+
+class _RoundPhases:
+    """Where the engine thread's time goes: stats()["round_phases"].
+
+    The engine thread is the only writer. Every phase boundary moves
+    the time since the previous boundary to the phase that was open
+    (the innermost; "other" when none was), so the phases tile the
+    thread's wall time exactly; `dispatches` is counted at the same
+    boundary (a `dispatch` phase left without an exception). A round is
+    one iteration of the engine's loop that dispatched something;
+    `round` numbers them for the life of the engine (the dispatch
+    spans' `round` attribute). Readers and `reset` come from other
+    threads: the clock is read under the lock, so that a boundary never
+    lies before the reset it follows."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._stack = []   # open phases, innermost last
+        self._t = None     # the last boundary; None: no thread running
+        self.round = 0
+        self.reset()
+
+    def reset(self):
+        with self._lock:
+            now = time.perf_counter()
+            self._seconds = dict.fromkeys(ROUND_PHASES + ("other",), 0.0)
+            self._dispatches = 0
+            self._longest = None
+            self._t_reset = now
+            self._open_round(now)
+            if self._t is not None:
+                self._t = now
+
+    def _open_round(self, now):
+        self._round = dict.fromkeys(self._seconds, 0.0)
+        self._round_t0 = now
+        self._kinds = []
+
+    def _boundary(self):
+        """Caller holds the lock. Returns the boundary's instant."""
+        now = time.perf_counter()
+        if self._t is not None:
+            cur = self._stack[-1] if self._stack else "other"
+            self._seconds[cur] += now - self._t
+            self._round[cur] += now - self._t
+            self._t = now
+        return now
+
+    def thread_started(self):
+        with self._lock:
+            self._t = time.perf_counter()
+            self._open_round(self._t)
+
+    def thread_stopped(self):
+        with self._lock:
+            self._boundary()
+            self._t = None
+            del self._stack[:]
+
+    def phase(self, name):
+        return _Phase(self, name)
+
+    def kind(self, kind):
+        """The round in progress holds a dispatch of this kind."""
+        with self._lock:
+            if kind not in self._kinds:
+                self._kinds.append(kind)
+
+    def close_round(self):
+        """Between two iterations of the engine's loop: if the one that
+        ended dispatched anything it was a round, and the longest round
+        of the window keeps its phases."""
+        with self._lock:
+            now = self._boundary()
+            if self._kinds:
+                ms = (now - self._round_t0) * 1e3
+                if self._longest is None or ms > self._longest["ms"]:
+                    self._longest = {
+                        "ms": ms, "at_s": self._round_t0 - self._t_reset,
+                        "kind": "+".join(self._kinds), "round": self.round,
+                        "phases_ms": {k: v * 1e3
+                                      for k, v in self._round.items()}}
+                self.round += 1
+            self._open_round(now)
+
+    def snapshot(self):
+        with self._lock:
+            seconds = dict(self._seconds)
+            if self._t is not None:  # the phase open right now
+                cur = self._stack[-1] if self._stack else "other"
+                seconds[cur] += time.perf_counter() - self._t
+            longest = self._longest or {
+                "ms": 0.0, "at_s": 0.0, "kind": "", "round": None,
+                "phases_ms": dict.fromkeys(seconds, 0.0)}
+            return {"seconds": seconds, "dispatches": self._dispatches,
+                    "longest_round": dict(longest)}
+
+
+class _Phase:
+    """One open phase: the `pt:<phase>` span plus the accounting."""
+
+    __slots__ = ("_clock", "_name", "_span")
+
+    def __init__(self, clock, name):
+        self._clock, self._name = clock, name
+
+    def __enter__(self):
+        clock = self._clock
+        with clock._lock:
+            clock._boundary()
+            clock._stack.append(self._name)
+        # the span opens inside the time it names, and closes inside it
+        self._span = _tracing.span(self._name)
+        self._span.__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._span.__exit__(exc_type, exc, tb)
+        clock = self._clock
+        with clock._lock:
+            clock._boundary()
+            if clock._stack:
+                clock._stack.pop()
+            if exc_type is None and self._name == "dispatch":
+                clock._dispatches += 1
+        return False
+
 
 @dataclass
 class RequestMeta:
@@ -1164,6 +1305,10 @@ class PagedGenerationServer:
         self._round_dispatch_count = 0
         self._mixed_rounds = 0
         self._overlap_s = 0.0
+        # the engine thread's time by phase (always on, reset with the
+        # window): every loop runs its rounds through `self._phase`
+        self._phases = _RoundPhases()
+        self._phase = self._phases.phase
         self._pending = None
         self._carry = None
         self._zero_carry = None
@@ -2760,6 +2905,7 @@ class PagedGenerationServer:
             self._round_dispatch_count = 0
             self._mixed_rounds = 0
             self._overlap_s = 0.0
+            self._phases.reset()
             self._compile_mark = _compile_tracker.mark()
             self._last_error = None  # a fresh window is healthy again
             self._last_error_info = None
@@ -2927,6 +3073,13 @@ class PagedGenerationServer:
                     "overlap_fraction": (self._overlap_s / dt
                                          if dt else 0.0),
                 },
+                # the engine thread's time by round phase (ROUND_PHASES
+                # + "other"; the seconds tile its wall time since the
+                # reset), the dispatches issued, and the window's
+                # longest round with its own phases — what the host
+                # costs per dispatch and where a round that stood
+                # still spent it; reset-coherent
+                "round_phases": self._phases.snapshot(),
                 # reliability (r17): fault injection + recovery ladder
                 # + timeout/shed window counters — schema-stable
                 # (zeros when nothing ever failed), reset-coherent
@@ -3354,228 +3507,238 @@ class PagedGenerationServer:
         packed_prefill program — K/V lands directly in each sequence's
         paged blocks. Slots whose FINAL chunk is in this dispatch
         sample their first token here (that is their TTFT)."""
-        jnp = self._jnp
-        align = self._pack_align
-        # sp multiplies the per-dispatch chunk budget: the sp-sharded
-        # packed program runs T/sp tokens per shard, so sp chunks'
-        # worth of prompt tokens cost one replica-budget dispatch
-        budget = self.prefill_chunk_tokens * self._sp_degree
-        # chunk-budget sharing (round 12): the scheduler orders the
-        # feeding slots (interactive/EDF first) and may cap each slot's
-        # share of this chunk so one lane cannot monopolize the budget;
-        # without a scheduler the order is slot order, uncapped
-        if self._sched is not None:
-            entries = self._sched.prefill_plan(
-                [(i, self._slots[i]) for i in pre_idx], budget)
-        else:
-            entries = [(i, None) for i in pre_idx]
-        plan = []  # (slot_idx, start, n, packed_offset)
-        off = 0
-        for i, cap in entries:
-            if budget <= 0:
-                break
-            s = self._slots[i]
-            n = min(s["prompt"].size - s["fed"], budget)
-            if cap is not None:
-                n = min(n, int(cap))
-            if n <= 0:
-                continue
-            plan.append((i, s["fed"], n, off))
-            off += -(-n // align) * align
-            budget -= n
-        if not plan:
-            return
-        T = align  # power-of-two bucket: compile count is logarithmic
-        while T < off:  # in the packed budget, not per prompt length
-            T *= 2
-        # COMPACT segment rows: the dispatch carries tables only for the
-        # plan's slots (row count bucketed to a power of two), so a
-        # one-request churn round pays for one row's cache, not
-        # max_slots of them
-        P = 1
-        while P < len(plan):
-            P *= 2
-        toks = np.zeros((T,), np.int32)
-        seg = np.zeros((T,), np.int32)
-        pos = np.full((T,), -1, np.int32)  # -1 marks packing pad
-        sample_idx = np.zeros((P,), np.int32)
-        done_rows = []  # (slot_idx, compact_row)
-        for r, (i, start, n, o) in enumerate(plan):
-            s = self._slots[i]
-            toks[o:o + n] = s["prompt"][start:start + n]
-            seg[o:o + n] = r
-            pos[o:o + n] = np.arange(start, start + n, dtype=np.int32)
-            if s["t_pre0"] is None:
-                s["t_pre0"] = time.perf_counter()
-            if start + n == s["prompt"].size:
-                sample_idx[r] = o + n - 1
-                done_rows.append((i, r))
-        # decode-phase slots stall while this dispatch runs — the stall
-        # the chunk budget exists to bound
-        in_plan = {p[0] for p in plan}
-        decoding = any(s is not None and j not in in_plan
-                       and s["fed"] >= s["prompt"].size
-                       for j, s in enumerate(self._slots))
-        self._recorder.record(
-            "prefill_chunk", packed=int(T), rows=len(plan),
-            tokens=int(sum(p[2] for p in plan)),
-            free_blocks=self.cache.available_block_count)
-        if self._sp_degree > 1:
-            self._note_sp_peak(T)
-        parts = self._cost_parts(
-            [(self._slots[i]["req"], n) for i, _start, n, _o in plan])
-        self._attr_begin(parts)
+        with self._phase("plan"):
+            jnp = self._jnp
+            align = self._pack_align
+            # sp multiplies the per-dispatch chunk budget: the sp-sharded
+            # packed program runs T/sp tokens per shard, so sp chunks'
+            # worth of prompt tokens cost one replica-budget dispatch
+            budget = self.prefill_chunk_tokens * self._sp_degree
+            # chunk-budget sharing (round 12): the scheduler orders the
+            # feeding slots (interactive/EDF first) and may cap each slot's
+            # share of this chunk so one lane cannot monopolize the budget;
+            # without a scheduler the order is slot order, uncapped
+            if self._sched is not None:
+                entries = self._sched.prefill_plan(
+                    [(i, self._slots[i]) for i in pre_idx], budget)
+            else:
+                entries = [(i, None) for i in pre_idx]
+            plan = []  # (slot_idx, start, n, packed_offset)
+            off = 0
+            for i, cap in entries:
+                if budget <= 0:
+                    break
+                s = self._slots[i]
+                n = min(s["prompt"].size - s["fed"], budget)
+                if cap is not None:
+                    n = min(n, int(cap))
+                if n <= 0:
+                    continue
+                plan.append((i, s["fed"], n, off))
+                off += -(-n // align) * align
+                budget -= n
+            if not plan:
+                return
+            T = align  # power-of-two bucket: compile count is logarithmic
+            while T < off:  # in the packed budget, not per prompt length
+                T *= 2
+            # COMPACT segment rows: the dispatch carries tables only for the
+            # plan's slots (row count bucketed to a power of two), so a
+            # one-request churn round pays for one row's cache, not
+            # max_slots of them
+            P = 1
+            while P < len(plan):
+                P *= 2
+            toks = np.zeros((T,), np.int32)
+            seg = np.zeros((T,), np.int32)
+            pos = np.full((T,), -1, np.int32)  # -1 marks packing pad
+            sample_idx = np.zeros((P,), np.int32)
+            done_rows = []  # (slot_idx, compact_row)
+            for r, (i, start, n, o) in enumerate(plan):
+                s = self._slots[i]
+                toks[o:o + n] = s["prompt"][start:start + n]
+                seg[o:o + n] = r
+                pos[o:o + n] = np.arange(start, start + n, dtype=np.int32)
+                if s["t_pre0"] is None:
+                    s["t_pre0"] = time.perf_counter()
+                if start + n == s["prompt"].size:
+                    sample_idx[r] = o + n - 1
+                    done_rows.append((i, r))
+            # decode-phase slots stall while this dispatch runs — the stall
+            # the chunk budget exists to bound
+            in_plan = {p[0] for p in plan}
+            decoding = any(s is not None and j not in in_plan
+                           and s["fed"] >= s["prompt"].size
+                           for j, s in enumerate(self._slots))
+            if self._recorder.enabled:
+                self._recorder.record(
+                    "prefill_chunk", packed=int(T), rows=len(plan),
+                    tokens=int(sum(p[2] for p in plan)),
+                    free_blocks=self.cache.available_block_count)
+            if self._sp_degree > 1:
+                self._note_sp_peak(T)
+            parts = self._cost_parts(
+                [(self._slots[i]["req"], n) for i, _start, n, _o in plan])
+            self._attr_begin(parts)
+        self._phases.kind("prefill")
         t0 = time.perf_counter()
         try:
             with _tracing.span(
                     "prefill_chunk", packed=T, segments=len(plan),
                     tokens=int(sum(p[2] for p in plan)),
+                    round=self._phases.round,
                     request_ids=[self._slots[i]["req"].rid
-                                 for i, *_ in plan], **self._rattr()):
-                self._maybe_fault("slow_dispatch")
-                self._maybe_fault("ensure_many")
-                # bulk multi-sequence allocation: the whole chunk plan's
-                # tables grow atomically (reservation-backed, so this
-                # cannot exhaust the pool mid-plan)
-                self.cache.ensure_many(
-                    [(self._slots[i]["seq"], start + n)
-                     for i, start, n, _ in plan])
-                if self.enable_prefix_cache:
-                    # copy-on-write guard: a chunk starting mid-block in
-                    # an attached (shared or index-claimed) block gets a
-                    # private copy before the dispatch writes into it
-                    for i, start, _n, _o in plan:
-                        self.cache.prepare_write(
-                            self._slots[i]["seq"], start)
-                # cap the table width at a power-of-two bucket of the
-                # plan's deepest chunk end: early chunks of long
-                # prompts attend (and the fallback gathers) only the
-                # cache they can reach, and the jit re-specializes per
-                # (T, width) pair — still logarithmically many
-                mcap = 1
-                need = max(self._blocks_for(start + n, self.block_size)
-                           for _, start, n, _ in plan)
-                while mcap < need:
-                    mcap *= 2
-                mcap = min(mcap, self._m_width)
-                tables = jnp.asarray(self.cache.table_array(
-                    [self._slots[plan[r][0]]["seq"]
-                     if r < len(plan) else None for r in range(P)],
-                    mcap))
-                # per-slot sampling buffers gathered to compact plan
-                # rows; token-0 sampling (PRNG step 0) runs the same
-                # vectorized pipeline as decode
-                done_set = {r for _, r in done_rows}
-                # per-row PRNG base step: 0 for a fresh prompt; a
-                # resumed request samples its next token at step
-                # len(generated so far), the exact counter position an
-                # uninterrupted decode would have used
-                base_steps = np.array(
-                    [len(self._slots[plan[r][0]]["toks"])
-                     if r < len(plan) else 0 for r in range(P)],
-                    np.int32)
-                sp_args, sp_mode = self._sp_store.packed_args(
-                    [plan[r][0] if r < len(plan) else None
-                     for r in range(P)],
-                    [r in done_set for r in range(P)], base_steps)
-                self._maybe_fault("prefill")
-                tok, stopped, kc, vc, counts = \
-                    self._decoder.packed_prefill(
-                        self._params, jnp.asarray(toks),
-                        jnp.asarray(seg), jnp.asarray(pos), tables,
-                        jnp.asarray(sample_idx), self.cache.k_blocks,
-                        self.cache.v_blocks, sp_args, sp_mode)
-                self._sp_store.swap_counts(counts)
-                tok_h = np.asarray(tok)
-                stopped_h = np.asarray(stopped)
+                                 for i, *_ in plan]
+                    if _tracing.enabled() else (), **self._rattr()):
+                with self._phase("plan"):
+                    self._maybe_fault("slow_dispatch")
+                    self._maybe_fault("ensure_many")
+                    # bulk multi-sequence allocation: the whole chunk plan's
+                    # tables grow atomically (reservation-backed, so this
+                    # cannot exhaust the pool mid-plan)
+                    self.cache.ensure_many(
+                        [(self._slots[i]["seq"], start + n)
+                         for i, start, n, _ in plan])
+                    if self.enable_prefix_cache:
+                        # copy-on-write guard: a chunk starting mid-block in
+                        # an attached (shared or index-claimed) block gets a
+                        # private copy before the dispatch writes into it
+                        for i, start, _n, _o in plan:
+                            self.cache.prepare_write(
+                                self._slots[i]["seq"], start)
+                    # cap the table width at a power-of-two bucket of the
+                    # plan's deepest chunk end: early chunks of long
+                    # prompts attend (and the fallback gathers) only the
+                    # cache they can reach, and the jit re-specializes per
+                    # (T, width) pair — still logarithmically many
+                    mcap = 1
+                    need = max(self._blocks_for(start + n, self.block_size)
+                               for _, start, n, _ in plan)
+                    while mcap < need:
+                        mcap *= 2
+                    mcap = min(mcap, self._m_width)
+                    tables = self.cache.table_array(
+                        [self._slots[plan[r][0]]["seq"]
+                         if r < len(plan) else None for r in range(P)],
+                        mcap)
+                    # per-slot sampling buffers gathered to compact plan
+                    # rows; token-0 sampling (PRNG step 0) runs the same
+                    # vectorized pipeline as decode
+                    done_set = {r for _, r in done_rows}
+                    # per-row PRNG base step: 0 for a fresh prompt; a
+                    # resumed request samples its next token at step
+                    # len(generated so far), the exact counter position an
+                    # uninterrupted decode would have used
+                    base_steps = np.array(
+                        [len(self._slots[plan[r][0]]["toks"])
+                         if r < len(plan) else 0 for r in range(P)],
+                        np.int32)
+                    sp_args, sp_mode = self._sp_store.packed_args(
+                        [plan[r][0] if r < len(plan) else None
+                         for r in range(P)],
+                        [r in done_set for r in range(P)], base_steps)
+                with self._phase("dispatch"):
+                    self._maybe_fault("prefill")
+                    tok, stopped, kc, vc, counts = \
+                        self._decoder.packed_prefill(
+                            self._params, jnp.asarray(toks),
+                            jnp.asarray(seg), jnp.asarray(pos),
+                            jnp.asarray(tables),
+                            jnp.asarray(sample_idx), self.cache.k_blocks,
+                            self.cache.v_blocks, sp_args, sp_mode)
+                    self._sp_store.swap_counts(counts)
+                with self._phase("read_back"):
+                    tok_h = np.asarray(tok)
+                    stopped_h = np.asarray(stopped)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-the-chunk path)
             self._dispatch_failure("prefill", e,
                                    [i for i, *_ in plan])
             return
-        self.cache.swap_arrays(kc, vc)
-        self._dispatch_ok([self._slots[i]["req"].rid
-                           for i, *_ in plan
-                           if self._slots[i] is not None])
-        t_now = time.perf_counter()
-        self._charge_dispatch(t_now - t0, parts)
-        if self._ledger is not None:
-            # feed the measured prefill unit cost (EMA) — the rate the
-            # prefix-cache savings credit is priced at
-            self._ledger.note_prefill_cost(
-                int((t_now - t0) * 1e9),
-                int(sum(p[2] for p in plan)))
-        self._ops_progress += 1
-        if decoding:
-            _m_decode_stall.observe(t_now - t0)
-        _m_prefill_dispatches.inc()
-        # goodput: a resumed request's chunk re-feeds already-generated
-        # tokens (positions past its ORIGINAL prompt) — decoded work
-        # that emits nothing, accounted as preempt replay
-        replay = 0
-        for i, start, n, _o in plan:
-            req = self._slots[i]["req"]
-            if req.resume_ids is not None:
-                replay += max(0, start + n - max(start, req.ids.size))
-        with self._lock:
-            self._prefill_dispatches += 1
-            if replay:
-                self._decoded_tokens += replay
-                self._replayed_tokens += replay
-        if replay:
-            _m_decoded.inc(replay)
-            _m_replayed.inc(replay)
-        for i, start, n, o in plan:
-            s = self._slots[i]
-            s["fed"] = start + n
-            s["chunks"] += 1
-        for i, r in done_rows:
-            s = self._slots[i]
-            req = s["req"]
-            if req.ttft is None:
-                # first token of the request's LIFETIME — a resumed
-                # request keeps the TTFT of its first residency
-                req.ttft = t_now - req.t_submit
-                _m_ttft.observe(req.ttft)
-                if self._slo is not None:
-                    self._slo_latency("ttft", req.ttft, req)
-                with self._lock:
-                    self._ttft.append(req.ttft)
-                    if req.meta is not None:
-                        lane = req.meta.lane
-                        self._lane_ttft.setdefault(lane, []).append(
-                            req.ttft)
-                        if req.meta.deadline_s is not None:
-                            self._deadline_requests[lane] = \
-                                self._deadline_requests.get(lane, 0) + 1
-                            if req.ttft > req.meta.deadline_s:
-                                self._deadline_misses[lane] = \
-                                    self._deadline_misses.get(lane,
-                                                              0) + 1
-                                _m_deadline_miss.labels(lane=lane).inc()
-                                _m_deadline_overage.observe(
-                                    req.ttft - req.meta.deadline_s)
-            if self.enable_prefix_cache:
-                # every prompt K/V position is now written: index the
-                # blocks so later requests can attach this prefix (a
-                # resumed request publishes its resume prompt —
-                # original prompt + generated-so-far)
-                self.cache.publish_prefix(s["seq"], s["prompt"])
-            # per-request prefill phase for the trace assembler: starts
-            # at the request's FIRST chunk dispatch, ends now (its end
-            # timestamp IS the request's first-token time)
-            _tracing.event("prefill", request_id=req.rid,
-                           ts=s["t_pre0"], dur=t_now - s["t_pre0"],
-                           prompt_len=int(s["prompt"].size),
-                           seq=s["seq"], chunks=s["chunks"],
-                           cached_tokens=s["cached"], **self._tr(req))
+        with self._phase("emit"):
+            self.cache.swap_arrays(kc, vc)
+            self._dispatch_ok([self._slots[i]["req"].rid
+                               for i, *_ in plan
+                               if self._slots[i] is not None])
+            t_now = time.perf_counter()
+            self._charge_dispatch(t_now - t0, parts)
+            if self._ledger is not None:
+                # feed the measured prefill unit cost (EMA) — the rate the
+                # prefix-cache savings credit is priced at
+                self._ledger.note_prefill_cost(
+                    int((t_now - t0) * 1e9),
+                    int(sum(p[2] for p in plan)))
+            self._ops_progress += 1
+            if decoding:
+                _m_decode_stall.observe(t_now - t0)
+            _m_prefill_dispatches.inc()
+            # goodput: a resumed request's chunk re-feeds already-generated
+            # tokens (positions past its ORIGINAL prompt) — decoded work
+            # that emits nothing, accounted as preempt replay
+            replay = 0
+            for i, start, n, _o in plan:
+                req = self._slots[i]["req"]
+                if req.resume_ids is not None:
+                    replay += max(0, start + n - max(start, req.ids.size))
             with self._lock:
-                self._prefills += 1
-                self._decoded_tokens += 1  # the token-0 sample
-            _m_decoded.inc()
-            s["t_last"] = t_now
-            self._slot_token(i, int(tok_h[r]),
-                             device_stopped=bool(stopped_h[r]))
+                self._prefill_dispatches += 1
+                if replay:
+                    self._decoded_tokens += replay
+                    self._replayed_tokens += replay
+            if replay:
+                _m_decoded.inc(replay)
+                _m_replayed.inc(replay)
+            for i, start, n, o in plan:
+                s = self._slots[i]
+                s["fed"] = start + n
+                s["chunks"] += 1
+            for i, r in done_rows:
+                s = self._slots[i]
+                req = s["req"]
+                if req.ttft is None:
+                    # first token of the request's LIFETIME — a resumed
+                    # request keeps the TTFT of its first residency
+                    req.ttft = t_now - req.t_submit
+                    _m_ttft.observe(req.ttft)
+                    if self._slo is not None:
+                        self._slo_latency("ttft", req.ttft, req)
+                    with self._lock:
+                        self._ttft.append(req.ttft)
+                        if req.meta is not None:
+                            lane = req.meta.lane
+                            self._lane_ttft.setdefault(lane, []).append(
+                                req.ttft)
+                            if req.meta.deadline_s is not None:
+                                self._deadline_requests[lane] = \
+                                    self._deadline_requests.get(lane, 0) + 1
+                                if req.ttft > req.meta.deadline_s:
+                                    self._deadline_misses[lane] = \
+                                        self._deadline_misses.get(lane,
+                                                                  0) + 1
+                                    _m_deadline_miss.labels(lane=lane).inc()
+                                    _m_deadline_overage.observe(
+                                        req.ttft - req.meta.deadline_s)
+                if self.enable_prefix_cache:
+                    # every prompt K/V position is now written: index the
+                    # blocks so later requests can attach this prefix (a
+                    # resumed request publishes its resume prompt —
+                    # original prompt + generated-so-far)
+                    self.cache.publish_prefix(s["seq"], s["prompt"])
+                # per-request prefill phase for the trace assembler: starts
+                # at the request's FIRST chunk dispatch, ends now (its end
+                # timestamp IS the request's first-token time)
+                _tracing.event("prefill", request_id=req.rid,
+                               ts=s["t_pre0"], dur=t_now - s["t_pre0"],
+                               prompt_len=int(s["prompt"].size),
+                               seq=s["seq"], chunks=s["chunks"],
+                               cached_tokens=s["cached"], **self._tr(req))
+                with self._lock:
+                    self._prefills += 1
+                    self._decoded_tokens += 1  # the token-0 sample
+                _m_decoded.inc()
+                s["t_last"] = t_now
+                self._slot_token(i, int(tok_h[r]),
+                                 device_stopped=bool(stopped_h[r]))
 
     def _slot_token(self, i, tok, device_stopped=False):
         """Record one generated token for slot i; completes the request
@@ -3669,6 +3832,7 @@ class PagedGenerationServer:
                 req.future.set_result(out)
 
     def _loop(self):
+        self._phases.thread_started()
         try:
             self._loop_body()
         except Exception as e:  # noqa: BLE001 — an unhandled engine
@@ -3677,10 +3841,13 @@ class PagedGenerationServer:
             # degraded and the flight recorder dumps
             self._engine_exception("engine_loop", e)
             raise
+        finally:
+            self._phases.thread_stopped()
 
     def _loop_body(self):
         while True:
-            with self._lock:
+            self._phases.close_round()
+            with self._phase("admit"), self._lock:
                 if self._stop:
                     # async: resolve the in-flight round so no future
                     # is stranded mid-stream
@@ -3700,7 +3867,8 @@ class PagedGenerationServer:
                 self._admit_locked()
                 if all(s is None for s in self._slots):
                     self._drain_pending()  # defensive: no-op when idle
-                    self._lock.wait(timeout=0.1)
+                    with self._phase("idle_wait"):
+                        self._lock.wait(timeout=0.1)
                     continue
             if self._unified:
                 self._round_unified()
@@ -3712,17 +3880,18 @@ class PagedGenerationServer:
         paths: `mixed` marks a round that carried prefill AND
         decode/verify work — the rounds the unified kernel collapses
         from up to 3 dispatches to 1."""
-        with self._lock:
-            self._rounds += 1
-            self._round_dispatch_count += n_dispatches
-            if mixed:
-                self._mixed_rounds += 1
-            if self._slo is not None:
-                self._slo_goodput_round()
-        _m_round_dispatches.observe(float(n_dispatches))
-        # capacity auto-sampling (ISSUE 17): min-interval gated, so
-        # this is a near-free no-op on almost every round
-        self._maybe_sample_capacity()
+        with self._phase("emit"):
+            with self._lock:
+                self._rounds += 1
+                self._round_dispatch_count += n_dispatches
+                if mixed:
+                    self._mixed_rounds += 1
+                if self._slo is not None:
+                    self._slo_goodput_round()
+            _m_round_dispatches.observe(float(n_dispatches))
+            # capacity auto-sampling (ISSUE 17): min-interval gated, so
+            # this is a near-free no-op on almost every round
+            self._maybe_sample_capacity()
 
     def _round_split(self):
         """One scheduler round of the SPLIT path (the pre-r16 loop
@@ -3733,17 +3902,19 @@ class PagedGenerationServer:
         # ---- packed/chunked prefill: at most ONE chunk dispatch
         # per round, interleaved with the decode dispatch below, so
         # in-flight decode never stalls longer than one chunk budget
-        pre_idx = [i for i, s in enumerate(self._slots)
-                   if s is not None
-                   and s["fed"] < s["prompt"].size]
+        with self._phase("plan"):
+            pre_idx = [i for i, s in enumerate(self._slots)
+                       if s is not None
+                       and s["fed"] < s["prompt"].size]
         if pre_idx:
             self._prefill_packed(pre_idx)
-        _m_slots_busy.labels(server="paged").set(
-            sum(s is not None for s in self._slots))
-        # decode phase: prompt fully fed (first token sampled)
-        active_idx = [i for i, s in enumerate(self._slots)
-                      if s is not None
-                      and s["fed"] >= s["prompt"].size]
+        with self._phase("plan"):
+            _m_slots_busy.labels(server="paged").set(
+                sum(s is not None for s in self._slots))
+            # decode phase: prompt fully fed (first token sampled)
+            active_idx = [i for i, s in enumerate(self._slots)
+                          if s is not None
+                          and s["fed"] >= s["prompt"].size]
         if active_idx:
             # speculative decoding (round 11): eligible slots propose
             # drafts and take ONE packed verification dispatch instead
@@ -3761,7 +3932,8 @@ class PagedGenerationServer:
         # tier prefetch-ahead: promote the NEXT queued requests' cold
         # blocks now, before the coming round boundary's admission
         # pass runs attach_prefix (one `look` check when disabled)
-        self._tier_prefetch_tick()
+        with self._phase("admit"):
+            self._tier_prefetch_tick()
         d1 = (self._prefill_dispatches + self._steps
               + self._spec_dispatches)
         if d1 > d0:
@@ -3782,7 +3954,8 @@ class PagedGenerationServer:
         this round's outputs — so the host plan+dispatch work is
         hidden behind device execution, measured as overlap."""
         t0 = time.perf_counter()
-        plan = self._plan_round()
+        with self._phase("plan"):
+            plan = self._plan_round()
         outs = self._dispatch_round(plan) if plan is not None else None
         t1 = time.perf_counter()
         # tier prefetch-ahead: the dispatch above is in flight on
@@ -3790,7 +3963,8 @@ class PagedGenerationServer:
         # through this host-side window (the r16 async seam: the
         # overlapped work is pure host state, outside the overlap
         # measurement so the planner metric stays comparable)
-        self._tier_prefetch_tick()
+        with self._phase("admit"):
+            self._tier_prefetch_tick()
         if not self._async:
             if outs is not None:
                 self._process_round(plan, outs)
@@ -4034,203 +4208,214 @@ class PagedGenerationServer:
         `_process_round`. Returns the device output triple (vtok,
         accepted, stopped) or None after a dispatch failure (the
         plan's slots are failed and freed)."""
-        jnp = self._jnp
-        rows = plan["rows"]
-        # grow every row's table in one atomic call. Async step rows
-        # grow to the host UPPER BOUND on the device write horizon
-        # (the carry may be up to one emitted round ahead), capped by
-        # the admission reservation.
-        updates = []
-        for row in rows:
-            s = self._slots[row["slot"]]
-            if row["kind"] == "chunk":
-                updates.append((row["seq"], row["start"] + row["n"]))
-            else:
-                k = int(row["drafts"].size)
-                # the last known token writes at wpos, drafts at
-                # wpos+1..wpos+k (the split verify's horizon). Async:
-                # the device write front may be one emitted round
-                # ahead of wpos — grow by that bound too, capped at
-                # the admission reservation.
-                need = row["wpos"] + k + 1
-                if self._async:
-                    cap = s["pos"] + s["budget"] + self._overrun
-                    need = min(need + 1 + self._spec_k, cap)
-                updates.append((row["seq"], need))
-        self._recorder.record(
-            "round", packed=plan["T"], rows=len(rows),
-            chunk_rows=plan["n_chunk"], step_rows=plan["n_step"],
-            proposed=plan["n_drafts"],
-            free_blocks=self.cache.available_block_count)
-        # chunk rows weigh their fed tokens, step rows their verify
-        # positions (drafts + the step token) — the same work split
-        # the packed program computes
-        parts = self._cost_parts(
-            [(self._slots[row["slot"]]["req"],
-              row["n"] if row["kind"] == "chunk"
-              else row["drafts"].size + 1) for row in rows])
-        plan["cost_parts"] = parts  # _process_round charges its sync
-        self._attr_begin(parts)     # wait to the same rows
+        with self._phase("plan"):
+            jnp = self._jnp
+            rows = plan["rows"]
+            # grow every row's table in one atomic call. Async step rows
+            # grow to the host UPPER BOUND on the device write horizon
+            # (the carry may be up to one emitted round ahead), capped by
+            # the admission reservation.
+            updates = []
+            for row in rows:
+                s = self._slots[row["slot"]]
+                if row["kind"] == "chunk":
+                    updates.append((row["seq"], row["start"] + row["n"]))
+                else:
+                    k = int(row["drafts"].size)
+                    # the last known token writes at wpos, drafts at
+                    # wpos+1..wpos+k (the split verify's horizon). Async:
+                    # the device write front may be one emitted round
+                    # ahead of wpos — grow by that bound too, capped at
+                    # the admission reservation.
+                    need = row["wpos"] + k + 1
+                    if self._async:
+                        cap = s["pos"] + s["budget"] + self._overrun
+                        need = min(need + 1 + self._spec_k, cap)
+                    updates.append((row["seq"], need))
+            if self._recorder.enabled:
+                self._recorder.record(
+                    "round", packed=plan["T"], rows=len(rows),
+                    chunk_rows=plan["n_chunk"], step_rows=plan["n_step"],
+                    proposed=plan["n_drafts"],
+                    free_blocks=self.cache.available_block_count)
+            # chunk rows weigh their fed tokens, step rows their verify
+            # positions (drafts + the step token) — the same work split
+            # the packed program computes
+            parts = self._cost_parts(
+                [(self._slots[row["slot"]]["req"],
+                  row["n"] if row["kind"] == "chunk"
+                  else row["drafts"].size + 1) for row in rows])
+            plan["cost_parts"] = parts  # _process_round charges its sync
+            self._attr_begin(parts)     # wait to the same rows
+        self._phases.kind("unified")
         t0 = time.perf_counter()
         try:
             with _tracing.span(
                     "round", packed=plan["T"], segments=len(rows),
                     chunk_rows=plan["n_chunk"],
-                    step_rows=plan["n_step"],
+                    step_rows=plan["n_step"], round=self._phases.round,
                     request_ids=[self._slots[row["slot"]]["req"].rid
-                                 for row in rows], **self._rattr()):
-                self._maybe_fault("slow_dispatch")
-                self._maybe_fault("ensure_many")
-                self.cache.ensure_many(updates)
-                if self.enable_prefix_cache and plan["n_chunk"]:
-                    # CoW guard: a chunk starting mid-block in an
-                    # attached (shared or index-claimed) block gets a
-                    # private copy before the dispatch writes into it.
-                    # A copy SWAPS a block id without changing the
-                    # row's block count, so the table cache below
-                    # cannot key on it — drop it for CoW-risk rounds.
-                    for row in rows:
-                        if row["kind"] == "chunk":
-                            self.cache.prepare_write(row["seq"],
-                                                     row["start"])
-                    self._tables_cache = None
-                P = plan["P"]
-                seqs = tuple(rows[r]["seq"] if r < len(rows) else None
-                             for r in range(P))
-                # device-argument reuse: the table matrix changes only
-                # when a row's block count grows, and in ASYNC window
-                # rounds (steady-state decode — no chunk rows, inputs
-                # ride the carry) the ENTIRE plan argument set is
-                # invariant per (slot, seq, drafts) signature — most
-                # rounds then re-dispatch already-uploaded arrays and
-                # the host planner all but vanishes from the round
-                tkey = (seqs, tuple(self.cache.blocks_held(s)
-                                    if s is not None else 0
-                                    for s in seqs))
-                if self._tables_cache is not None \
-                        and self._tables_cache[0] == tkey:
-                    tables = self._tables_cache[1]
-                else:
-                    tables = jnp.asarray(self.cache.table_array(
-                        list(seqs), self._m_width))
-                    self._tables_cache = (tkey, tables)
-                dev = akey = None
-                if plan.get("cached"):
-                    dev = self._args_cache[1]
-                elif self._async and plan["window"]:
-                    akey = (plan["T"], P, tuple(
-                        (row["slot"], row["seq"],
-                         row["drafts"].tobytes()) for row in rows))
-                    if self._args_cache is not None \
-                            and self._args_cache[0] == akey:
+                                 for row in rows]
+                    if _tracing.enabled() else (), **self._rattr()):
+                with self._phase("plan"):
+                    self._maybe_fault("slow_dispatch")
+                    self._maybe_fault("ensure_many")
+                    self.cache.ensure_many(updates)
+                    if self.enable_prefix_cache and plan["n_chunk"]:
+                        # CoW guard: a chunk starting mid-block in an
+                        # attached (shared or index-claimed) block gets a
+                        # private copy before the dispatch writes into it.
+                        # A copy SWAPS a block id without changing the
+                        # row's block count, so the table cache below
+                        # cannot key on it — drop it for CoW-risk rounds.
+                        for row in rows:
+                            if row["kind"] == "chunk":
+                                self.cache.prepare_write(row["seq"],
+                                                         row["start"])
+                        self._tables_cache = None
+                    P = plan["P"]
+                    seqs = tuple(rows[r]["seq"] if r < len(rows) else None
+                                 for r in range(P))
+                    # device-argument reuse: the table matrix changes only
+                    # when a row's block count grows, and in ASYNC window
+                    # rounds (steady-state decode — no chunk rows, inputs
+                    # ride the carry) the ENTIRE plan argument set is
+                    # invariant per (slot, seq, drafts) signature — most
+                    # rounds then re-dispatch already-uploaded arrays and
+                    # the host planner all but vanishes from the round
+                    tkey = (seqs, tuple(self.cache.blocks_held(s)
+                                        if s is not None else 0
+                                        for s in seqs))
+                    tables = tables_h = None
+                    if self._tables_cache is not None \
+                            and self._tables_cache[0] == tkey:
+                        tables = self._tables_cache[1]
+                    else:
+                        tables_h = self.cache.table_array(
+                            list(seqs), self._m_width)
+                    dev = akey = None
+                    if plan.get("cached"):
                         dev = self._args_cache[1]
-                if dev is None:
-                    slot_rows = [rows[r]["slot"] if r < len(rows)
-                                 else None for r in range(P)]
-                    sp_args, sp_mode = self._sp_store.unified_args(
-                        slot_rows, plan["emit_rows"], plan["steps"])
-                    dev = {
-                        "toks": jnp.asarray(plan["toks"]),
-                        "seg": jnp.asarray(plan["seg"]),
-                        "pos": jnp.asarray(plan["pos"]),
-                        "sample_idx": jnp.asarray(plan["sample_idx"]),
-                        "dlen": jnp.asarray(plan["dlen"]),
-                        "row_slot": jnp.asarray(plan["row_slot"]),
-                        "carry_map": jnp.asarray(plan["carry_map"]),
-                        "pos_map": jnp.asarray(plan["pos_map"]),
-                        "steps_map": jnp.asarray(plan["steps_map"]),
-                        "sp": sp_args, "mode": sp_mode,
-                    }
-                    if akey is not None:
-                        self._args_cache = (akey, dev)
-                sp_args, sp_mode = dev["sp"], dev["mode"]
-                if sp_mode[1]:
-                    # the penalty count buffer round-trips through the
-                    # dispatch — refresh that one leaf per round
-                    sp_args = dict(sp_args,
-                                   counts=self._sp_store.counts)
-                if self._async:
-                    ct, cp, cs = self._carry
-                else:
-                    ct, cp, cs = self._zero_carry_arrays()
-                self._maybe_fault("unified_round")
-                (vtok, accepted, stopped, kc, vc, counts, nct, ncp,
-                 ncs) = self._decoder.unified_round(
-                    self._params, dev["toks"], dev["seg"], dev["pos"],
-                    tables, dev["sample_idx"], dev["dlen"],
-                    dev["row_slot"], dev["carry_map"], dev["pos_map"],
-                    dev["steps_map"], ct, cp, cs,
-                    self.cache.k_blocks, self.cache.v_blocks, sp_args,
-                    sp_mode, window=plan["window"])
+                    elif self._async and plan["window"]:
+                        akey = (plan["T"], P, tuple(
+                            (row["slot"], row["seq"],
+                             row["drafts"].tobytes()) for row in rows))
+                        if self._args_cache is not None \
+                                and self._args_cache[0] == akey:
+                            dev = self._args_cache[1]
+                    if dev is None:
+                        slot_rows = [rows[r]["slot"] if r < len(rows)
+                                     else None for r in range(P)]
+                        sp_args, sp_mode = self._sp_store.unified_args(
+                            slot_rows, plan["emit_rows"], plan["steps"])
+                with self._phase("dispatch"):
+                    if tables is None:
+                        tables = jnp.asarray(tables_h)
+                        self._tables_cache = (tkey, tables)
+                    if dev is None:
+                        dev = {
+                            "toks": jnp.asarray(plan["toks"]),
+                            "seg": jnp.asarray(plan["seg"]),
+                            "pos": jnp.asarray(plan["pos"]),
+                            "sample_idx": jnp.asarray(plan["sample_idx"]),
+                            "dlen": jnp.asarray(plan["dlen"]),
+                            "row_slot": jnp.asarray(plan["row_slot"]),
+                            "carry_map": jnp.asarray(plan["carry_map"]),
+                            "pos_map": jnp.asarray(plan["pos_map"]),
+                            "steps_map": jnp.asarray(plan["steps_map"]),
+                            "sp": sp_args, "mode": sp_mode,
+                        }
+                        if akey is not None:
+                            self._args_cache = (akey, dev)
+                    sp_args, sp_mode = dev["sp"], dev["mode"]
+                    if sp_mode[1]:
+                        # the penalty count buffer round-trips through
+                        # the dispatch — refresh that one leaf per round
+                        sp_args = dict(sp_args,
+                                       counts=self._sp_store.counts)
+                    if self._async:
+                        ct, cp, cs = self._carry
+                    else:
+                        ct, cp, cs = self._zero_carry_arrays()
+                    self._maybe_fault("unified_round")
+                    (vtok, accepted, stopped, kc, vc, counts, nct, ncp,
+                     ncs) = self._decoder.unified_round(
+                        self._params, dev["toks"], dev["seg"], dev["pos"],
+                        tables, dev["sample_idx"], dev["dlen"],
+                        dev["row_slot"], dev["carry_map"],
+                        dev["pos_map"], dev["steps_map"], ct, cp, cs,
+                        self.cache.k_blocks, self.cache.v_blocks,
+                        sp_args, sp_mode, window=plan["window"])
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
             self._carry = None
             self._dispatch_failure("unified_round", e,
                                    [row["slot"] for row in rows])
             return None
-        self._sp_store.swap_counts(counts)
-        self.cache.swap_arrays(kc, vc)
-        self._dispatch_ok([self._slots[row["slot"]]["req"].rid
-                           for row in rows
-                           if self._slots[row["slot"]] is not None])
-        if self._async:
-            self._carry = (nct, ncp, ncs)
-        self._charge_dispatch(time.perf_counter() - t0, parts)
-        if self._ledger is not None and plan["n_chunk"]:
-            chunk_toks = sum(row["n"] for row in rows
-                             if row["kind"] == "chunk")
-            self._ledger.note_prefill_cost(
-                int((time.perf_counter() - t0) * 1e9), chunk_toks)
-        self._ops_progress += 1
-        # host-deterministic bookkeeping (valid before any sync): fed
-        # positions advance, dispatch/mode counters, spec proposals
-        replay = 0
-        for row in rows:
-            if row["kind"] != "chunk":
-                continue
-            s = self._slots[row["slot"]]
-            s["fed"] = row["start"] + row["n"]
-            s["chunks"] += 1
-            req = s["req"]
-            if req.resume_ids is not None:
-                # a resumed request's chunk re-feeds already-generated
-                # tokens — decoded work that emits nothing
-                replay += max(0, row["start"] + row["n"]
-                              - max(row["start"], req.ids.size))
-        sampled = bool(sp_mode[0])
-        with self._lock:
+        with self._phase("emit"):
+            self._sp_store.swap_counts(counts)
+            self.cache.swap_arrays(kc, vc)
+            self._dispatch_ok([self._slots[row["slot"]]["req"].rid
+                               for row in rows
+                               if self._slots[row["slot"]] is not None])
+            if self._async:
+                self._carry = (nct, ncp, ncs)
+            self._charge_dispatch(time.perf_counter() - t0, parts)
+            if self._ledger is not None and plan["n_chunk"]:
+                chunk_toks = sum(row["n"] for row in rows
+                                 if row["kind"] == "chunk")
+                self._ledger.note_prefill_cost(
+                    int((time.perf_counter() - t0) * 1e9), chunk_toks)
+            self._ops_progress += 1
+            # host-deterministic bookkeeping (valid before any sync): fed
+            # positions advance, dispatch/mode counters, spec proposals
+            replay = 0
+            for row in rows:
+                if row["kind"] != "chunk":
+                    continue
+                s = self._slots[row["slot"]]
+                s["fed"] = row["start"] + row["n"]
+                s["chunks"] += 1
+                req = s["req"]
+                if req.resume_ids is not None:
+                    # a resumed request's chunk re-feeds already-generated
+                    # tokens — decoded work that emits nothing
+                    replay += max(0, row["start"] + row["n"]
+                                  - max(row["start"], req.ids.size))
+            sampled = bool(sp_mode[0])
+            with self._lock:
+                if plan["n_chunk"]:
+                    self._prefill_dispatches += 1
+                if plan["n_step"]:
+                    self._steps += 1
+                    self._active_integral += plan["n_step"]
+                    self._fill_integral += self.cache.block_fill()
+                if sampled:
+                    self._sampled_dispatches += 1
+                else:
+                    self._fastpath_dispatches += 1
+                if plan["n_drafts"]:
+                    self._spec_dispatches += 1
+                    self._spec_proposed += plan["n_drafts"]
+                    self._spec_rounds_per_slot += sum(
+                        1 for row in rows if row["kind"] == "step"
+                        and row["drafts"].size)
+                if replay:
+                    self._decoded_tokens += replay
+                    self._replayed_tokens += replay
             if plan["n_chunk"]:
-                self._prefill_dispatches += 1
-            if plan["n_step"]:
-                self._steps += 1
-                self._active_integral += plan["n_step"]
-                self._fill_integral += self.cache.block_fill()
-            if sampled:
-                self._sampled_dispatches += 1
-            else:
-                self._fastpath_dispatches += 1
+                _m_prefill_dispatches.inc()
             if plan["n_drafts"]:
-                self._spec_dispatches += 1
-                self._spec_proposed += plan["n_drafts"]
-                self._spec_rounds_per_slot += sum(
-                    1 for row in rows if row["kind"] == "step"
-                    and row["drafts"].size)
+                _m_spec_verify.inc()
+                _m_spec_proposed.inc(plan["n_drafts"])
+            (_m_sampling_sampled if sampled else _m_sampling_fast).inc()
             if replay:
-                self._decoded_tokens += replay
-                self._replayed_tokens += replay
-        if plan["n_chunk"]:
-            _m_prefill_dispatches.inc()
-        if plan["n_drafts"]:
-            _m_spec_verify.inc()
-            _m_spec_proposed.inc(plan["n_drafts"])
-        (_m_sampling_sampled if sampled else _m_sampling_fast).inc()
-        if replay:
-            _m_decoded.inc(replay)
-            _m_replayed.inc(replay)
-        _m_slots_busy.labels(server="paged").set(
-            sum(s is not None for s in self._slots))
-        self._note_round(1, mixed=bool(plan["n_chunk"]
-                                       and plan["n_step"]))
+                _m_decoded.inc(replay)
+                _m_replayed.inc(replay)
+            _m_slots_busy.labels(server="paged").set(
+                sum(s is not None for s in self._slots))
+            self._note_round(1, mixed=bool(plan["n_chunk"]
+                                           and plan["n_step"]))
         return (vtok, accepted, stopped)
 
     def _process_round(self, plan, outs):
@@ -4240,260 +4425,273 @@ class PagedGenerationServer:
         was freed since planning (async overshoot past a stop the host
         had not yet seen) are discarded as replay, token-identically
         to the split path."""
+        self._phases.kind("unified")
         t_sync0 = time.perf_counter()
-        vtok_h = np.asarray(outs[0])
-        acc_h = np.asarray(outs[1])
-        stop_h = np.asarray(outs[2])
-        t_now = time.perf_counter()
-        # async: the asarray above is where the host actually waits on
-        # the device — busy time the dispatch-site charge missed
-        self._charge_dispatch(t_now - t_sync0,
-                              plan.get("cost_parts") or ())
-        self._ops_progress += 1
-        decoded = 0
-        discarded = 0
-        rolled = 0
-        accepted_n = 0
-        itl_updates = []
-        for r, row in enumerate(plan["rows"]):
-            i = row["slot"]
-            s = self._slots[i]
-            live = s is not None and s["seq"] == row["seq"]
-            if row["kind"] == "chunk":
-                if not row["done"]:
+        with self._phase("read_back"):
+            vtok_h = np.asarray(outs[0])
+            acc_h = np.asarray(outs[1])
+            stop_h = np.asarray(outs[2])
+        with self._phase("emit"):
+            t_now = time.perf_counter()
+            # async: the asarray above is where the host actually waits on
+            # the device — busy time the dispatch-site charge missed
+            self._charge_dispatch(t_now - t_sync0,
+                                  plan.get("cost_parts") or ())
+            self._ops_progress += 1
+            decoded = 0
+            discarded = 0
+            rolled = 0
+            accepted_n = 0
+            itl_updates = []
+            for r, row in enumerate(plan["rows"]):
+                i = row["slot"]
+                s = self._slots[i]
+                live = s is not None and s["seq"] == row["seq"]
+                if row["kind"] == "chunk":
+                    if not row["done"]:
+                        continue
+                    decoded += 1
+                    if not live:
+                        discarded += 1
+                        continue
+                    req = s["req"]
+                    if req.ttft is None:
+                        # first token of the request's LIFETIME — a resumed
+                        # request keeps the TTFT of its first residency
+                        req.ttft = t_now - req.t_submit
+                        _m_ttft.observe(req.ttft)
+                        if self._slo is not None:
+                            self._slo_latency("ttft", req.ttft, req)
+                        with self._lock:
+                            self._ttft.append(req.ttft)
+                            if req.meta is not None:
+                                lane = req.meta.lane
+                                self._lane_ttft.setdefault(
+                                    lane, []).append(req.ttft)
+                                if req.meta.deadline_s is not None:
+                                    self._deadline_requests[lane] = \
+                                        self._deadline_requests.get(
+                                            lane, 0) + 1
+                                    if req.ttft > req.meta.deadline_s:
+                                        self._deadline_misses[lane] = \
+                                            self._deadline_misses.get(
+                                                lane, 0) + 1
+                                        _m_deadline_miss.labels(
+                                            lane=lane).inc()
+                                        _m_deadline_overage.observe(
+                                            req.ttft - req.meta.deadline_s)
+                    if self.enable_prefix_cache:
+                        self.cache.publish_prefix(s["seq"], s["prompt"])
+                    _tracing.event("prefill", request_id=req.rid,
+                                   ts=s["t_pre0"],
+                                   dur=t_now - s["t_pre0"],
+                                   prompt_len=int(s["prompt"].size),
+                                   seq=s["seq"], chunks=s["chunks"],
+                                   cached_tokens=s["cached"])
+                    with self._lock:
+                        self._prefills += 1
+                    s["t_last"] = t_now
+                    self._slot_token(i, int(vtok_h[r, 0]),
+                                     device_stopped=bool(stop_h[r, 0]))
                     continue
-                decoded += 1
+                # decode / verify row
+                a = int(acc_h[r])
+                k_r = int(row["drafts"].size)
+                decoded += k_r + 1
                 if not live:
+                    # async overshoot: the device ran one extra round for a
+                    # slot the host has since stopped — pure replay, plus
+                    # its drafts count as rolled back (conservation:
+                    # proposed == accepted + rolled_back)
+                    rolled += k_r
                     discarded += 1
                     continue
-                req = s["req"]
-                if req.ttft is None:
-                    # first token of the request's LIFETIME — a resumed
-                    # request keeps the TTFT of its first residency
-                    req.ttft = t_now - req.t_submit
-                    _m_ttft.observe(req.ttft)
-                    if self._slo is not None:
-                        self._slo_latency("ttft", req.ttft, req)
-                    with self._lock:
-                        self._ttft.append(req.ttft)
-                        if req.meta is not None:
-                            lane = req.meta.lane
-                            self._lane_ttft.setdefault(
-                                lane, []).append(req.ttft)
-                            if req.meta.deadline_s is not None:
-                                self._deadline_requests[lane] = \
-                                    self._deadline_requests.get(
-                                        lane, 0) + 1
-                                if req.ttft > req.meta.deadline_s:
-                                    self._deadline_misses[lane] = \
-                                        self._deadline_misses.get(
-                                            lane, 0) + 1
-                                    _m_deadline_miss.labels(
-                                        lane=lane).inc()
-                                    _m_deadline_overage.observe(
-                                        req.ttft - req.meta.deadline_s)
-                if self.enable_prefix_cache:
-                    self.cache.publish_prefix(s["seq"], s["prompt"])
-                _tracing.event("prefill", request_id=req.rid,
-                               ts=s["t_pre0"],
-                               dur=t_now - s["t_pre0"],
-                               prompt_len=int(s["prompt"].size),
-                               seq=s["seq"], chunks=s["chunks"],
-                               cached_tokens=s["cached"])
-                with self._lock:
-                    self._prefills += 1
-                s["t_last"] = t_now
-                self._slot_token(i, int(vtok_h[r, 0]),
-                                 device_stopped=bool(stop_h[r, 0]))
-                continue
-            # decode / verify row
-            a = int(acc_h[r])
-            k_r = int(row["drafts"].size)
-            decoded += k_r + 1
-            if not live:
-                # async overshoot: the device ran one extra round for a
-                # slot the host has since stopped — pure replay, plus
-                # its drafts count as rolled back (conservation:
-                # proposed == accepted + rolled_back)
-                rolled += k_r
-                discarded += 1
-                continue
-            if k_r and not self._async:
-                # rollback FIRST (while the sequence still exists); the
-                # async chain instead overwrites rejected positions at
-                # the next rounds' write front (see docs/SERVING.md)
-                self.cache.truncate_seq(s["seq"],
-                                        row["wpos"] + a + 1)
-            if k_r:
-                rolled += k_r - a
-                accepted_n += a
-                _m_spec_accepted.inc(a)
-                _m_spec_accept_rate.observe(a / k_r)
-                _tracing.event("spec_round", request_id=s["req"].rid,
-                               proposed=k_r, accepted=a,
-                               rolled_back=k_r - a)
-            t_prev = s["t_last"] if s["t_last"] is not None else t_now
-            consumed = 0
-            for jj in range(a + 1):
-                consumed += 1
-                self._slot_token(i, int(vtok_h[r, jj]),
-                                 device_stopped=bool(stop_h[r, jj]))
-                if self._slots[i] is None:  # stopped mid-prefix
-                    break
-            discarded += (a + 1) - consumed
-            if self._slots[i] is not None:
-                self._slots[i]["t_last"] = t_now
-            per = max(t_now - t_prev, 0.0) / consumed
-            lane = (s["req"].meta.lane if s["req"].meta is not None
-                    else None)
-            itl_updates.append((per, consumed, lane))
-            if self._slo is not None:
-                self._slo_latency("itl", per, s["req"], n=consumed)
-            for _ in range(consumed):
-                _m_itl.observe(per)
-        with self._lock:
-            for per, consumed, lane in itl_updates:
-                self._itl.extend([per] * consumed)
-                if lane is not None:
-                    self._lane_itl.setdefault(lane, []).extend(
-                        [per] * consumed)
-            self._decoded_tokens += decoded
-            self._spec_accepted += accepted_n
-            self._spec_rolled_back += rolled
+                if k_r and not self._async:
+                    # rollback FIRST (while the sequence still exists); the
+                    # async chain instead overwrites rejected positions at
+                    # the next rounds' write front (see docs/SERVING.md)
+                    self.cache.truncate_seq(s["seq"],
+                                            row["wpos"] + a + 1)
+                if k_r:
+                    rolled += k_r - a
+                    accepted_n += a
+                    _m_spec_accepted.inc(a)
+                    _m_spec_accept_rate.observe(a / k_r)
+                    _tracing.event("spec_round", request_id=s["req"].rid,
+                                   proposed=k_r, accepted=a,
+                                   rolled_back=k_r - a)
+                t_prev = s["t_last"] if s["t_last"] is not None else t_now
+                consumed = 0
+                for jj in range(a + 1):
+                    consumed += 1
+                    self._slot_token(i, int(vtok_h[r, jj]),
+                                     device_stopped=bool(stop_h[r, jj]))
+                    if self._slots[i] is None:  # stopped mid-prefix
+                        break
+                discarded += (a + 1) - consumed
+                if self._slots[i] is not None:
+                    self._slots[i]["t_last"] = t_now
+                per = max(t_now - t_prev, 0.0) / consumed
+                lane = (s["req"].meta.lane if s["req"].meta is not None
+                        else None)
+                itl_updates.append((per, consumed, lane))
+                if self._slo is not None:
+                    self._slo_latency("itl", per, s["req"], n=consumed)
+                for _ in range(consumed):
+                    _m_itl.observe(per)
+            with self._lock:
+                for per, consumed, lane in itl_updates:
+                    self._itl.extend([per] * consumed)
+                    if lane is not None:
+                        self._lane_itl.setdefault(lane, []).extend(
+                            [per] * consumed)
+                self._decoded_tokens += decoded
+                self._spec_accepted += accepted_n
+                self._spec_rolled_back += rolled
+                if discarded:
+                    self._replayed_tokens += discarded
+            _m_decoded.inc(decoded)
+            if rolled:
+                _m_spec_rolled_back.inc(rolled)
             if discarded:
-                self._replayed_tokens += discarded
-        _m_decoded.inc(decoded)
-        if rolled:
-            _m_spec_rolled_back.inc(rolled)
-        if discarded:
-            _m_replayed.inc(discarded)
-        _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
+                _m_replayed.inc(discarded)
+            _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
 
     def _decode_plain(self, active_idx):
         """One plain decode dispatch (k tokens per slot with multi-step
         scheduling) for the given decode-phase slots — the pre-round-11
         decode body, extracted so the scheduler can interleave it with
         the speculative verify dispatch."""
-        jnp = self._jnp
-        k = self.steps_per_dispatch
-        tok = np.zeros((self.max_slots,), np.int32)
-        pos = np.zeros((self.max_slots,), np.int32)
-        act = np.zeros((self.max_slots,), bool)
-        steps = np.zeros((self.max_slots,), np.int32)
-        for i in active_idx:
-            s = self._slots[i]
-            tok[i] = s["toks"][-1]
-            pos[i] = s["pos"] + len(s["toks"]) - 1
-            act[i] = True
-            steps[i] = len(s["toks"])  # PRNG step counter
-        # per-slot sampling buffers + the static dispatch mode: ONE
-        # jitted dispatch serves the whole mixed batch; all-greedy
-        # residents take the argmax fast path
-        sp_args, sp_mode = self._sp_store.step_args(steps)
-        if sp_mode[0]:
-            _m_sampling_sampled.inc()
-        else:
-            _m_sampling_fast.inc()
-        with self._lock:
+        with self._phase("plan"):
+            jnp = self._jnp
+            k = self.steps_per_dispatch
+            tok = np.zeros((self.max_slots,), np.int32)
+            pos = np.zeros((self.max_slots,), np.int32)
+            act = np.zeros((self.max_slots,), bool)
+            steps = np.zeros((self.max_slots,), np.int32)
+            for i in active_idx:
+                s = self._slots[i]
+                tok[i] = s["toks"][-1]
+                pos[i] = s["pos"] + len(s["toks"]) - 1
+                act[i] = True
+                steps[i] = len(s["toks"])  # PRNG step counter
+            # per-slot sampling buffers + the static dispatch mode: ONE
+            # jitted dispatch serves the whole mixed batch; all-greedy
+            # residents take the argmax fast path
+            sp_args, sp_mode = self._sp_store.step_args(steps)
             if sp_mode[0]:
-                self._sampled_dispatches += 1
+                _m_sampling_sampled.inc()
             else:
-                self._fastpath_dispatches += 1
-        self._recorder.record(
-            "decode_dispatch", slots=len(active_idx), k=k,
-            sampled=bool(sp_mode[0]),
-            free_blocks=self.cache.available_block_count)
-        parts = self._cost_parts(
-            [(self._slots[i]["req"], k) for i in active_idx])
-        self._attr_begin(parts)
+                _m_sampling_fast.inc()
+            with self._lock:
+                if sp_mode[0]:
+                    self._sampled_dispatches += 1
+                else:
+                    self._fastpath_dispatches += 1
+            if self._recorder.enabled:
+                self._recorder.record(
+                    "decode_dispatch", slots=len(active_idx), k=k,
+                    sampled=bool(sp_mode[0]),
+                    free_blocks=self.cache.available_block_count)
+            parts = self._cost_parts(
+                [(self._slots[i]["req"], k) for i in active_idx])
+            self._attr_begin(parts)
+        self._phases.kind("decode")
         t0 = time.perf_counter()
         try:
             with _tracing.span(
-                    "decode_dispatch", k=k,
+                    "decode_dispatch", k=k, round=self._phases.round,
                     request_ids=[self._slots[i]["req"].rid
-                                 for i in active_idx], **self._rattr()):
-                self._maybe_fault("slow_dispatch")
-                self._maybe_fault("ensure_many")
-                # grow tables for the incoming token(s) BEFORE the
-                # step writes them (k tokens starting at the feed
-                # position) — inside the try so a pool error takes the
-                # recovery path instead of killing the engine thread
-                self.cache.ensure_many(
-                    [(self._slots[i]["seq"], self._slots[i]["pos"]
-                      + len(self._slots[i]["toks"]) - 1 + k)
-                     for i in active_idx])
-                tables = jnp.asarray(self.cache.table_array(
-                    [s["seq"] if s is not None else None
-                     for s in self._slots], self._m_width))
-                self._maybe_fault("decode")
-                if k == 1:
-                    nxt, stopped, kc, vc, counts = \
-                        self._decoder.step(
-                            self._params, jnp.asarray(tok),
-                            jnp.asarray(pos), jnp.asarray(act),
-                            tables, self.cache.k_blocks,
-                            self.cache.v_blocks, sp_args, sp_mode)
-                    toks = np.asarray(nxt)[None]   # [1, S]
-                    stops = np.asarray(stopped)[None]
-                else:
-                    toks, stopped, kc, vc, counts = \
-                        self._decoder.multistep(k, sp_mode)(
-                            self._params, jnp.asarray(tok),
-                            jnp.asarray(pos), jnp.asarray(act),
-                            tables, self.cache.k_blocks,
-                            self.cache.v_blocks, sp_args)
-                    toks = np.asarray(toks)        # [k, S]
+                                 for i in active_idx]
+                    if _tracing.enabled() else (), **self._rattr()):
+                with self._phase("plan"):
+                    self._maybe_fault("slow_dispatch")
+                    self._maybe_fault("ensure_many")
+                    # grow tables for the incoming token(s) BEFORE the
+                    # step writes them (k tokens starting at the feed
+                    # position) — inside the try so a pool error takes
+                    # the recovery path instead of killing the engine
+                    # thread
+                    self.cache.ensure_many(
+                        [(self._slots[i]["seq"], self._slots[i]["pos"]
+                          + len(self._slots[i]["toks"]) - 1 + k)
+                         for i in active_idx])
+                    tables = self.cache.table_array(
+                        [s["seq"] if s is not None else None
+                         for s in self._slots], self._m_width)
+                with self._phase("dispatch"):
+                    self._maybe_fault("decode")
+                    tables = jnp.asarray(tables)
+                    if k == 1:
+                        toks, stopped, kc, vc, counts = \
+                            self._decoder.step(
+                                self._params, jnp.asarray(tok),
+                                jnp.asarray(pos), jnp.asarray(act),
+                                tables, self.cache.k_blocks,
+                                self.cache.v_blocks, sp_args, sp_mode)
+                    else:
+                        toks, stopped, kc, vc, counts = \
+                            self._decoder.multistep(k, sp_mode)(
+                                self._params, jnp.asarray(tok),
+                                jnp.asarray(pos), jnp.asarray(act),
+                                tables, self.cache.k_blocks,
+                                self.cache.v_blocks, sp_args)
+                with self._phase("read_back"):
+                    toks = np.asarray(toks)        # [S], or [k, S]
                     stops = np.asarray(stopped)
+                    if k == 1:
+                        toks, stops = toks[None], stops[None]  # [1, S]
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
             self._dispatch_failure("decode", e, list(active_idx))
             return
-        self._sp_store.swap_counts(counts)
-        self.cache.swap_arrays(kc, vc)
-        self._dispatch_ok([self._slots[i]["req"].rid
-                           for i in active_idx
-                           if self._slots[i] is not None])
-        t_now = time.perf_counter()
-        self._charge_dispatch(t_now - t0, parts)
-        self._ops_progress += 1
-        decoded = toks.shape[0] * len(active_idx)
-        discarded = 0
-        with self._lock:
-            self._steps += 1
-            self._active_integral += len(active_idx)
-            self._fill_integral += self.cache.block_fill()
-            self._decoded_tokens += decoded
-        _m_decoded.inc(decoded)
-        for i in active_idx:
-            s = self._slots[i]
-            t_prev = s["t_last"] if s["t_last"] is not None else t_now
-            consumed = 0
-            for j in range(toks.shape[0]):
-                consumed += 1
-                self._slot_token(i, int(toks[j, i]),
-                                 device_stopped=bool(stops[j, i]))
-                if self._slots[i] is None:  # finished mid-scan: the
-                    break  # remaining scan tokens are discarded
-            discarded += toks.shape[0] - consumed  # multi-step overrun
-            if self._slots[i] is not None:
-                self._slots[i]["t_last"] = t_now
-            # ITL: the dispatch's host-visible gap amortized over
-            # the tokens it emitted for this slot
-            per = max(t_now - t_prev, 0.0) / consumed
+        with self._phase("emit"):
+            self._sp_store.swap_counts(counts)
+            self.cache.swap_arrays(kc, vc)
+            self._dispatch_ok([self._slots[i]["req"].rid
+                               for i in active_idx
+                               if self._slots[i] is not None])
+            t_now = time.perf_counter()
+            self._charge_dispatch(t_now - t0, parts)
+            self._ops_progress += 1
+            decoded = toks.shape[0] * len(active_idx)
+            discarded = 0
             with self._lock:
-                self._itl.extend([per] * consumed)
-                if s["req"].meta is not None:
-                    self._lane_itl.setdefault(
-                        s["req"].meta.lane, []).extend([per] * consumed)
-            if self._slo is not None:
-                self._slo_latency("itl", per, s["req"], n=consumed)
-            for _ in range(consumed):
-                _m_itl.observe(per)
-        if discarded:
-            with self._lock:
-                self._replayed_tokens += discarded
-            _m_replayed.inc(discarded)
-        _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
+                self._steps += 1
+                self._active_integral += len(active_idx)
+                self._fill_integral += self.cache.block_fill()
+                self._decoded_tokens += decoded
+            _m_decoded.inc(decoded)
+            for i in active_idx:
+                s = self._slots[i]
+                t_prev = s["t_last"] if s["t_last"] is not None else t_now
+                consumed = 0
+                for j in range(toks.shape[0]):
+                    consumed += 1
+                    self._slot_token(i, int(toks[j, i]),
+                                     device_stopped=bool(stops[j, i]))
+                    if self._slots[i] is None:  # finished mid-scan: the
+                        break  # remaining scan tokens are discarded
+                discarded += toks.shape[0] - consumed  # multi-step overrun
+                if self._slots[i] is not None:
+                    self._slots[i]["t_last"] = t_now
+                # ITL: the dispatch's host-visible gap amortized over
+                # the tokens it emitted for this slot
+                per = max(t_now - t_prev, 0.0) / consumed
+                with self._lock:
+                    self._itl.extend([per] * consumed)
+                    if s["req"].meta is not None:
+                        self._lane_itl.setdefault(
+                            s["req"].meta.lane, []).extend([per] * consumed)
+                if self._slo is not None:
+                    self._slo_latency("itl", per, s["req"], n=consumed)
+                for _ in range(consumed):
+                    _m_itl.observe(per)
+            if discarded:
+                with self._lock:
+                    self._replayed_tokens += discarded
+                _m_replayed.inc(discarded)
+            _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
 
     def _speculate(self, active_idx):
         """Propose drafts for every eligible decode-phase slot; when
@@ -4511,29 +4709,30 @@ class PagedGenerationServer:
         token for its context."""
         from ..spec_decode import build_verify_plan
 
-        entries = []
-        any_drafts = False
-        empty = np.empty((0,), np.int32)
-        for i in active_idx:
-            s = self._slots[i]
-            remaining = s["budget"] - len(s["toks"])
-            kcap = min(self._spec_k, remaining - 1)
-            drafts = empty
-            if kcap >= 1:
-                ctx = np.concatenate(
-                    [s["req"].ids, np.asarray(s["toks"], np.int32)])
-                drafts = np.asarray(self._drafter.propose(ctx, kcap),
-                                    np.int32).reshape(-1)[:kcap]
-            if drafts.size:
-                any_drafts = True
-            wpos = s["pos"] + len(s["toks"]) - 1
-            entries.append((i, s["toks"][-1], wpos, len(s["toks"]),
-                            drafts))
-        if not any_drafts:
-            return ()
-        plan = build_verify_plan(entries, self._spec_k,
-                                 self._verify_align,
-                                 min_rows=self.max_slots)
+        with self._phase("plan"):
+            entries = []
+            any_drafts = False
+            empty = np.empty((0,), np.int32)
+            for i in active_idx:
+                s = self._slots[i]
+                remaining = s["budget"] - len(s["toks"])
+                kcap = min(self._spec_k, remaining - 1)
+                drafts = empty
+                if kcap >= 1:
+                    ctx = np.concatenate(
+                        [s["req"].ids, np.asarray(s["toks"], np.int32)])
+                    drafts = np.asarray(self._drafter.propose(ctx, kcap),
+                                        np.int32).reshape(-1)[:kcap]
+                if drafts.size:
+                    any_drafts = True
+                wpos = s["pos"] + len(s["toks"]) - 1
+                entries.append((i, s["toks"][-1], wpos, len(s["toks"]),
+                                drafts))
+            if not any_drafts:
+                return ()
+            plan = build_verify_plan(entries, self._spec_k,
+                                     self._verify_align,
+                                     min_rows=self.max_slots)
         self._verify_packed(plan)
         return set(plan.slots)
 
@@ -4546,125 +4745,134 @@ class PagedGenerationServer:
         token) feed the normal `_slot_token` path; rejected tail
         positions roll the paged cache back via
         `PagedKVCache.truncate_seq`."""
-        jnp = self._jnp
-        proposed = int(sum(d.size for d in plan.drafts))
-        with self._lock:
-            self._spec_proposed += proposed
-            self._spec_rounds_per_slot += sum(
-                1 for d in plan.drafts if d.size)
-        _m_spec_proposed.inc(proposed)
-        self._recorder.record(
-            "verify_dispatch", rows=plan.rows, proposed=proposed,
-            free_blocks=self.cache.available_block_count)
-        P = plan.dlen.shape[0]
-        parts = self._cost_parts(
-            [(self._slots[i]["req"], plan.drafts[r].size + 1)
-             for r, i in enumerate(plan.slots)])
-        self._attr_begin(parts)
+        with self._phase("plan"):
+            jnp = self._jnp
+            proposed = int(sum(d.size for d in plan.drafts))
+            with self._lock:
+                self._spec_proposed += proposed
+                self._spec_rounds_per_slot += sum(
+                    1 for d in plan.drafts if d.size)
+            _m_spec_proposed.inc(proposed)
+            if self._recorder.enabled:
+                self._recorder.record(
+                    "verify_dispatch", rows=plan.rows, proposed=proposed,
+                    free_blocks=self.cache.available_block_count)
+            P = plan.dlen.shape[0]
+            parts = self._cost_parts(
+                [(self._slots[i]["req"], plan.drafts[r].size + 1)
+                 for r, i in enumerate(plan.slots)])
+            self._attr_begin(parts)
+        self._phases.kind("verify")
         t0 = time.perf_counter()
         try:
             with _tracing.span(
                     "verify_dispatch", segments=plan.rows,
-                    proposed=proposed,
+                    proposed=proposed, round=self._phases.round,
                     request_ids=[self._slots[i]["req"].rid
-                                 for i in plan.slots], **self._rattr()):
-                self._maybe_fault("slow_dispatch")
-                self._maybe_fault("ensure_many")
-                # grow every row's table to its speculative write
-                # horizon in one atomic call (reservation-backed: the
-                # admission worst case includes the K-token overrun)
-                self.cache.ensure_many(
-                    plan.grow_updates([self._slots[i]["seq"]
-                                       for i in plan.slots]))
-                # FIXED table width (the decode-dispatch width, not the
-                # prefill path's pow2 bucketing): verify runs every
-                # round, so its jit shape must be pinned — one compiled
-                # variant per sampling mode
-                tables = jnp.asarray(self.cache.table_array(
-                    [self._slots[plan.slots[r]]["seq"]
-                     if r < plan.rows else None for r in range(P)],
-                    self._m_width))
-                sp_args, sp_mode = self._sp_store.verify_args(
-                    [plan.slots[r] if r < plan.rows else None
-                     for r in range(P)], plan.steps)
-                self._maybe_fault("verify")
-                vtok, accepted, stopped, kc, vc, counts = \
-                    self._decoder.packed_verify(
-                        self._params, jnp.asarray(plan.toks),
-                        jnp.asarray(plan.seg), jnp.asarray(plan.pos),
-                        tables, jnp.asarray(plan.sample_idx),
-                        jnp.asarray(plan.dlen), self.cache.k_blocks,
-                        self.cache.v_blocks, sp_args, sp_mode)
-                vtok_h = np.asarray(vtok)
-                acc_h = np.asarray(accepted)
-                stop_h = np.asarray(stopped)
+                                 for i in plan.slots]
+                    if _tracing.enabled() else (), **self._rattr()):
+                with self._phase("plan"):
+                    self._maybe_fault("slow_dispatch")
+                    self._maybe_fault("ensure_many")
+                    # grow every row's table to its speculative write
+                    # horizon in one atomic call (reservation-backed: the
+                    # admission worst case includes the K-token overrun)
+                    self.cache.ensure_many(
+                        plan.grow_updates([self._slots[i]["seq"]
+                                           for i in plan.slots]))
+                    # FIXED table width (the decode-dispatch width, not the
+                    # prefill path's pow2 bucketing): verify runs every
+                    # round, so its jit shape must be pinned — one compiled
+                    # variant per sampling mode
+                    tables = self.cache.table_array(
+                        [self._slots[plan.slots[r]]["seq"]
+                         if r < plan.rows else None for r in range(P)],
+                        self._m_width)
+                    sp_args, sp_mode = self._sp_store.verify_args(
+                        [plan.slots[r] if r < plan.rows else None
+                         for r in range(P)], plan.steps)
+                with self._phase("dispatch"):
+                    self._maybe_fault("verify")
+                    vtok, accepted, stopped, kc, vc, counts = \
+                        self._decoder.packed_verify(
+                            self._params, jnp.asarray(plan.toks),
+                            jnp.asarray(plan.seg), jnp.asarray(plan.pos),
+                            jnp.asarray(tables),
+                            jnp.asarray(plan.sample_idx),
+                            jnp.asarray(plan.dlen), self.cache.k_blocks,
+                            self.cache.v_blocks, sp_args, sp_mode)
+                with self._phase("read_back"):
+                    vtok_h = np.asarray(vtok)
+                    acc_h = np.asarray(accepted)
+                    stop_h = np.asarray(stopped)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
             self._dispatch_failure("verify", e, list(plan.slots))
             return
-        self._sp_store.swap_counts(counts)
-        self.cache.swap_arrays(kc, vc)
-        self._dispatch_ok([self._slots[i]["req"].rid
-                           for i in plan.slots
-                           if self._slots[i] is not None])
-        _m_spec_verify.inc()
-        t_now = time.perf_counter()
-        self._charge_dispatch(t_now - t0, parts)
-        self._ops_progress += 1
-        verify_discarded = 0
-        with self._lock:
-            self._spec_dispatches += 1
-        for r, i in enumerate(plan.slots):
-            s = self._slots[i]
-            a = int(acc_h[r])
-            k_r = int(plan.drafts[r].size)
-            # rollback FIRST (while the sequence still exists): the
-            # kept prefix is the last emitted token plus the accepted
-            # drafts; rejected speculative positions leave the cache
-            self.cache.truncate_seq(s["seq"], plan.write_pos[r] + a + 1)
-            rolled = k_r - a
-            if k_r:  # draft-free ride-along rows have nothing to score
+        with self._phase("emit"):
+            self._sp_store.swap_counts(counts)
+            self.cache.swap_arrays(kc, vc)
+            self._dispatch_ok([self._slots[i]["req"].rid
+                               for i in plan.slots
+                               if self._slots[i] is not None])
+            _m_spec_verify.inc()
+            t_now = time.perf_counter()
+            self._charge_dispatch(t_now - t0, parts)
+            self._ops_progress += 1
+            verify_discarded = 0
+            with self._lock:
+                self._spec_dispatches += 1
+            for r, i in enumerate(plan.slots):
+                s = self._slots[i]
+                a = int(acc_h[r])
+                k_r = int(plan.drafts[r].size)
+                # rollback FIRST (while the sequence still exists): the
+                # kept prefix is the last emitted token plus the accepted
+                # drafts; rejected speculative positions leave the cache
+                self.cache.truncate_seq(s["seq"], plan.write_pos[r] + a + 1)
+                rolled = k_r - a
+                if k_r:  # draft-free ride-along rows have nothing to score
+                    with self._lock:
+                        self._spec_accepted += a
+                        self._spec_rolled_back += rolled
+                    _m_spec_accepted.inc(a)
+                    _m_spec_rolled_back.inc(rolled)
+                    _m_spec_accept_rate.observe(a / k_r)
+                    _tracing.event("spec_round", request_id=s["req"].rid,
+                                   proposed=k_r, accepted=a,
+                                   rolled_back=rolled)
+                t_prev = s["t_last"] if s["t_last"] is not None else t_now
+                consumed = 0
+                for j in range(a + 1):
+                    consumed += 1
+                    self._slot_token(i, int(vtok_h[r, j]),
+                                     device_stopped=bool(stop_h[r, j]))
+                    if self._slots[i] is None:  # stopped mid-prefix: the
+                        break  # remaining accepted tokens are discarded
+                # goodput: the row computed k_r+1 verify positions — a+1
+                # candidate emissions (stop-truncated remainder is replay)
+                # plus k_r-a rejected drafts (rolled back above)
                 with self._lock:
-                    self._spec_accepted += a
-                    self._spec_rolled_back += rolled
-                _m_spec_accepted.inc(a)
-                _m_spec_rolled_back.inc(rolled)
-                _m_spec_accept_rate.observe(a / k_r)
-                _tracing.event("spec_round", request_id=s["req"].rid,
-                               proposed=k_r, accepted=a,
-                               rolled_back=rolled)
-            t_prev = s["t_last"] if s["t_last"] is not None else t_now
-            consumed = 0
-            for j in range(a + 1):
-                consumed += 1
-                self._slot_token(i, int(vtok_h[r, j]),
-                                 device_stopped=bool(stop_h[r, j]))
-                if self._slots[i] is None:  # stopped mid-prefix: the
-                    break  # remaining accepted tokens are discarded
-            # goodput: the row computed k_r+1 verify positions — a+1
-            # candidate emissions (stop-truncated remainder is replay)
-            # plus k_r-a rejected drafts (rolled back above)
-            with self._lock:
-                self._decoded_tokens += k_r + 1
-            _m_decoded.inc(k_r + 1)
-            verify_discarded += (a + 1) - consumed
-            if self._slots[i] is not None:
-                self._slots[i]["t_last"] = t_now
-            per = max(t_now - t_prev, 0.0) / consumed
-            with self._lock:
-                self._itl.extend([per] * consumed)
-                if s["req"].meta is not None:
-                    self._lane_itl.setdefault(
-                        s["req"].meta.lane, []).extend([per] * consumed)
-            if self._slo is not None:
-                self._slo_latency("itl", per, s["req"], n=consumed)
-            for _ in range(consumed):
-                _m_itl.observe(per)
-        if verify_discarded:
-            with self._lock:
-                self._replayed_tokens += verify_discarded
-            _m_replayed.inc(verify_discarded)
-        _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
+                    self._decoded_tokens += k_r + 1
+                _m_decoded.inc(k_r + 1)
+                verify_discarded += (a + 1) - consumed
+                if self._slots[i] is not None:
+                    self._slots[i]["t_last"] = t_now
+                per = max(t_now - t_prev, 0.0) / consumed
+                with self._lock:
+                    self._itl.extend([per] * consumed)
+                    if s["req"].meta is not None:
+                        self._lane_itl.setdefault(
+                            s["req"].meta.lane, []).extend([per] * consumed)
+                if self._slo is not None:
+                    self._slo_latency("itl", per, s["req"], n=consumed)
+                for _ in range(consumed):
+                    _m_itl.observe(per)
+            if verify_discarded:
+                with self._lock:
+                    self._replayed_tokens += verify_discarded
+                _m_replayed.inc(verify_discarded)
+            _m_goodput.set(self._tokens_out / (self._decoded_tokens or 1))
 
 
 def measure_offered_load(server, prompts, offered_rps, duration_s):

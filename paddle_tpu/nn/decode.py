@@ -1209,10 +1209,11 @@ class PagedDecoder:
         """(prefill, step, packed_prefill, packed_verify,
         unified_round, unified_round_window) tracing-wrapped jitted
         fns for one static sampling mode.
-        Dispatch-boundary spans (ISSUE 2): when tracing is on, every
-        jitted call shows up as its own span — the device-side cost
-        inside a request's prefill/decode phases; when off, the wrapper
-        is one bool check. Compile tracking (ISSUE 10) wraps INSIDE
+        Dispatch-boundary spans (ISSUE 2): every jitted call is its
+        own span (`pt:step_dispatch` etc. in a profiler's trace, inside
+        the engine's `pt:dispatch` phase; an event of the JSONL log
+        when tracing is on) — the device-side cost inside a request's
+        prefill/decode phases. Compile tracking (ISSUE 10) wraps INSIDE
         the span: any call that grew the jit's executable cache is
         recorded as an XLA compile of that program, labeled with
         whether requests were in flight — the event that lets a bench

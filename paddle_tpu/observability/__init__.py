@@ -6,11 +6,12 @@ Pillars, shared by serving, training, and bench:
   * `metrics` — process-wide registry of counters/gauges/histograms
     with labels; Prometheus-text and JSON snapshot exporters; near-zero
     cost when disabled.
-  * `tracing` — span API emitting a JSONL event log with monotonic
-    timestamps (bounded/rotating sink), plus the per-request trace
+  * `tracing` — span API: every span is a `jax.profiler`
+    annotation (`pt:<name>`, on the device's clock under a profiler
+    session) and, when telemetry is on, a JSONL event with monotonic
+    timestamps (bounded/rotating sink); plus the per-request trace
     assembler (queue-wait / admission / prefill / decode / detokenize
-    phases, TTFT, per-token latency) and the utils/profiler.top_ops
-    bridge.
+    phases, TTFT, per-token latency).
   * `exporter` — stdlib http.server daemon thread serving /metrics
     (Prometheus text), /statusz (live JSON engine state), /healthz
     (ok | degraded | stalled); started via
@@ -49,8 +50,9 @@ Pillars, shared by serving, training, and bench:
     in one versioned snapshot (`/capacity` endpoint, federated by
     the fleet router; the ROADMAP-3 Autoscaler input contract).
 
-One switch turns metrics+tracing on: PADDLE_TPU_TELEMETRY=1 in the
-environment, or `observability.enable()` at runtime.
+One switch turns metrics and the tracing event log on:
+PADDLE_TPU_TELEMETRY=1 in the environment, or `observability.enable()`
+at runtime.
 """
 from __future__ import annotations
 
@@ -72,7 +74,7 @@ from .timeline import write_chrome_trace  # noqa: F401
 from .trace_context import (TraceContext,  # noqa: F401
                             assemble_causal_traces)
 from .tracing import (TRACER, assemble_request_traces,  # noqa: F401
-                      attach_device_ops, span, summarize_traces)
+                      span, summarize_traces)
 
 
 def enable():

@@ -6,12 +6,23 @@ Lightweight span API answering "where did this request's 934ms go":
     with tracing.span("prefill", request_id=rid):
         ...
 
-Every span/event is one dict with MONOTONIC timestamps
+Every span is ALWAYS a `jax.profiler.TraceAnnotation` named
+`"pt:" + name` (its attributes become the annotation's stats): with no
+profiler session that is one flag check, with one the span lands on its
+thread's line of the `/host:CPU` plane, on the same nanosecond clock as
+the device's `XLA Ops` — so an idle gap of the device can be put to the
+span the host was in.
+
+Behind the PADDLE_TPU_TELEMETRY switch every span/event is also one
+dict with MONOTONIC timestamps
 (time.perf_counter — durations and orderings are exact; `wall` carries
 one time.time() anchor per process so JSONL files from different runs
 can still be aligned roughly). Events buffer in memory and, when a sink
 is configured, append to a JSONL file line-by-line — the trace survives
-a crash up to the last completed span.
+a crash up to the last completed span. A span's `id` is handed out when
+it OPENS and a nested span records its parent's as `parent_id` (beside
+the parent's name, `parent`), so the tree can be rebuilt from the
+events; events are written when a span closes, children before parents.
 
 The serving engine emits a small vocabulary per request
 (inference/serving.py):
@@ -38,17 +49,19 @@ request with contiguous phases (queue_wait / admission / prefill /
 decode / detokenize) that tile the request's wall-clock exactly, plus
 TTFT and per-token decode latency — the standard latency lens of paged
 serving engines (Ragged Paged Attention, arXiv:2604.15464).
-
-`attach_device_ops` bridges utils/profiler.top_ops so a traced serving
-window can carry a device-op breakdown in the same report.
 """
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+# every span's name in the profiler's trace: "pt:" + name
+SPAN_PREFIX = "pt:"
 
 ENV_ENABLE = "PADDLE_TPU_TELEMETRY"
 ENV_TRACE_PATH = "PADDLE_TPU_TRACE_PATH"
@@ -79,7 +92,7 @@ class Tracer:
         self._rotations = 0
         self.max_bytes = int(os.environ.get(ENV_TRACE_MAX_BYTES,
                                             DEFAULT_TRACE_MAX_BYTES))
-        self._next_id = 0
+        self._ids = itertools.count()
         self._local = threading.local()
         # one wall-clock anchor: wall ~= _wall0 + (ts - _ts0)
         self._ts0 = time.perf_counter()
@@ -154,8 +167,8 @@ class Tracer:
     # -- emission --------------------------------------------------------
     def _emit(self, ev):
         with self._lock:
-            ev["id"] = self._next_id
-            self._next_id += 1
+            if "id" not in ev:  # a span took its id when it opened
+                ev["id"] = next(self._ids)
             self._events.append(ev)
             if self._file is not None:
                 self._write_line(json.dumps(ev))
@@ -169,37 +182,39 @@ class Tracer:
         ev.update(attrs)
         self._emit(ev)
 
-    @contextlib.contextmanager
     def span(self, name, **attrs):
-        """Timed span; emitted on exit with its duration. Nested spans
-        record their parent span's id (per-thread stack)."""
-        if not self.enabled:
-            yield None
-            return
+        """Timed span, a context manager: always a profiler annotation
+        `pt:<name>`; when tracing is on also an event, emitted on exit
+        with its duration (the `with` target; None when off). Nested
+        spans record their parent span's id and name (per-thread
+        stack)."""
+        return _Span(self, name, attrs)
+
+    def _open(self, name, attrs):
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         ev = {"name": name, "ts": time.perf_counter(),
               "tid": threading.get_ident()}
         ev.update(attrs)
+        ev["id"] = next(self._ids)
         if stack:
             ev["parent"] = stack[-1]["name"]
+            ev["parent_id"] = stack[-1]["id"]
         ev["depth"] = len(stack)
         stack.append(ev)
-        try:
-            yield ev
-        finally:
-            stack.pop()
-            ev["dur"] = time.perf_counter() - ev["ts"]
-            self._emit(ev)
+        return ev
+
+    def _close(self, ev):
+        self._local.stack.pop()
+        ev["dur"] = time.perf_counter() - ev["ts"]
+        self._emit(ev)
 
     def wrap(self, name, fn, **attrs):
         """Decorator form: time every call of `fn` as a span — used for
         jitted dispatch boundaries (nn/decode.py)."""
         def wrapped(*a, **kw):
-            if not self.enabled:
-                return fn(*a, **kw)
-            with self.span(name, **attrs):
+            with _Span(self, name, attrs):
                 return fn(*a, **kw)
         wrapped.__name__ = getattr(fn, "__name__", name)
         wrapped.__wrapped__ = fn
@@ -227,6 +242,32 @@ class Tracer:
                 self._file.close()
                 self._file = None
                 self._path = None
+
+
+class _Span:
+    """One open span (what `Tracer.span` returns). A class and not a
+    generator so that the off path — every engine phase of every round
+    — allocates nothing but this and the annotation."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_ann", "_ev")
+
+    def __init__(self, tracer, name, attrs):
+        self._tracer, self._name, self._attrs = tracer, name, attrs
+        self._ev = None
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(SPAN_PREFIX + self._name,
+                                    **self._attrs)
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._ev = self._tracer._open(self._name, self._attrs)
+        return self._ev
+
+    def __exit__(self, *exc):
+        if self._ev is not None:
+            self._tracer._close(self._ev)
+        self._ann.__exit__(*exc)
+        return False
 
 
 # ---- process-wide default tracer ---------------------------------------
@@ -470,23 +511,3 @@ def summarize_traces(traces):
         "wall_p99_ms": round(pct(walls, .99), 3),
         "mean_phase_ms": {k: round(v / n, 3) for k, v in phases.items()},
     }
-
-
-def attach_device_ops(report, fn, steps=3, k=25):
-    """Attach a device-op breakdown (utils/profiler.top_ops over the
-    already-compiled zero-arg `fn`) to an assembled trace report dict:
-    the per-request phases say WHERE the request's time went host-side,
-    the op table says where the device milliseconds inside the dispatch
-    spans go. Returns `report` (mutated) for chaining; profiling
-    failures (no xplane on this backend) degrade to an "error" note
-    rather than losing the report."""
-    from ..utils import profiler as _profiler
-
-    try:
-        ops = _profiler.top_ops(fn, steps=steps, k=k)
-        report["device_ops"] = [
-            {"op": name, "total_ms": round(ms, 4), "count": count}
-            for name, ms, count in ops]
-    except Exception as e:  # noqa: BLE001 — xplane parsing is optional
-        report["device_ops_error"] = f"{type(e).__name__}: {e}"
-    return report

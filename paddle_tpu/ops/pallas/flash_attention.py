@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import named_pallas_call
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -247,7 +249,8 @@ def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret,
                                      lambda i, j, kk: (i, 0, kk),
                                      **mem_kwargs))
         operands.append(bias)
-    out, lse = pl.pallas_call(
+    out, lse = named_pallas_call(
+        "flash_fwd",
         kernel,
         out_shape=(jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((b * h, sq, LSE_LANES), jnp.float32)),
@@ -476,7 +479,8 @@ def _delta_rows(o3, do3, interpret):
     row = pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0), **mem_kwargs)
     out = pl.BlockSpec((None, bq, LSE_LANES), lambda i, j: (i, j, 0),
                        **mem_kwargs)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "flash_bwd_delta",
         _delta_kernel,
         out_shape=jax.ShapeDtypeStruct((bh, sq, LSE_LANES), jnp.float32),
         grid=(bh, sq // bq),
@@ -517,7 +521,8 @@ def _flash_bwd_fused(q, k, v, o, lse, g, scale, causal, block_q, block_k,
         operands.append(bias)
         out_shape.append(jax.ShapeDtypeStruct((b * h, 8, sk), jnp.float32))
         out_specs.append(biascol)
-    outs = pl.pallas_call(
+    outs = named_pallas_call(
+        "flash_bwd_fused",
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           block_q=bq, sq=sq, nk=sk // bk,
                           has_bias=bias is not None),
@@ -563,7 +568,8 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, block_q, block_k,
                                       lambda i, j, kk: (i, 0, kk),
                                       **mem_kwargs))
         dq_ops.append(bias)
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, nk=nk,
                           has_bias=bias is not None),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
@@ -598,7 +604,8 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, 8, sk), jnp.float32))
         dkv_out_specs.append(biascol)
         dkv_scratch.append(pltpu.VMEM((8, bk), jnp.float32))
-    outs = pl.pallas_call(
+    outs = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, nq=nq,
                           has_bias=bias is not None),
         out_shape=tuple(dkv_out_shape),

@@ -77,6 +77,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import named_pallas_call
+
 NEG_INF = -1e30
 Q_TILE = 128     # stream query-tile (and packing alignment) size
 DECODE_TILE = 8  # query tile of a one-token decode row: one f32 sublane
@@ -256,7 +258,11 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
             pltpu.VMEM((H, qt), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    # decode rows ride DECODE_TILE tiles, every other caller (chunk
+    # prefill, verify, the unified round's mixed stream) the wide ones:
+    # two names, so a device trace tells the two loads apart
+    out = named_pallas_call(
+        "paged_attn_decode" if qt == DECODE_TILE else "paged_attn_prefill",
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, T, Dh), q.dtype),

@@ -123,7 +123,11 @@ class TestFlashBias:
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.ops.pallas import flash_attention as FA
 
+        import paddle_tpu.parallel.mesh as mesh_mod
+
         monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        # one device, whatever mesh an earlier file of this worker left
+        monkeypatch.setattr(mesh_mod, "_current_mesh", None)
         calls = []
         orig = FA.flash_attention_bias
 

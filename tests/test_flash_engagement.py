@@ -27,8 +27,13 @@ class TestFlashEngagement:
         import paddle_tpu.ops.attention as A
         from paddle_tpu.ops.pallas import flash_attention as FA
 
+        import paddle_tpu.parallel.mesh as mesh_mod
+
         orig = FA.flash_attention
         monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        # one device, whatever mesh an earlier file of this worker left
+        # behind (under a leftover mesh sdpa takes its sharded branch)
+        monkeypatch.setattr(mesh_mod, "_current_mesh", None)
 
         @functools.wraps(orig)
         def spy(q, k, v, *a, **kw):

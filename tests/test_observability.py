@@ -25,6 +25,7 @@ def telemetry_on():
     from paddle_tpu import observability as obs
     obs.enable()
     T.TRACER.reset()
+    M.REGISTRY.reset()  # whatever an earlier file of this worker left
     try:
         yield
     finally:
@@ -210,24 +211,6 @@ class TestTracing:
         assert g(1) == 2
         assert calls == [1]
         assert tr.events()[0]["name"] == "fn_dispatch"
-
-    def test_attach_device_ops_bridge(self):
-        """profiler.top_ops bridge: either a real op table or a
-        degraded error note — the report is never lost."""
-        import jax
-        import jax.numpy as jnp
-
-        f = jax.jit(lambda x: (x @ x).sum())
-        x = jnp.ones((16, 16))
-        f(x).block_until_ready()
-        report = {"summary": {"requests": 1}}
-        out = T.attach_device_ops(report, lambda: f(x).block_until_ready(),
-                                  steps=1, k=5)
-        assert out is report
-        assert ("device_ops" in report) ^ ("device_ops_error" in report)
-        if "device_ops" in report:
-            assert all({"op", "total_ms", "count"} <= set(r)
-                       for r in report["device_ops"])
 
 
 @pytest.fixture(scope="module")

@@ -148,6 +148,42 @@ def test_decode_path_compiles(one_chip, quant):
                     i32(B, M), i32(B)) == 1
 
 
+def _kernel_op_names(fn, *args):
+    """The HLO instruction names of the program's kernels, as the chip's
+    compiler gives them — what a device trace shows on `XLA Ops`."""
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    return sorted(ln.split(" = ", 1)[0].split("%")[-1].rsplit(".", 1)[0]
+                  for ln in text.splitlines()
+                  if "tpu_custom_call" in ln and " = " in ln)
+
+
+def test_kernels_are_named_in_the_compiled_program(one_chip):
+    """Each kernel's XLA op reads its own name, also under `jax.grad`
+    (where an unnamed kernel read `jvp__` / `transpose_jvp___`)."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.unified_attention import (
+        Q_TILE, paged_decode_attention_kernel,
+        unified_ragged_attention_kernel)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    assert _kernel_op_names(jax.grad(loss, argnums=(0, 1, 2)),
+                            *_flash_args(one_chip, 8, 16, 1024, 64)) == [
+        "flash_bwd_delta", "flash_bwd_fused", "flash_fwd"]
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    pool = _pool(one_chip, False)
+    q = jax.ShapeDtypeStruct((32, H, DH), jnp.bfloat16, sharding=one_chip)
+    assert _kernel_op_names(paged_decode_attention_kernel, q, pool, pool,
+                            i32(32, M), i32(32)) == ["paged_attn_decode"]
+    q = jax.ShapeDtypeStruct((512, H, DH), jnp.bfloat16, sharding=one_chip)
+    assert _kernel_op_names(
+        unified_ragged_attention_kernel, q, pool, pool, i32(8, M),
+        i32(512 // Q_TILE), i32(512 // Q_TILE)) == ["paged_attn_prefill"]
+
+
 def test_head_sharded_kernels_compile_on_four_devices(mesh4, monkeypatch):
     """What a tensor-parallel engine hands the attention ops: a pool whose
     heads are split over mp (and blocks over dp) under a plain multi-device
