@@ -285,6 +285,14 @@ def serve(model, requests, label, block_size, num_blocks, max_new,
         n = len(distinct_devices(tree))
         need(n == pool_devices,
              f"[{label}] {name} sit on {n} device(s), not {pool_devices}")
+    # one layer's K and V, all devices together, and what an int8 pool's
+    # programs still re-lay: its two scale stacks [L, N, BS, H], whose
+    # rows of H are padded to the 128 lanes (PERF.md section 7)
+    pool = server.cache
+    layer_pool = pool.pool_bytes_total // cfg.num_layers
+    relaid_scales = 0 if pool.kv_dtype is None else (
+        2 * cfg.num_layers * num_blocks * block_size * 128
+        * pool.k_blocks.scales.dtype.itemsize)
     server.start()
     try:
         futures = [server.submit(p, max_new_tokens=n) for p, n in requests]
@@ -310,18 +318,33 @@ def serve(model, requests, label, block_size, num_blocks, max_new,
     # re-compile each dispatched program from its shapes and read the
     # compiled HLO (the persistent cache serves what the dispatch compiled)
     t1 = time.perf_counter()
-    counts = {}
+    counts, temps = {}, {}
     for ev in compiles:
+        compiled = ev["lower"]().compile()
         counts.setdefault(ev["program"], []).append(
-            kernel_count(ev["lower"]().compile().as_text()))
+            kernel_count(compiled.as_text()))
+        temps.setdefault(ev["program"], []).append(
+            compiled.memory_analysis().temp_size_in_bytes)
     recount_s = time.perf_counter() - t1
     for name, ks in sorted(counts.items()):
         log(f"[{label}] program {name}: {len(ks)} compiled variant(s), "
             f"tpu_custom_call per variant {sorted(set(ks))}, attention "
             f"path {path if name in ATTENTION_PROGRAMS else 'xla (dense)'}")
-        if on_tpu and name in ATTENTION_PROGRAMS:
+        if name not in ATTENTION_PROGRAMS:
+            continue
+        # the pool is written and read in place: a program's temporaries
+        # hold no layer's slice, no copy and no re-laid copy of it
+        log(f"[{label}] program {name}: temporaries up to "
+            f"{max(temps[name]) / 1e6:.1f} MB a device (one layer's pool "
+            f"{layer_pool / 1e6:.1f} MB, re-laid int8 scale stacks "
+            f"{relaid_scales / 1e6:.1f} MB)")
+        if on_tpu:
             need((min(ks) > 0) == (path != "xla"),
                  f"[{label}] {name}: path {path!r} but kernel counts {ks}")
+            need(max(temps[name]) < layer_pool + relaid_scales,
+                 f"[{label}] {name}: {max(temps[name])} bytes of "
+                 f"temporaries, as much as one layer's pool ({layer_pool}"
+                 f" + {relaid_scales}): the pool is being copied")
     need(not on_tpu or any(n in counts for n in ATTENTION_PROGRAMS),
          f"[{label}] no attention program was dispatched: {list(counts)}")
     log(f"[{label}] {len(out)} requests, {stats['new_tokens']} new tokens, "

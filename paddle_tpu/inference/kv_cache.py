@@ -5,10 +5,18 @@ TPU-native answer to the static-cache serving loop. Instead of one
 contiguous [B, S_max] cache slab per batch (which pins every slot to the
 longest possible sequence), K/V live in a pool of fixed-size blocks:
 
-    k_blocks, v_blocks: [L, num_blocks, block_size, H, Dh]
+    k_blocks, v_blocks: [L, num_blocks, block_size, H * Dh]
 
 Each sequence owns an ordered *block table* (a list of block ids); token
-`t` of a sequence lives at (table[t // block_size], t % block_size).
+`t` of a sequence lives at (table[t // block_size], t % block_size), its
+H heads side by side in one row of H * Dh values. That minor axis is the
+pool's device layout (PR 25): a TPU pads a minor dimension of Dh = 64 to
+its 128 lanes, or moves the block axis onto the lanes, and XLA then
+re-laid the whole pool around every program that wrote K/V rows into it
+and read [BS, H, Dh] blocks out of it. A row of H * Dh is a lane
+multiple: the pool lies row-major and unpadded, the K/V scatter writes
+whole rows in place and the paged kernel reads whole blocks in place
+(ops/pallas/unified_attention.py).
 Attention gathers keys by block table, masked by the sequence's true
 length — no pad-token-value matching anywhere, so a prompt that
 legitimately contains `pad_token_id` can never be corrupted.
@@ -231,6 +239,17 @@ def prefix_block_hash(parent: int, tokens) -> int:
         hashlib.blake2b(data, digest_size=16).digest(), "little")
 
 
+def split_heads(a, num_heads):
+    """Pool rows with the heads named: [.., H*Dh] -> [.., H, Dh].  A
+    scale array [.., H] has no Dh to split off and passes through.
+    What leaves the pool (a tier payload, an exported prefix, the
+    sequence-parallel seam's layer) names the heads; the pool's own
+    arrays keep them side by side (module docstring)."""
+    if a.shape[-1] == num_heads:
+        return a
+    return a.reshape(a.shape[:-1] + (num_heads, -1))
+
+
 @functools.lru_cache(maxsize=8)
 def _copy_block_fn(donate):
     """Jitted whole-block device copy (the CoW kernel): one dynamic
@@ -293,15 +312,18 @@ class PagedKVCache:
         self._name = str(name) if name else f"pool{next(_pool_ids)}"
         dt = jnp.float32 if dtype is None else dtype
         self.dtype = dt
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
+        rows = (self.num_layers, self.num_blocks, self.block_size)
+        shape = rows + (self.num_heads * self.head_dim,)
         if kv_dtype == "int8":
             from .kv_quant import QuantizedKV
 
-            self.k_blocks = QuantizedKV(jnp.zeros(shape, jnp.int8),
-                                        jnp.zeros(shape[:-1], dt))
-            self.v_blocks = QuantizedKV(jnp.zeros(shape, jnp.int8),
-                                        jnp.zeros(shape[:-1], dt))
+            # one scale a (token, head) vector: [L, N, BS, H]
+            self.k_blocks = QuantizedKV(
+                jnp.zeros(shape, jnp.int8),
+                jnp.zeros(rows + (self.num_heads,), dt))
+            self.v_blocks = QuantizedKV(
+                jnp.zeros(shape, jnp.int8),
+                jnp.zeros(rows + (self.num_heads,), dt))
         else:
             self.k_blocks = jnp.zeros(shape, dt)
             self.v_blocks = jnp.zeros(shape, dt)
@@ -546,14 +568,17 @@ class PagedKVCache:
         (bit-exact round trip), `kv_quant.kv_encode` for a dense one."""
         from .kv_quant import QuantizedKV, kv_encode
 
+        # the tier's payload names the heads: codes [L, fill, H, Dh]
         if self.kv_dtype == "int8":
             def grab(arr):
                 return QuantizedKV(
-                    np.asarray(arr.codes[:, b, :fill]),
+                    split_heads(np.asarray(arr.codes[:, b, :fill]),
+                                self.num_heads),
                     np.asarray(arr.scales[:, b, :fill]))
         else:
             def grab(arr):
-                codes, scales = kv_encode(arr[:, b, :fill])
+                codes, scales = kv_encode(
+                    split_heads(arr[:, b, :fill], self.num_heads))
                 return QuantizedKV(np.asarray(codes),
                                    np.asarray(scales))
         return grab(self.k_blocks), grab(self.v_blocks)
@@ -567,18 +592,20 @@ class PagedKVCache:
 
         from .kv_quant import kv_decode
 
+        merged = (self.num_layers, fill, -1)  # [L, fill, H * Dh]
         if self.kv_dtype == "int8":
             def put(arr, pay):
                 return type(arr)(
                     arr.codes.at[:, b, :fill].set(
-                        jnp.asarray(pay.codes, arr.codes.dtype)),
+                        jnp.asarray(pay.codes, arr.codes.dtype)
+                        .reshape(merged)),
                     arr.scales.at[:, b, :fill].set(
                         jnp.asarray(pay.scales, arr.scales.dtype)))
         else:
             def put(arr, pay):
                 rows = kv_decode(jnp.asarray(pay.codes),
                                  jnp.asarray(pay.scales), arr.dtype)
-                return arr.at[:, b, :fill].set(rows)
+                return arr.at[:, b, :fill].set(rows.reshape(merged))
         self.k_blocks = put(self.k_blocks, k_pay)
         self.v_blocks = put(self.v_blocks, v_pay)
 
@@ -1217,8 +1244,10 @@ class PagedKVCache:
         if pos == 0:
             return None
 
-        def grab(arr, b):
-            return jax.tree.map(lambda a: np.asarray(a[:, b]), arr)
+        def grab(arr, b):  # the payload names the heads
+            return jax.tree.map(
+                lambda a: split_heads(np.asarray(a[:, b]),
+                                      self.num_heads), arr)
 
         return {
             "tokens": [int(t) for t in ids[:pos]],
@@ -1264,12 +1293,12 @@ class PagedKVCache:
         new_blocks = self._take_blocks(len(fills),
                                        owner=owner)  # may raise
         for b, pk, pv in zip(new_blocks, payload["k"], payload["v"]):
-            self.k_blocks = jax.tree.map(
-                lambda a, p, _b=b: a.at[:, _b].set(p),
-                self.k_blocks, pk)
-            self.v_blocks = jax.tree.map(
-                lambda a, p, _b=b: a.at[:, _b].set(p),
-                self.v_blocks, pv)
+            def put(a, p, _b=b):  # [L, BS, H, Dh] back into pool rows
+                return a.at[:, _b].set(
+                    np.asarray(p).reshape((a.shape[0],) + a.shape[2:]))
+
+            self.k_blocks = jax.tree.map(put, self.k_blocks, pk)
+            self.v_blocks = jax.tree.map(put, self.v_blocks, pv)
         h = ROOT_HASH
         pos = 0
         for b, f in zip(new_blocks, fills):

@@ -38,8 +38,10 @@ from typing import Any, NamedTuple
 
 class QuantizedKV(NamedTuple):
     """One K or V pool quantized: int8 `codes` plus the per-vector
-    `scales` buffer (codes.shape[:-1], compute dtype)."""
-    codes: Any   # int8  [..., BS, H, Dh]
+    `scales` buffer (compute dtype), one scale a (token, head).  In the
+    pool a token's heads lie side by side (`kv_cache`); what leaves it
+    (a tier payload, an exported block) names them."""
+    codes: Any   # int8  [..., BS, H*Dh] in the pool, [..., H, Dh] outside
     scales: Any  # float [..., BS, H]
 
 

@@ -89,33 +89,39 @@ GREEDY_MODE = (False, False)
 
 
 @functools.lru_cache(maxsize=4)
-def _kv_io(kv_quant):
-    """(write, layer) accessor pair over the cache arrays, selected by
-    the STATIC kv_quant flag (quantized-serving round). Dense pools are
-    plain [L, N, BS, H, Dh] arrays; int8 pools are
-    `inference.kv_quant.QuantizedKV` (codes, per-vector scales)
-    pytrees. `write` quantizes ON APPEND — each written vector gets
-    its own absmax scale, so no already-stored code ever needs
-    rescaling and the functional scatter stays a scatter; `layer`
-    slices one layer's pool for the attention ops (which dequantize
-    inside the kernel)."""
+def _kv_write(kv_quant):
+    """The K/V append over the cache arrays, selected by the STATIC
+    kv_quant flag (quantized-serving round). Dense pools are plain
+    [L, N, BS, H*Dh] arrays (a token's heads side by side:
+    `inference.kv_cache`); int8 pools are
+    `inference.kv_quant.QuantizedKV` (codes like them, per-vector
+    scales [L, N, BS, H]) pytrees. The write takes t [.., H, Dh] and
+    quantizes ON APPEND — each written vector gets its own absmax
+    scale, so no already-stored code ever needs rescaling and the
+    functional scatter stays a scatter.
+
+    It is a scatter of whole rows into the donated STACK, and the
+    attention ops read the same stack through `layer=` (PR 25): with
+    nothing between a layer's scatter and its launch but the stack
+    itself, the chain scatter_i -> attention_i -> scatter_{i+1} is
+    linear, the stack lies on the device the way both want it, and XLA
+    keeps the chain in the one donated buffer: no program holds a copy,
+    a re-laid copy or a layer's slice of the pool."""
+    def rows(t):  # [.., H, Dh] -> [.., H*Dh]
+        return t.reshape(t.shape[:-2] + (-1,))
+
     if not kv_quant:
         def write(cache, i, blk, off, t):
-            return cache.at[i, blk, off].set(t)
-
-        def layer(cache, i):
-            return cache[i]
+            return cache.at[i, blk, off].set(rows(t))
     else:
         from ..inference.kv_quant import QuantizedKV, kv_encode
 
         def write(cache, i, blk, off, t):
             codes, sc = kv_encode(t, cache.scales.dtype)
-            return QuantizedKV(cache.codes.at[i, blk, off].set(codes),
-                               cache.scales.at[i, blk, off].set(sc))
-
-        def layer(cache, i):
-            return QuantizedKV(cache.codes[i], cache.scales[i])
-    return write, layer
+            return QuantizedKV(
+                cache.codes.at[i, blk, off].set(rows(codes)),
+                cache.scales.at[i, blk, off].set(sc))
+    return write
 
 
 @functools.lru_cache(maxsize=32)
@@ -299,7 +305,7 @@ def _build_paged_fns(spec, block_size, return_logits, mode,
     scale = Dh ** -0.5
     BS = int(block_size)
     sampled, penalties = mode
-    kv_write, kv_layer = _kv_io(bool(kv_quant))
+    kv_write = _kv_write(bool(kv_quant))
     hp = _layer_helpers(spec, cq)
     ln, qkv_split, make_embed_head, block_and_mlp = (
         hp.ln, hp.qkv_split, hp.make_embed_head, hp.block_and_mlp)
@@ -367,9 +373,8 @@ def _build_paged_fns(spec, block_size, return_logits, mode,
             q, k, v = qkv_split(params, i, a)          # [B, H, Dh]
             kc = kv_write(kc, i, blk, off, k)
             vc = kv_write(vc, i, blk, off, v)
-            o = paged_decode_attention(q, kv_layer(kc, i),
-                                       kv_layer(vc, i), tables, ctx,
-                                       scale=scale, mesh=mesh
+            o = paged_decode_attention(q, kc, vc, tables, ctx,
+                                       scale=scale, mesh=mesh, layer=i
                                        ).reshape(B, E)
             x = block_and_mlp(params, i, x, o, dt)
         xf = ln(x, params["ln_f.weight"], params["ln_f.bias"])
@@ -479,7 +484,7 @@ def _packed_trunk(spec, block_size, kv_quant=False, cq=None,
     L, H, Dh, E, eps, tied = spec
     scale = Dh ** -0.5
     BS = int(block_size)
-    kv_write, kv_layer = _kv_io(bool(kv_quant))
+    kv_write = _kv_write(bool(kv_quant))
     hp = _layer_helpers(spec, cq)
     spin = _sp_stream_pin(sp_mesh)
     spg = _sp_kv_gather(sp_mesh)
@@ -505,8 +510,8 @@ def _packed_trunk(spec, block_size, kv_quant=False, cq=None,
         blk = jnp.where(valid, tables[seg, p0 // BS], 0)  # [T]
         off = p0 % BS
         if sp_flat:
-            from ..serving_dist.sp_attention import (kv_set_layer,
-                                                     segment_starts)
+            from ..serving_dist.sp_attention import (
+                kv_get_layer, kv_set_layer, segment_starts)
 
             starts = segment_starts(seg, pos, tables.shape[0])
         for i in range(L):
@@ -515,18 +520,18 @@ def _packed_trunk(spec, block_size, kv_quant=False, cq=None,
             q, k, v = hp.qkv_split(params, i, a)          # [T, H, Dh]
             if sp_flat:
                 o, kc_i, vc_i = sp_attn(
-                    q, k, v, kv_layer(kc, i), kv_layer(vc, i),
+                    q, k, v, kv_get_layer(kc, i, H), kv_get_layer(vc, i, H),
                     tables, seg, pos, starts)
-                kc = kv_set_layer(kc, i, kc_i, bool(kv_quant))
-                vc = kv_set_layer(vc, i, vc_i, bool(kv_quant))
+                kc = kv_set_layer(kc, i, kc_i)
+                vc = kv_set_layer(vc, i, vc_i)
                 o = o.reshape(T, E)
             else:
                 kc = kv_write(kc, i, blk, off, spg(k))
                 vc = kv_write(vc, i, blk, off, spg(v))
                 o = ragged_prefill_attention(
-                    q, kv_layer(kc, i), kv_layer(vc, i), tables, seg,
-                    pos, scale=scale, allow_pallas=sp_mesh is None,
-                    mesh=mesh).reshape(T, E)
+                    q, kc, vc, tables, seg, pos, scale=scale,
+                    allow_pallas=sp_mesh is None, mesh=mesh,
+                    layer=i).reshape(T, E)
             x = spin(hp.block_and_mlp(params, i, x, o, dt))
         return x, kc, vc
 
@@ -629,7 +634,7 @@ def _verify_trunk(spec, block_size, kv_quant=False, cq=None, mesh=None):
     L, H, Dh, E, eps, tied = spec
     scale = Dh ** -0.5
     BS = int(block_size)
-    kv_write, kv_layer = _kv_io(bool(kv_quant))
+    kv_write = _kv_write(bool(kv_quant))
     hp = _layer_helpers(spec, cq)
 
     def trunk(params, toks, seg, pos, tables, kc, vc):
@@ -653,9 +658,8 @@ def _verify_trunk(spec, block_size, kv_quant=False, cq=None, mesh=None):
             kc = kv_write(kc, i, blk, off, k)
             vc = kv_write(vc, i, blk, off, v)
             o = verify_window_attention(
-                q.reshape(P, W, H, Dh), kv_layer(kc, i),
-                kv_layer(vc, i), tables, pos2,
-                scale=scale, mesh=mesh).reshape(T, E)
+                q.reshape(P, W, H, Dh), kc, vc, tables, pos2,
+                scale=scale, mesh=mesh, layer=i).reshape(T, E)
             x = hp.block_and_mlp(params, i, x, o, dt)
         return x, kc, vc
 

@@ -54,25 +54,55 @@ def paged_attention_path(head_dim, block_size, num_heads,
     return "pallas" if mesh is None else "pallas/shard_map"
 
 
-def _paged_kernel(kernel, mesh, q, k_blocks, v_blocks, *rest, **kw):
-    """Run a paged-pool Pallas `kernel(q, k_blocks, v_blocks, *rest)`;
-    with a mesh, per device under shard_map: q/out [T, H, Dh] and the
-    pools split on heads over mp, the int32 steering arrays
-    replicated."""
+def _paged_kernel(kernel, mesh, q, k_blocks, v_blocks, layer, *rest, **kw):
+    """Run a paged-pool Pallas `kernel(q, k_blocks, v_blocks, *rest,
+    layer)` on the pool stack [L, N, BS, H*Dh], which the kernel reads
+    where it lies (or on one layer's [N, BS, H, Dh], layer None); with a
+    mesh, per device under shard_map: q/out [T, H, Dh] and the pools
+    split on heads over mp, the int32 steering arrays replicated."""
     mesh = _manual_mesh(mesh)
     fn = functools.partial(kernel, **kw)
     if mesh is None:
-        return fn(q, k_blocks, v_blocks, *rest)
+        return fn(q, k_blocks, v_blocks, *rest, layer)
+    if layer is None:  # one layer's pool: a stack of one, as the kernel
+        k_blocks, v_blocks = jax.tree.map(  # itself would take it
+            lambda a: a.reshape((1,) + a.shape[:2] + (-1,)),
+            (k_blocks, v_blocks))
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32)
+    if dict(mesh.shape).get("dp", 1) > 1:
+        # the engine shards blocks over dp and a launch may name any of
+        # them: gather ONE layer's blocks over dp, never the stack
+        k_blocks, v_blocks = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0),
+            (k_blocks, v_blocks))
+        layer = jnp.zeros((), jnp.int32)
     heads = PartitionSpec(None, "mp", None)
-    # dense blocks / int8 codes [N, BS, H, Dh] and, for an int8 pool, the
-    # per-vector scales [N, BS, H]: the same pytree as the pool
-    pool = jax.tree.map(
-        lambda a: PartitionSpec(None, None, "mp", *[None] * (a.ndim - 3)),
-        k_blocks)
+    # blocks / int8 codes [L, N, BS, H*Dh] and, for an int8 pool, the
+    # per-vector scales [L, N, BS, H]: heads are the minor axis of both
+    pool = PartitionSpec(None, None, None, "mp")
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(heads, pool, pool) + (PartitionSpec(),) * len(rest),
-        out_specs=heads, check_vma=False)(q, k_blocks, v_blocks, *rest)
+        in_specs=(heads, pool, pool) + (PartitionSpec(),) * (len(rest) + 1),
+        out_specs=heads, check_vma=False)(q, k_blocks, v_blocks, *rest,
+                                          layer)
+
+
+def _pool_gather(layer, block_tables):
+    """blocks -> `blocks[block_tables]` of the stack's `layer`, as ONE
+    gather from the stack (no slice of a layer's pool in between); of
+    one layer's pool when layer is None.  [.., M, BS, H*Dh] from a
+    stack, [.., M, BS, H, Dh] from one layer's pool: the callers
+    reshape either to [.., M*BS, H, Dh]."""
+    if layer is None:
+        return lambda blocks: blocks[block_tables]
+    return lambda blocks: blocks[layer, block_tables]
+
+
+def _block_size(blocks, layer):
+    """BS of a pool stack [L, N, BS, H*Dh] or (layer None) of one
+    layer's pool [N, BS, H, Dh]."""
+    return blocks.shape[1 if layer is None else 2]
 
 
 def _xla_attention(q, k, v, mask=None, scale=None, causal=False):
@@ -175,16 +205,21 @@ def _is_quantized_kv(kv):
 
 
 def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
-                           scale=None, mesh=None):
+                           scale=None, mesh=None, layer=None):
     """Single-token decode attention over a PAGED KV cache (the
     gather-by-block-table read half of inference/kv_cache.py).
 
     q: [B, H, Dh] — one new token per sequence.
-    k_blocks/v_blocks: [N, BS, H, Dh] — ONE layer's block pool; OR a
-        `QuantizedKV` (int8 codes [N, BS, H, Dh], per-vector scales
-        [N, BS, H]) for an int8 pool — dequantization happens INSIDE
-        the kernel/contraction (the scales fold into the score and
-        output einsums), so no bf16 copy of the cache ever
+    k_blocks/v_blocks: the pool STACK [L, N, BS, H*Dh] (a token's
+        heads side by side: `inference.kv_cache`) with `layer` the
+        layer to attend — what every decode program passes: the Pallas
+        kernel reads the stack where it lies and the XLA path gathers
+        from it, so no layer's slice is ever materialized — or ONE
+        layer's [N, BS, H, Dh] with layer=None; OR a `QuantizedKV`
+        (int8 codes like the blocks, per-vector scales [L, N, BS, H]
+        or [N, BS, H]) for an int8 pool — dequantization happens
+        INSIDE the kernel/contraction (the scales fold into the score
+        and output einsums), so no bf16 copy of the cache ever
         materializes in HBM.
     block_tables: [B, M] int32 — block ids per sequence, 0-padded.
     ctx_lens: [B] int32 — tokens (cache positions) visible to each query;
@@ -204,26 +239,26 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     B, H, Dh = q.shape
-    _, BS, _, _ = kcodes.shape
+    BS = _block_size(kcodes, layer)
     M = block_tables.shape[1]
     sc = (Dh ** -0.5) if scale is None else scale
     if paged_attention_path(Dh, BS, H, mesh=mesh) != "xla":
         from .pallas.unified_attention import paged_decode_attention_kernel
         return _paged_kernel(paged_decode_attention_kernel, mesh, q,
-                             k_blocks, v_blocks, block_tables, ctx_lens,
-                             scale=float(sc))
+                             k_blocks, v_blocks, layer, block_tables,
+                             ctx_lens, scale=float(sc))
+    gather = _pool_gather(layer, block_tables)
     if quant:
         # gather CODES + per-vector scales; the int8->dt convert fuses
         # into the einsum operand pipeline (the weight-dot ::w8c trick)
         # and the scale vector multiplies the SCORE/PROB tensors — the
         # cache is consumed as raw int8
-        k = jnp.transpose(kcodes[block_tables], (0, 3, 1, 2, 4)) \
-            .reshape(B, H, M * BS, Dh)
-        v = jnp.transpose(v_blocks.codes[block_tables], (0, 3, 1, 2, 4)) \
-            .reshape(B, H, M * BS, Dh)
-        ks = jnp.transpose(k_blocks.scales[block_tables]
+        k = gather(kcodes).reshape(B, M * BS, H, Dh).transpose(0, 2, 1, 3)
+        v = gather(v_blocks.codes).reshape(B, M * BS, H, Dh) \
+            .transpose(0, 2, 1, 3)
+        ks = jnp.transpose(gather(k_blocks.scales)
                            .reshape(B, M * BS, H), (0, 2, 1))  # [B,H,C]
-        vs = jnp.transpose(v_blocks.scales[block_tables]
+        vs = jnp.transpose(gather(v_blocks.scales)
                            .reshape(B, M * BS, H), (0, 2, 1))
         s = jnp.einsum("bhd,bhsd->bhs", q, k.astype(q.dtype)) \
             .astype(jnp.float32) * ks.astype(jnp.float32) * sc
@@ -232,11 +267,9 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
         w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bhs,bhsd->bhd", w * vs.astype(q.dtype),
                           v.astype(q.dtype))
-    # XLA gather path: [B, M, BS, H, Dh] -> [B, H, M*BS, Dh]
-    k = jnp.transpose(k_blocks[block_tables], (0, 3, 1, 2, 4)) \
-        .reshape(B, H, M * BS, Dh)
-    v = jnp.transpose(v_blocks[block_tables], (0, 3, 1, 2, 4)) \
-        .reshape(B, H, M * BS, Dh)
+    # XLA gather path: [B, M, BS, H*Dh] -> [B, H, M*BS, Dh]
+    k = gather(k_blocks).reshape(B, M * BS, H, Dh).transpose(0, 2, 1, 3)
+    v = gather(v_blocks).reshape(B, M * BS, H, Dh).transpose(0, 2, 1, 3)
     s = jnp.einsum("bhd,bhsd->bhs", q, k).astype(jnp.float32) * sc
     valid = jnp.arange(M * BS)[None, :] < ctx_lens[:, None]  # [B, M*BS]
     s = jnp.where(valid[:, None, :], s, -1e30)
@@ -245,7 +278,8 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
 
 
 def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
-                             scale=None, allow_pallas=True, mesh=None):
+                             scale=None, allow_pallas=True, mesh=None,
+                             layer=None):
     """Packed ragged prefill attention over a PAGED KV cache: every token
     of a token-packed multi-sequence stream attends its OWN sequence's
     cache positions [0, pos] — both the K/V this chunk just wrote and
@@ -253,10 +287,11 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
     so chunked prefill carries no extra state.
 
     q: [T, H, Dh] — packed query stream (several prompt chunks).
-    k_blocks/v_blocks: [N, BS, H, Dh] — ONE layer's block pool; OR
-        `QuantizedKV` (int8 codes + per-vector scales) for an int8
-        pool — scales fold into the score/output contractions, the
-        cache streams as raw int8.
+    k_blocks/v_blocks: the pool stack [L, N, BS, H*Dh] and `layer`,
+        or ONE layer's [N, BS, H, Dh] and layer=None, as in
+        `paged_decode_attention`; OR `QuantizedKV` (int8 codes +
+        per-vector scales) for an int8 pool — scales fold into the
+        score/output contractions, the cache streams as raw int8.
     block_tables: [B, M] int32 — block ids per slot row, 0-padded.
     seg: [T] int32 — slot row (index into block_tables) of each token.
     pos: [T] int32 — absolute cache position of each token; -1 marks a
@@ -294,29 +329,30 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     T, H, Dh = q.shape
-    _, BS, _, _ = kcodes.shape
+    BS = _block_size(kcodes, layer)
     B, M = block_tables.shape
     sc = (Dh ** -0.5) if scale is None else scale
     if allow_pallas and paged_attention_path(Dh, BS, H, T, mesh) != "xla":
         from .pallas.unified_attention import (
             Q_TILE, unified_ragged_attention_kernel)
         return _paged_kernel(unified_ragged_attention_kernel, mesh, q,
-                             k_blocks, v_blocks, block_tables,
+                             k_blocks, v_blocks, layer, block_tables,
                              seg[::Q_TILE], pos[::Q_TILE], scale=float(sc))
     # row-gather, head-major, joint-row softmax
+    gather = _pool_gather(layer, block_tables)
     if quant:
-        k = kcodes[block_tables].reshape(B, M * BS, H, Dh) \
+        k = gather(kcodes).reshape(B, M * BS, H, Dh) \
             .transpose(2, 0, 1, 3).astype(q.dtype)        # [H, B, C, Dh]
-        v = v_blocks.codes[block_tables].reshape(B, M * BS, H, Dh) \
+        v = gather(v_blocks.codes).reshape(B, M * BS, H, Dh) \
             .transpose(2, 0, 1, 3).astype(q.dtype)
-        ks = k_blocks.scales[block_tables].reshape(B, M * BS, H) \
+        ks = gather(k_blocks.scales).reshape(B, M * BS, H) \
             .transpose(2, 0, 1)                           # [H, B, C]
-        vs = v_blocks.scales[block_tables].reshape(B, M * BS, H) \
+        vs = gather(v_blocks.scales).reshape(B, M * BS, H) \
             .transpose(2, 0, 1)
     else:
-        k = k_blocks[block_tables].reshape(B, M * BS, H, Dh) \
+        k = gather(k_blocks).reshape(B, M * BS, H, Dh) \
             .transpose(2, 0, 1, 3)                        # [H, B, C, Dh]
-        v = v_blocks[block_tables].reshape(B, M * BS, H, Dh) \
+        v = gather(v_blocks).reshape(B, M * BS, H, Dh) \
             .transpose(2, 0, 1, 3)
         ks = vs = None
     qh = q.transpose(1, 0, 2)                             # [H, T, Dh]
@@ -336,7 +372,7 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
 
 
 def unified_stream_attention(q, k_blocks, v_blocks, block_tables, seg,
-                             pos, scale=None, mesh=None):
+                             pos, scale=None, mesh=None, layer=None):
     """Unified serving-round attention (one-kernel round, r16): score a
     single packed token stream containing MIXED prefill chunks, plain
     decode rows and speculative verify regions in one launch.
@@ -355,19 +391,22 @@ def unified_stream_attention(q, k_blocks, v_blocks, block_tables, seg,
     (`nn.decode` `unified_round`); the argument contract is exactly
     `ragged_prefill_attention`'s."""
     return ragged_prefill_attention(q, k_blocks, v_blocks, block_tables,
-                                    seg, pos, scale=scale, mesh=mesh)
+                                    seg, pos, scale=scale, mesh=mesh,
+                                    layer=layer)
 
 
 def verify_window_attention(q, k_blocks, v_blocks, block_tables, pos,
-                            scale=None, mesh=None):
+                            scale=None, mesh=None, layer=None):
     """Speculative-verification attention over a PAGED KV cache: a
     DENSE [P, W] window of queries per plan row (each row's last
     emitted token + its draft tokens, W pinned by the verify plan),
     every query attending its OWN row's cache positions [0, pos].
 
-    q: [P, W, H, Dh]; k_blocks/v_blocks: [N, BS, H, Dh] (one layer's
-    pool) or `QuantizedKV` codes+scales for an int8 pool (scales fold
-    into the contractions); block_tables: [P, M] int32 0-padded; pos:
+    q: [P, W, H, Dh]; k_blocks/v_blocks: the pool stack
+    [L, N, BS, H*Dh] and `layer`, or one layer's [N, BS, H, Dh] and
+    layer=None, as in `paged_decode_attention`, or `QuantizedKV`
+    codes+scales for an int8 pool (scales fold into the
+    contractions); block_tables: [P, M] int32 0-padded; pos:
     [P, W] int32
     absolute cache positions (-1 = region pad; its output is finite
     garbage no readout index touches).
@@ -384,26 +423,27 @@ def verify_window_attention(q, k_blocks, v_blocks, block_tables, pos,
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     P, W, H, Dh = q.shape
-    _, BS, _, _ = kcodes.shape
+    BS = _block_size(kcodes, layer)
     M = block_tables.shape[1]
     sc = (Dh ** -0.5) if scale is None else scale
     if _on_tpu():
         seg = jnp.repeat(jnp.arange(P, dtype=jnp.int32), W)
         return ragged_prefill_attention(
             q.reshape(P * W, H, Dh), k_blocks, v_blocks, block_tables,
-            seg, pos.reshape(-1), scale=sc, mesh=mesh).reshape(P, W, H, Dh)
+            seg, pos.reshape(-1), scale=sc, mesh=mesh,
+            layer=layer).reshape(P, W, H, Dh)
+    gather = _pool_gather(layer, block_tables)
     if quant:
-        k = kcodes[block_tables].reshape(P, M * BS, H, Dh) \
+        k = gather(kcodes).reshape(P, M * BS, H, Dh).astype(q.dtype)
+        v = gather(v_blocks.codes).reshape(P, M * BS, H, Dh) \
             .astype(q.dtype)
-        v = v_blocks.codes[block_tables].reshape(P, M * BS, H, Dh) \
-            .astype(q.dtype)
-        ks = k_blocks.scales[block_tables].reshape(P, M * BS, H) \
+        ks = gather(k_blocks.scales).reshape(P, M * BS, H) \
             .transpose(0, 2, 1)[:, :, None, :]            # [P, H, 1, C]
-        vs = v_blocks.scales[block_tables].reshape(P, M * BS, H) \
+        vs = gather(v_blocks.scales).reshape(P, M * BS, H) \
             .transpose(0, 2, 1)[:, :, None, :]
     else:
-        k = k_blocks[block_tables].reshape(P, M * BS, H, Dh)
-        v = v_blocks[block_tables].reshape(P, M * BS, H, Dh)
+        k = gather(k_blocks).reshape(P, M * BS, H, Dh)
+        v = gather(v_blocks).reshape(P, M * BS, H, Dh)
         ks = vs = None
     s = jnp.einsum("pwhd,pchd->phwc", q, k).astype(jnp.float32) * sc
     if quant:

@@ -24,20 +24,26 @@ Shared machinery:
 
   * `kv_operand_specs` — the scalar-prefetched block-index BlockSpec
     construction: the k/v (and int8 scale) index maps read
-    `tables[row, m]` from a prefetched table, so the pipeline DMAs
-    exactly the pool blocks each query's sequence names and never
-    materializes the [.., M*BS, ...] gather copy the XLA fallback
-    builds.  Scale tiles ride the SAME prefetched index as their
+    `(layer[0], tables[row, m])` from prefetched scalars, so the
+    pipeline DMAs exactly the pool blocks each query's sequence names,
+    out of the WHOLE layer stack, and never materializes the
+    [.., M*BS, ...] gather copy the XLA fallback builds nor a slice of
+    one layer.  Scale tiles ride the SAME prefetched index as their
     codes.
-  * `_load_kv` — the int8-KV dequant (quantized-serving round): pools
-    may be `QuantizedKV` (codes [N, BS, H, Dh] int8 + per-vector
-    scales [N, BS, H]); dequantization happens HERE on the
-    VMEM-resident block in flight, so a bf16 copy of the cache never
-    exists in HBM.
+  * `_load_heads` — the heads of one lane tile of the block in
+    flight, each [BS, Dh], with the int8-KV dequant (quantized-serving
+    round): pools may be `QuantizedKV` (codes [L, N, BS, H*Dh] int8 +
+    per-vector scales [L, N, BS, H]); dequantization happens HERE on
+    the VMEM-resident block, so a bf16 copy of the cache never exists
+    in HBM.
 
 Layout (matches inference/kv_cache.py):
     q:        [T, H, Dh] stream / [B, H, Dh] decode
-    k_blocks: [N, BS, H, Dh]             one layer's pool
+    k_blocks: [L, N, BS, H*Dh] + layer   the pool stack, every token's
+                                         heads side by side on the
+                                         lanes, and a layer index (an
+                                         int or a traced scalar); or
+                                         one layer's [N, BS, H, Dh]
     tables:   [B, M] int32               block ids, 0-padded (trash)
     tile_seg: [T // QT] int32            slot row of each query tile
     tile_pos: [T // QT] int32            abs cache position of each
@@ -63,10 +69,19 @@ where per-row seg/pos metadata must SURVIVE block rotation so
 cross-shard causality stays exact) implement this same contract and
 are parity-tested against each other.
 
-Per (tile, kv-block) step the score tile is [H, QT, BS] from a
-head-batched dot over Dh; online-softmax state (m, l, acc) rides VMEM
-scratch across the M dimension exactly like flash_attention.py, with
-the extra QT query axis on the lanes.
+The pool is read WHERE IT LIES (PR 25).  [L, N, BS, H*Dh] is
+row-major on the device with no padding (a minor dimension of
+[.., H, Dh] with Dh = 64 is padded to the 128 lanes, or re-laid with
+BS on the lanes, and either way XLA re-laid the whole pool around every
+program that handed this kernel a [BS, H, Dh] block: PERF.md section
+6, PR 25).  A block is one contiguous [BS, H*Dh] tile; head h is its
+lanes [h*Dh, (h+1)*Dh).
+
+Per (tile, kv-block) step and head the score tile is [QT, BS] from a
+dot over Dh, in an unrolled loop over the block's 128-lane tiles (two
+heads of 64 each); online-softmax state (m, l, acc) rides VMEM scratch
+across the M dimension exactly like flash_attention.py, one row of it
+per head.
 """
 from __future__ import annotations
 
@@ -106,44 +121,64 @@ def is_quantized(kv):
 def kv_operand_specs(BS, H, Dh, quant, block_id):
     """The scalar-prefetched block-index construction the kernel
     steers its DMA pipeline with:
-    `block_id(*grid_and_prefetch_refs) -> pool block` feeds the k/v
-    BlockSpec index maps, and for int8 pools the per-vector scale tiles
-    ride the SAME index as their codes.  Returns the in_specs list for
-    (k[, ks], v[, vs])."""
-    kv = pl.BlockSpec((1, BS, H, Dh),
-                      lambda *a: (block_id(*a), 0, 0, 0))
+    `block_id(*grid_and_prefetch_refs) -> (layer, pool block)` feeds
+    the k/v BlockSpec index maps over the pool stack, and for int8
+    pools the per-vector scale tiles ride the SAME index as their
+    codes.  Returns the in_specs list for (k[, ks], v[, vs])."""
+    kv = pl.BlockSpec((None, None, BS, H * Dh),
+                      lambda *a: (*block_id(*a), 0, 0))
     if not quant:
         return [kv, kv]
-    sc = pl.BlockSpec((1, BS, H), lambda *a: (block_id(*a), 0, 0))
+    sc = pl.BlockSpec((None, None, BS, H),
+                      lambda *a: (*block_id(*a), 0, 0))
     return [kv, sc, kv, sc]
 
 
-def kv_operands(k_blocks, v_blocks):
+def kv_operands(k_blocks, v_blocks, layer):
     """(quant, operand tuple) for a dense or QuantizedKV pool pair —
-    the argument-flattening half of `kv_operand_specs`."""
-    if is_quantized(k_blocks):
-        return True, (k_blocks.codes, k_blocks.scales,
-                      v_blocks.codes, v_blocks.scales)
-    return False, (k_blocks, v_blocks)
+    the argument-flattening half of `kv_operand_specs`.  Every operand
+    is a stack: blocks and codes [L, N, BS, H*Dh], scales
+    [L, N, BS, H].  With layer None the pools are ONE layer's
+    [N, BS, H, Dh] (scales [N, BS, H]), taken as a stack of one."""
+    quant = is_quantized(k_blocks)
+    operands = (k_blocks.codes, k_blocks.scales, v_blocks.codes,
+                v_blocks.scales) if quant else (k_blocks, v_blocks)
+    if layer is None:
+        operands = tuple(
+            a.reshape((1,) + a.shape[:2] + (-1,)) for a in operands)
+    if any(a.ndim != 4 for a in operands):
+        raise ValueError(
+            "a pool stack is [L, N, BS, H*Dh] and takes layer=; one "
+            "layer's pool is [N, BS, H, Dh] and takes none; got "
+            f"{[a.shape for a in operands]} with layer={layer}")
+    return quant, operands
 
 
-def _load_kv(ref, sref, dt):
-    """One pool block from VMEM, dequantized in place when the pool is
-    int8 (codes * per-vector scales, elementwise).  The convert
+def _load_heads(ref, sref, g, per, dh, dt):
+    """The `per` heads of lane group `g` of the pool block in VMEM, each
+    [BS, Dh]: heads g*per .. g*per + per - 1 are the lanes
+    [g*per*Dh, (g+1)*per*Dh) of the [BS, H*Dh] tile, one aligned lane
+    tile (so `g` may be a loop's index), cut into heads by static slices.
+    Dequantized when the pool is int8: codes * the head's per-vector
+    scales, column g*per + i of the [BS, H] scale tile.  The convert
     happens on the ONE block in flight; no bf16 cache copy ever exists
-    in HBM.  The product is taken in f32: Mosaic refuses the
-    [BS, H] -> [BS, H, 1] shape cast of a bf16 scale tile, and the
-    v5e's VPU has no bf16 arithmetic to lose."""
-    x = ref[0]
+    in HBM.  The product is taken in f32: the v5e's VPU has no bf16
+    arithmetic to lose."""
+    width = per * dh
+    x = ref[:, pl.ds(pl.multiple_of(g * width, width), width)]
+    heads = [x[:, i * dh:(i + 1) * dh] for i in range(per)]
     if sref is None:
-        return x
-    return (x.astype(jnp.float32)
-            * sref[0].astype(jnp.float32)[..., None]).astype(dt)
+        return heads
+    sc = sref[...].astype(jnp.float32)                       # [BS, H]
+    col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    return [(x_i.astype(jnp.float32) * jnp.sum(
+        jnp.where(col == g * per + i, sc, 0.0), axis=1, keepdims=True)
+             ).astype(dt) for i, x_i in enumerate(heads)]
 
 
 # ---- stream kernel (prefill chunks / decode rows / verify regions) ----
 
-def _stream_kernel(tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
+def _stream_kernel(layer_ref, tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
                    *refs, scale, nm, qt, quant, tile_base):
     if quant:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
@@ -152,6 +187,10 @@ def _stream_kernel(tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
         ks_ref = vs_ref = None
     qi = pl.program_id(0)
     mi = pl.program_id(1)
+    nh, _, dh = q_ref.shape
+    # heads side by side in one 128-lane tile of a pool row: the loop
+    # below steps over such tiles, so its index may address the lanes
+    per = max(1, min(nh, 128 // dh))
 
     @pl.when(mi == 0)
     def _init():
@@ -162,58 +201,76 @@ def _stream_kernel(tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
     # abs position of the tile's first query (-1 pad); tile_base shifts
     # a shard-local grid into the GLOBAL prefetch arrays (sp shards)
     q0 = tile_pos_ref[qi + tile_base]
-    bs = k_ref.shape[1]
+    bs = k_ref.shape[0]
 
     # a kv block matters iff it starts at or before the tile's LAST
     # query's causal horizon; pad tiles (q0 < 0) skip every block
     @pl.when((q0 >= 0) & (mi * bs <= q0 + qt - 1))
     def _compute():
-        q = q_ref[:]  # [H, QT, Dh] — input dtype feeds the MXU full-rate
-        k = _load_kv(k_ref, ks_ref, q.dtype)  # [BS, H, Dh]
-        v = _load_kv(v_ref, vs_ref, q.dtype)
-        # s[h, i, j] = sum_d q[h, i, d] * k[j, h, d]: batch over heads
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [H, QT, BS]
-        row = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        col = mi * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(col <= row, s, NEG_INF)  # segment-causal by abs pos
-        m_prev = m_ref[:]                       # [H, QT]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        p = jnp.exp(s - m_new[:, :, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=2)
-        # o[h, i, d] += sum_j p[h, i, j] * v[j, h, d]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)  # [H, QT, Dh]
-        acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
-        m_ref[:] = m_new
+        row = q0 + jax.lax.broadcasted_iota(jnp.int32, (qt, bs), 0)
+        col = mi * bs + jax.lax.broadcasted_iota(jnp.int32, (qt, bs), 1)
+        live = col <= row  # segment-causal by abs pos
+
+        def one_head(h, k, v):
+            q = q_ref[h]  # [QT, Dh] — input dtype feeds the MXU full-rate
+            # s[i, j] = sum_d q[i, d] * k[j, d]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [QT, BS]
+            s = jnp.where(live, s, NEG_INF)
+            m_prev = m_ref[h, :, 0:1]             # [QT, 1]
+            l_prev = l_ref[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            # o[i, d] += sum_j p[i, j] * v[j, d]
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [QT, Dh]
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        def lane_group(g, carry):
+            ks = _load_heads(k_ref, ks_ref, g, per, dh, q_ref.dtype)
+            vs = _load_heads(v_ref, vs_ref, g, per, dh, q_ref.dtype)
+            for i in range(per):
+                one_head(g * per + i, ks[i], vs[i])
+            return carry
+
+        # one traced body, unrolled by the lowering: Python-unrolling the
+        # heads cost every program 0.5 s of tracing (56 programs a warm
+        # set-up), a rolled loop cost the kernel a fifth of its speed (the
+        # scheduler overlaps the heads' dots only in straight-line code)
+        jax.lax.fori_loop(0, nh // per, lane_group, 0, unroll=True)
 
     @pl.when(mi == nm - 1)
     def _flush():
-        l = jnp.maximum(l_ref[:], 1e-30)  # pad tiles flush zeros
-        o_ref[:] = (acc_ref[:] / l[:, :, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, 0:1], 1e-30)  # pad tiles flush zeros
+        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "q_tile", "interpret",
                                     "tile_base"))
 def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
-                                    tile_seg, tile_pos, *, scale=None,
-                                    q_tile=None, interpret=False,
-                                    tile_base=0):
+                                    tile_seg, tile_pos, layer=None, *,
+                                    scale=None, q_tile=None,
+                                    interpret=False, tile_base=0):
     """Pallas segment-causal stream attention: ONE launch scores a
     token-packed stream mixing prefill chunks, plain decode rows and
     speculative verify regions (see module docstring for the layout
     and packing contract); returns [T, H, Dh] in q's dtype.
-    k_blocks/v_blocks may be `QuantizedKV` (codes [N, BS, H, Dh] int8,
-    scales [N, BS, H]) — the scale tiles ride the same
-    scalar-prefetched block index as their codes and dequant happens
-    in VMEM (`_load_kv`).  q_tile defaults to the production
-    Q_TILE=128 (interpret-mode tests shrink it to exercise tiny
-    shapes).
+    k_blocks/v_blocks are the pool STACK [L, N, BS, H*Dh] with `layer`
+    the layer to attend (an int or a traced int32 scalar: it is
+    scalar-prefetched, so every layer's launch of a program shares one
+    kernel body), or one layer's [N, BS, H, Dh] with layer=None.  They
+    may be `QuantizedKV` (codes like the blocks, int8, and scales
+    [L, N, BS, H] or [N, BS, H]) — the scale tiles ride the same
+    scalar-prefetched index as their codes and dequant happens in VMEM
+    (`_load_heads`).  q_tile defaults to the production Q_TILE=128
+    (interpret-mode tests shrink it to exercise tiny shapes).
 
     tile_base (long-context round): static tile offset into the
     scalar-prefetched tile_seg/tile_pos arrays — a SEQUENCE-PARALLEL
@@ -222,11 +279,13 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
     and tile_base=base, and the block-index maps (`tb[ts[qi+base], m]`)
     DMA exactly the pool blocks the shard's own tiles name.  0 (the
     default) is the exact pre-round single-stream kernel."""
-    quant, operands = kv_operands(k_blocks, v_blocks)
+    quant, operands = kv_operands(k_blocks, v_blocks, layer)
+    layer = jnp.reshape(jnp.asarray(0 if layer is None else layer,
+                                    jnp.int32), (1,))
     qt = Q_TILE if q_tile is None else int(q_tile)
     tile_base = int(tile_base)
     T, H, Dh = q.shape
-    _, BS, _, _ = operands[0].shape
+    BS = operands[0].shape[2]
     M = tables.shape[1]
     if T % qt:
         raise ValueError(f"packed length {T} not a multiple of the "
@@ -240,22 +299,24 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
 
     qh = q.transpose(1, 0, 2)  # [H, T, Dh]: heads ride the sublane axis
     q_spec = pl.BlockSpec((H, qt, Dh),
-                          lambda qi, m, ts, tp, tb: (0, qi, 0))
+                          lambda qi, m, ly, ts, tp, tb: (0, qi, 0))
     in_specs = [q_spec] + kv_operand_specs(
         BS, H, Dh, quant,
-        lambda qi, m, ts, tp, tb: tb[ts[qi + tile_base], m])
+        lambda qi, m, ly, ts, tp, tb: (ly[0], tb[ts[qi + tile_base], m]))
     kernel = functools.partial(_stream_kernel, scale=scale, nm=M,
                                qt=qt, quant=quant, tile_base=tile_base)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tile_seg, tile_pos, tables steer the DMA
+        # layer, tile_seg, tile_pos, tables steer the DMA
+        num_scalar_prefetch=4,
         grid=(NQ, M),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((H, qt, Dh),
-                               lambda qi, m, ts, tp, tb: (0, qi, 0)),
+                               lambda qi, m, ly, ts, tp, tb: (0, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, qt, Dh), jnp.float32),
-            pltpu.VMEM((H, qt), jnp.float32),
-            pltpu.VMEM((H, qt), jnp.float32),
+            # m, l: one value a row, kept across a lane tile
+            pltpu.VMEM((H, qt, 128), jnp.float32),
+            pltpu.VMEM((H, qt, 128), jnp.float32),
         ],
     )
     # decode rows ride DECODE_TILE tiles, every other caller (chunk
@@ -267,7 +328,7 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, T, Dh), q.dtype),
         interpret=interpret,
-    )(tile_seg.astype(jnp.int32), tile_pos.astype(jnp.int32),
+    )(layer, tile_seg.astype(jnp.int32), tile_pos.astype(jnp.int32),
       tables.astype(jnp.int32), qh, *operands)
     return out.transpose(1, 0, 2)
 
@@ -275,9 +336,11 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
 # ---- decode (one token per sequence) --------------------------------
 
 def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
-                                  *, scale=None, interpret=False):
+                                  layer=None, *, scale=None,
+                                  interpret=False):
     """Ragged paged decode attention: q [B, H, Dh], one token per
-    sequence attending cache positions [0, ctx_len).  A decode row is a
+    sequence attending cache positions [0, ctx_len) of the pool stack's
+    `layer` (or of one layer's pool, layer=None).  A decode row is a
     one-token segment of the stream, so this IS the stream kernel at a
     DECODE_TILE-row query tile: row b sits at stream row b*DECODE_TILE
     with pos = ctx_len - 1 and the tile's other rows are zero padding
@@ -289,6 +352,6 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
     out = unified_ragged_attention_kernel(
         stream.reshape((B * DECODE_TILE,) + q.shape[1:]), k_blocks,
         v_blocks, tables, jnp.arange(B, dtype=jnp.int32),
-        ctx_lens.astype(jnp.int32) - 1, scale=scale, q_tile=DECODE_TILE,
-        interpret=interpret)
+        ctx_lens.astype(jnp.int32) - 1, layer, scale=scale,
+        q_tile=DECODE_TILE, interpret=interpret)
     return out[::DECODE_TILE]

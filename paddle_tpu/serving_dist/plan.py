@@ -89,15 +89,16 @@ def param_shardings(mesh, params):
 
 def kv_pool_specs(kv_dtype=None):
     """(k_blocks, v_blocks) sharding-spec pytrees for the pool arrays:
-    [L, num_blocks, block_size, H, Dh] with heads over mp and blocks
-    over dp.  For an int8 pool the per-vector scale buffer
-    [L, num_blocks, block_size, H] shards identically minus Dh, so
-    codes and scales stay in lockstep under every block operation."""
-    codes = P(None, "dp", None, "mp", None)
+    [L, num_blocks, block_size, H*Dh] with heads over mp (they are the
+    major half of the minor axis, so a shard holds whole heads) and
+    blocks over dp.  For an int8 pool the per-vector scale buffer
+    [L, num_blocks, block_size, H] shards identically, so codes and
+    scales stay in lockstep under every block operation."""
+    codes = P(None, "dp", None, "mp")
     if kv_dtype == "int8":
         from ..inference.kv_quant import QuantizedKV
 
-        spec = QuantizedKV(codes, P(None, "dp", None, "mp"))
+        spec = QuantizedKV(codes, codes)
     elif kv_dtype is None:
         spec = codes
     else:

@@ -144,16 +144,27 @@ def segment_starts(seg, pos, num_segments):
     return jnp.full((num_segments,), big, jnp.int32).at[seg].min(p)
 
 
-def kv_set_layer(cache, i, new, kv_quant):
-    """Functional single-layer write-back into the full pool stack —
-    the inverse of `nn.decode._kv_io`'s `layer` accessor, for trunks
-    whose attention seam updates a whole layer slice at once."""
-    if kv_quant:
-        from ..inference.kv_quant import QuantizedKV
+def kv_get_layer(cache, i, num_heads):
+    """One layer's pool out of the stack, with the heads named:
+    [N, BS, H, Dh] from [L, N, BS, H*Dh] (dense, or the codes of a
+    QuantizedKV, whose scales [N, BS, H] come along), for the seam
+    below, which takes and returns a whole layer.  Every other trunk
+    hands the attention ops the stack and `layer=`."""
+    import jax
 
-        return QuantizedKV(cache.codes.at[i].set(new.codes),
-                           cache.scales.at[i].set(new.scales))
-    return cache.at[i].set(new)
+    from ..inference.kv_cache import split_heads
+
+    return jax.tree.map(lambda a: split_heads(a[i], num_heads), cache)
+
+
+def kv_set_layer(cache, i, new):
+    """Functional single-layer write-back into the full pool stack —
+    the inverse of `kv_get_layer`, for trunks whose attention seam
+    updates a whole layer slice at once."""
+    import jax
+
+    return jax.tree.map(
+        lambda a, n: a.at[i].set(n.reshape(a.shape[1:])), cache, new)
 
 
 @functools.lru_cache(maxsize=16)

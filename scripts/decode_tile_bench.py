@@ -35,22 +35,31 @@ def main():
         raise SystemExit("decode_tile_bench.py: no TPU; a time comes only "
                          "from a chip run")
     rs = np.random.RandomState(0)
-    kb = jnp.asarray(rs.randn(N, BS, H, DH), jnp.bfloat16)
-    vb = jnp.asarray(rs.randn(N, BS, H, DH), jnp.bfloat16)
-    pools = {"dense": (kb, vb),
-             "int8": (QuantizedKV(*kv_encode(kb)), QuantizedKV(*kv_encode(vb)))}
+    # one layer as the engine holds it: a stack [1, N, BS, H*Dh] of one
+    kb = jnp.asarray(rs.randn(1, N, BS, H, DH), jnp.bfloat16)
+    vb = jnp.asarray(rs.randn(1, N, BS, H, DH), jnp.bfloat16)
+
+    def rows(x):
+        return x.reshape(1, N, BS, H * DH)
+
+    def int8(x):
+        codes, scales = kv_encode(x)
+        return QuantizedKV(rows(codes), scales)
+
+    pools = {"dense": (rows(kb), rows(vb)), "int8": (int8(kb), int8(vb))}
 
     def kernel(q, k, v, tables, lens, qt):
         B = q.shape[0]
         stream = jnp.pad(q[:, None], ((0, 0), (0, qt - 1), (0, 0), (0, 0)))
         return unified_ragged_attention_kernel(
             stream.reshape(B * qt, H, DH), k, v, tables,
-            jnp.arange(B, dtype=jnp.int32), lens - 1, q_tile=qt)[::qt]
+            jnp.arange(B, dtype=jnp.int32), lens - 1, 0, q_tile=qt)[::qt]
 
     def gather(q, k, v, tables, lens, _qt):  # the XLA path, chosen by hand
         saved, attention._on_tpu = attention._on_tpu, lambda: False
         try:
-            return attention.paged_decode_attention(q, k, v, tables, lens)
+            return attention.paged_decode_attention(q, k, v, tables, lens,
+                                                    layer=0)
         finally:
             attention._on_tpu = saved
 

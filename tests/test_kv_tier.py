@@ -52,13 +52,16 @@ def _fill_blocks(cache, seq, n_tokens, rng):
         if cache.kv_dtype == "int8":
             kc, ks = kv_encode(jnp.asarray(kk))
             vc, vs = kv_encode(jnp.asarray(vv))
-            k = type(k)(k.codes.at[:, b, :rows].set(kc),
+            # pool rows hold a token's heads side by side: [.., H*Dh]
+            merged = (cache.num_layers, rows, -1)
+            k = type(k)(k.codes.at[:, b, :rows].set(kc.reshape(merged)),
                         k.scales.at[:, b, :rows].set(ks))
-            v = type(v)(v.codes.at[:, b, :rows].set(vc),
+            v = type(v)(v.codes.at[:, b, :rows].set(vc.reshape(merged)),
                         v.scales.at[:, b, :rows].set(vs))
         else:
-            k = k.at[:, b, :rows].set(kk)
-            v = v.at[:, b, :rows].set(vv)
+            merged = (cache.num_layers, rows, -1)
+            k = k.at[:, b, :rows].set(kk.reshape(merged))
+            v = v.at[:, b, :rows].set(vv.reshape(merged))
     cache.swap_arrays(k, v)
     return {b: jax.tree.map(lambda a: np.asarray(a[:, b]),
                             cache.k_blocks) for b in tbl}

@@ -74,9 +74,10 @@ def check_invariants(c):
         for kv in (c.k_blocks, c.v_blocks):
             assert str(kv.codes.dtype) == "int8"
             assert kv.codes.shape == (c.num_layers, c.num_blocks,
-                                      c.block_size, c.num_heads,
-                                      c.head_dim)
-            assert kv.scales.shape == kv.codes.shape[:-1]
+                                      c.block_size,
+                                      c.num_heads * c.head_dim)
+            assert kv.scales.shape == kv.codes.shape[:-1] + (
+                c.num_heads,)
     # host tier (long-context round): the tier index is DISJOINT from
     # the device index (move semantics), stays within capacity, and
     # its token accounting is internally consistent — so tiering adds

@@ -66,20 +66,23 @@ def _kernels(fn, *args):
             "tpu_custom_call")
 
 
+LAYERS = 2  # depth of the pool stacks and programs compiled here
+
+
 def _pool(sharding, quant, spec=None):
-    """One layer's K (or V) pool as shapes: dense bf16, or int8 codes with
-    per-vector scales in the compute dtype (what a bf16 engine holds)."""
-    def s(shape, dt, spec):
+    """The K (or V) pool stack as shapes, [L, N, BS, H*Dh] as the engine
+    holds it: dense bf16, or int8 codes with per-vector scales [L, N, BS, H]
+    in the compute dtype (what a bf16 engine holds)."""
+    def s(shape, dt):
         sh = sharding if spec is None else NamedSharding(sharding, spec)
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
+    rows = (LAYERS, N_BLOCKS, BS)
     if not quant:
-        return s((N_BLOCKS, BS, H, DH), jnp.bfloat16, spec)
+        return s(rows + (H * DH,), jnp.bfloat16)
     from paddle_tpu.inference.kv_quant import QuantizedKV
-    return QuantizedKV(
-        s((N_BLOCKS, BS, H, DH), jnp.int8, spec),
-        s((N_BLOCKS, BS, H), jnp.bfloat16,
-          None if spec is None else P(*spec[:3])))
+    return QuantizedKV(s(rows + (H * DH,), jnp.int8),
+                       s(rows + (H,), jnp.bfloat16))
 
 
 def _flash_args(one_chip, b, h, s, d):
@@ -130,7 +133,8 @@ def test_stream_kernel_compiles(one_chip, quant):
     q = jax.ShapeDtypeStruct((T, H, DH), jnp.bfloat16, sharding=one_chip)
     assert _kernels(unified_ragged_attention_kernel, q,
                     _pool(one_chip, quant), _pool(one_chip, quant),
-                    i32(B, M), i32(T // Q_TILE), i32(T // Q_TILE)) == 1
+                    i32(B, M), i32(T // Q_TILE), i32(T // Q_TILE),
+                    i32()) == 1
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
@@ -145,7 +149,7 @@ def test_decode_path_compiles(one_chip, quant):
     q = jax.ShapeDtypeStruct((B, H, DH), jnp.bfloat16, sharding=one_chip)
     assert _kernels(paged_decode_attention_kernel, q,
                     _pool(one_chip, quant), _pool(one_chip, quant),
-                    i32(B, M), i32(B)) == 1
+                    i32(B, M), i32(B), i32()) == 1
 
 
 def _kernel_op_names(fn, *args):
@@ -177,11 +181,13 @@ def test_kernels_are_named_in_the_compiled_program(one_chip):
     pool = _pool(one_chip, False)
     q = jax.ShapeDtypeStruct((32, H, DH), jnp.bfloat16, sharding=one_chip)
     assert _kernel_op_names(paged_decode_attention_kernel, q, pool, pool,
-                            i32(32, M), i32(32)) == ["paged_attn_decode"]
+                            i32(32, M), i32(32),
+                            i32()) == ["paged_attn_decode"]
     q = jax.ShapeDtypeStruct((512, H, DH), jnp.bfloat16, sharding=one_chip)
     assert _kernel_op_names(
         unified_ragged_attention_kernel, q, pool, pool, i32(8, M),
-        i32(512 // Q_TILE), i32(512 // Q_TILE)) == ["paged_attn_prefill"]
+        i32(512 // Q_TILE), i32(512 // Q_TILE),
+        i32()) == ["paged_attn_prefill"]
 
 
 def test_head_sharded_kernels_compile_on_four_devices(mesh4, monkeypatch):
@@ -199,7 +205,7 @@ def test_head_sharded_kernels_compile_on_four_devices(mesh4, monkeypatch):
     assert attention.paged_attention_path(DH, BS, 4, 512, mesh4) == "xla"
 
     rep = NamedSharding(mesh4, P())
-    pool = P("dp", None, "mp", None)
+    pool = P(None, "dp", None, "mp")
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
 
     def heads(n):
@@ -211,13 +217,85 @@ def test_head_sharded_kernels_compile_on_four_devices(mesh4, monkeypatch):
     kv = _pool(mesh4, False, pool)
     assert _kernels(
         lambda q, k, v, tb, seg, pos: attention.ragged_prefill_attention(
-            q, k, v, tb, seg, pos, mesh=mesh4),
+            q, k, v, tb, seg, pos, mesh=mesh4, layer=1),
         heads(T), kv, kv, i32(B, M), i32(T), i32(T)) == 1
     kv8 = _pool(mesh4, True, pool)
     assert _kernels(
         lambda q, k, v, tb, ctx: attention.paged_decode_attention(
-            q, k, v, tb, ctx, mesh=mesh4),
+            q, k, v, tb, ctx, mesh=mesh4, layer=1),
         heads(B), kv8, kv8, i32(B, M), i32(B)) == 1
+
+
+def _gpt2_medium_params(sharding, layers):
+    E = H * DH
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding)
+    p = {"wte.weight": f(50257, E), "wpe.weight": f(1024, E),
+         "ln_f.weight": f(E), "ln_f.bias": f(E)}
+    for i in range(layers):
+        h = f"h.{i}."
+        p.update({
+            h + "ln_1.weight": f(E), h + "ln_1.bias": f(E),
+            h + "ln_2.weight": f(E), h + "ln_2.bias": f(E),
+            h + "qkv_proj.weight": f(E, 3 * E), h + "qkv_proj.bias": f(3 * E),
+            h + "out_proj.weight": f(E, E), h + "out_proj.bias": f(E),
+            h + "fc1.weight": f(E, 4 * E), h + "fc1.bias": f(4 * E),
+            h + "fc2.weight": f(4 * E, E), h + "fc2.bias": f(E)})
+    return p
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("program", ["step", "packed_prefill"])
+def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
+                                                    program, quant):
+    """GPT-2-medium's width at depth 2, through the Pallas path, as the
+    chip's compiler schedules it: the donated pools are aliased to the
+    outputs, one kernel a layer reads the stack, and nothing the program
+    produces but the K/V scatters is as large as one layer's pool — no
+    slice of a layer, no copy, no re-laid copy of the stack (PR 25: the
+    parent's programs held three pools' worth of those)."""
+    import re
+
+    from test_pool_in_place import pool_sized_instructions
+
+    from paddle_tpu.nn import decode
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    spec = (LAYERS, H, DH, H * DH, 1e-5, True)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    pool = _pool(one_chip, quant)
+    params = _gpt2_medium_params(one_chip, LAYERS)
+    if program == "step":
+        _, fn = decode._build_paged_fns(spec, BS, False, (False, False),
+                                        quant)
+        args = (params, i32(32), i32(32),
+                jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip),
+                i32(32, M), pool, pool, {"stop": i32(32, 1)})
+        donate = (5, 6)
+    else:
+        fn = decode._build_packed_prefill(spec, BS, False, (False, False),
+                                          quant)
+        args = (params, i32(512), i32(512), i32(512), i32(4, M), i32(4),
+                pool, pool, {"stop": i32(4, 1)})
+        donate = (6, 7)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == LAYERS
+    n_pools = 4 if quant else 2   # codes and scales, or the blocks
+    assert len(re.findall(r"(?:may|must)-alias",
+                          text.split("\n", 1)[0])) == n_pools
+    layer_elems = N_BLOCKS * BS * H * DH
+    big = [b for b in pool_sized_instructions(text, layer_elems)
+           if "scatter" not in b[2]]
+    # the head and the embedding are the vocabulary matrix, not the pool
+    big = [b[:2] for b in big if "[50257," not in b[2].split(" = ", 1)[1][:24]]
+    assert not big, big
+    if not quant:
+        # an int8 pool's scale stacks [L, N, BS, H] are still re-laid (H
+        # is narrower than the lanes): 1/64 of the codes, PERF.md section 7
+        layer_bytes = 2 * layer_elems * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 def test_flash_under_dp_mp_mesh_compiles(mesh4, monkeypatch):
