@@ -290,12 +290,33 @@ class PagedKVCache:
         attached below the pool — cold retained blocks demote to host
         RAM instead of being dropped, and prefix matches promote them
         back. None (default) keeps the pre-tier behaviour exactly.
+    state_layout, state_slots: a recurrent-state store beside the pool
+        (`nn.decode_blocks.DecoderDescription.cache_layout()`; build
+        with `for_description`).  The pool is then ONE array of rows
+        `num_heads * head_dim` wide (`k_blocks`: latents, not heads;
+        `v_blocks` is None) under the same allocator and block tables,
+        and `state` is the store: {"S": [L, slots + 1, H, D, D] float32,
+        "conv": [L, slots + 1, K-1, C]} indexed by a slot that a
+        sequence takes with its first block and gives back in `free`
+        (slot 0 is the trash slot).  A slot is not zeroed on the device
+        when it changes hands: a program that starts a sequence at
+        position 0 starts from zero state whatever the slot holds, and
+        until another sequence takes the slot it keeps its last
+        holder's state.  `table_array` rows are then
+        [state slot | blocks].
     """
 
     def __init__(self, num_layers, num_heads, head_dim, *, block_size=128,
                  num_blocks=64, dtype=None, kv_dtype=None, name=None,
-                 tier=None):
+                 tier=None, state_layout=None, state_slots=0):
         import jax.numpy as jnp
+
+        if state_layout is not None and (kv_dtype is not None
+                                         or tier is not None):
+            raise ValueError(
+                "a cache with a recurrent-state store takes neither "
+                "kv_dtype nor tier: its pool and store are dense and "
+                "device-resident")
 
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
@@ -324,9 +345,29 @@ class PagedKVCache:
             self.v_blocks = QuantizedKV(
                 jnp.zeros(shape, jnp.int8),
                 jnp.zeros(rows + (self.num_heads,), dt))
-        else:
+        elif state_layout is None:
             self.k_blocks = jnp.zeros(shape, dt)
             self.v_blocks = jnp.zeros(shape, dt)
+        else:
+            # one pool of rows (a latent pool: `num_heads * head_dim` is
+            # the row's width, there is no V) and, beside it, the
+            # slot-indexed store of what is not keys and values
+            st = (state_layout["state_layers"], int(state_slots) + 1)
+            self.k_blocks = jnp.zeros(shape, dt)
+            self.v_blocks = None
+            self.state = {
+                "S": jnp.zeros(st + tuple(state_layout["state_shape"]),
+                               jnp.float32),
+                "conv": jnp.zeros(st + tuple(state_layout["conv_shape"]),
+                                  dt)}
+        if state_layout is None:
+            self.state = None
+        # the recurrent-state store's slots (slot 0 reserved: trash), each
+        # held by one sequence from its first block to its `free`
+        self.state_slots = int(state_slots) if state_layout else 0
+        self._state_free = list(range(self.state_slots, 0, -1))
+        self._state_of: dict[object, int] = {}
+        self._peak_state = 0
         # block 0 reserved: free list starts at 1
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._tables: dict[object, list[int]] = {}
@@ -376,6 +417,32 @@ class PagedKVCache:
         self._tier_owner: dict[int, tuple] = {}     # hash -> (tenant, bytes)
         if tier is not None:
             self.attach_tier(tier)
+
+    @classmethod
+    def for_description(cls, desc, *, block_size, num_blocks, dtype,
+                        max_slots):
+        """The cache a `nn.decode_blocks.DecoderDescription` needs: a
+        latent pool with a state store of `max_slots` slots."""
+        layout = desc.cache_layout()
+        return cls(layout["pool_layers"], 1, layout["row_width"],
+                   block_size=block_size, num_blocks=num_blocks,
+                   dtype=dtype, state_layout=layout, state_slots=max_slots)
+
+    # ---- the recurrent-state store's slots ----------------------------
+    @property
+    def free_state_slots(self):
+        """State slots a new sequence can take (0 when there is no
+        store: ask `state_slots` first)."""
+        return len(self._state_free)
+
+    def reset_state_peak(self):
+        """Start `peak_used_slots` again from the slots held now (the
+        server's `reset_stats`)."""
+        self._peak_state = len(self._state_of)
+
+    def state_slot(self, seq_id):
+        """The store slot `seq_id` holds (0, the trash slot, if none)."""
+        return self._state_of.get(seq_id, 0)
 
     # ---- pool bookkeeping (host-side) ---------------------------------
     @property
@@ -933,6 +1000,17 @@ class PagedKVCache:
                 f"only {len(self._free)} free + {len(self._retained)} "
                 f"reclaimable (pool {self.num_blocks - 1})",
                 needed=total, available=self.available_block_count)
+        if self.state_slots:
+            fresh = [s for s, _n in updates if s not in self._tables]
+            if len(fresh) > len(self._state_free):
+                raise BlockPoolExhausted(
+                    f"{len(fresh)} new sequences need a recurrent-state "
+                    f"slot each, only {len(self._state_free)} of "
+                    f"{self.state_slots} free", needed=len(fresh),
+                    available=len(self._state_free))
+            for s in fresh:
+                self._state_of[s] = self._state_free.pop()
+            self._peak_state = max(self._peak_state, len(self._state_of))
         for (seq_id, n), grow in zip(updates, need):
             table = self._tables.setdefault(seq_id, [])
             if grow:
@@ -956,6 +1034,9 @@ class PagedKVCache:
         del self._tables[seq_id]
         del self._lens[seq_id]
         self._seq_owner.pop(seq_id, None)
+        slot = self._state_of.pop(seq_id, None)
+        if slot is not None:   # the state goes with the blocks
+            self._state_free.append(slot)
         for b in reversed(table):
             self._release_block(b)
         self.maybe_demote()    # retention may have grown past watermark
@@ -1325,19 +1406,25 @@ class PagedKVCache:
                 for s in seq_ids]
         if width is None:
             width = max((len(r) for r in rows), default=1) or 1
-        out = np.zeros((len(rows), int(width)), np.int32)
+        lead = 1 if self.state_slots else 0   # [state slot | blocks]
+        out = np.zeros((len(rows), lead + int(width)), np.int32)
         for i, r in enumerate(rows):
             if len(r) > width:
                 raise ValueError(f"block table of {seq_ids[i]!r} "
                                  f"({len(r)}) exceeds width {width}")
-            out[i, :len(r)] = r
+            out[i, lead:lead + len(r)] = r
+            if lead:
+                out[i, 0] = self._state_of.get(seq_ids[i], 0)
         return out
 
-    def swap_arrays(self, k_blocks, v_blocks):
+    def swap_arrays(self, k_blocks, v_blocks, state=None):
         """Install the updated device arrays a jitted prefill/step
-        returned (the functional write-back half of the cycle)."""
+        returned (the functional write-back half of the cycle); `state`
+        is the recurrent-state store of a cache that has one."""
         self.k_blocks = k_blocks
         self.v_blocks = v_blocks
+        if self.state is not None:
+            self.state = state
 
     def block_fill(self):
         """Live tokens / allocated block capacity — the
@@ -1412,6 +1499,10 @@ class PagedKVCache:
             # host-RAM tier block: zeroed-when-disabled, so the schema
             # is identical with and without a tier attached
             "tier": self._tier_stats(),
+            # the recurrent-state store (zeros when the cache has none)
+            "state": {"slots": self.state_slots,
+                      "used_slots": len(self._state_of),
+                      "peak_used_slots": self._peak_state},
         }
 
     def _tier_stats(self):

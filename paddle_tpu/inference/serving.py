@@ -25,6 +25,8 @@ same regardless — decode time is batch-invariant at fixed B).
 from __future__ import annotations
 
 import itertools
+import math
+from collections import deque
 import os
 import threading
 import time
@@ -238,6 +240,9 @@ STOP_REASONS = ("eos", "stop_token", "stop_string", "budget")
 
 HEALTH_CODES = {"ok": 0.0, "degraded": 1.0, "stalled": 2.0}
 
+#: dispatches whose expert counters `stats()["experts"]["dispatches"]` keeps
+EXPERT_RING = 4096
+
 # What the paged engine's thread does in a round, one vocabulary for
 # every loop (split, unified, unified async):
 #   admit      taking the lock at the round boundary, host ops,
@@ -415,6 +420,7 @@ class _Req:
     # resume_ids = ids ++ gen0, the prompt the resume re-prefills.
     meta: "RequestMeta | None" = None
     on_token: object = None
+    on_routing: object = None
     gen0: tuple = ()
     resume_ids: np.ndarray | None = None
     preempts: int = 0  # times this request has been swapped out
@@ -1019,6 +1025,35 @@ class PagedGenerationServer:
 
         self._jnp, self._jax = jnp, jax
         cfg = model.cfg
+        # a model of latent and recurrent layers hands over its own
+        # description (`nn.decode_blocks`); a model without one is the
+        # GPT-2 layout its cfg spells out.  A description serves through
+        # the default loop alone, over dense device-resident caches:
+        # every option that would need its state shared, rolled back,
+        # moved, quantized or sharded is refused here, by name.
+        self._desc = (model.decoder_description()
+                      if hasattr(model, "decoder_description") else None)
+        if self._desc is not None:
+            for name, value, ok in (
+                    ("enable_prefix_cache", bool(enable_prefix_cache),
+                     False),
+                    ("speculation", speculation, None),
+                    ("kv_dtype", kv_dtype, None),
+                    ("quantization", quantization, None),
+                    ("weight_quant", weight_quant, None),
+                    ("unified_round", bool(unified_round), False),
+                    ("async_rounds", bool(async_rounds), False),
+                    ("steps_per_dispatch", int(steps_per_dispatch), 1),
+                    ("sharding", sharding, None),
+                    ("kv_tier", kv_tier or None, None),
+                    ("tier_prefetch", tier_prefetch or None, None)):
+                if value != ok:
+                    raise ValueError(
+                        f"PagedGenerationServer({name}={value!r}) has no "
+                        f"meaning yet beside a recurrent-state or latent "
+                        f"cache: this model serves through the default "
+                        f"loop (packed_prefill + decode_step) with "
+                        f"{name}={ok!r}")
         self.max_new = int(max_new_tokens)
         self.steps_per_dispatch = max(1, int(steps_per_dispatch))
         # speculation (round 11): True -> default SpecConfig; a
@@ -1116,7 +1151,26 @@ class PagedGenerationServer:
             raise ValueError("prefill_chunk_tokens must be >= 1")
         if pack_align is None:  # Pallas kernel query-tile contract on TPU
             pack_align = 128 if jax.default_backend() not in ("cpu",) else 8
+        if self._desc is not None:
+            # a recurrent chunk and a latent tile are wholly one
+            # sequence's: a packed region starts on their boundary
+            pack_align = math.lcm(int(pack_align),
+                                  self._desc.pack_multiple)
         self._pack_align = int(pack_align)
+        # a program of a description with experts, a latent pool and a
+        # state store costs tens of seconds to compile and next to
+        # nothing for plan rows it does not use (the head reads its
+        # weights whatever the rows; the latent walk stops at the deepest
+        # token): its packed prefill is bucketed by packed length alone,
+        # with every slot's row and the full table width
+        self._one_plan_shape = self._desc is not None
+        if self._one_plan_shape \
+                and self.prefill_chunk_tokens % self._pack_align:
+            raise ValueError(
+                f"prefill_chunk_tokens ({self.prefill_chunk_tokens}) must "
+                f"be a multiple of the packed regions' alignment "
+                f"({self._pack_align}) for this model: its packed prefill "
+                f"is bucketed by packed length alone")
         # verify regions only need alignment where the Pallas kernel
         # runs; the XLA fallback takes any packing, and a verify
         # dispatch fires every round — off TPU, padding each K+1-token
@@ -1152,7 +1206,8 @@ class PagedGenerationServer:
         if quantization == "w8a16":
             params = model.quantize_weights(params)
         self._params = params
-        dt = params["ln_f.weight"].dtype
+        dt = params["ln_f.weight" if self._desc is None
+                    else self._desc.final_norm].dtype
         self.enable_prefix_cache = bool(enable_prefix_cache)
         self._m_width = blocks_for(
             self.max_prompt_len + self.max_new + slack, self.block_size)
@@ -1204,11 +1259,19 @@ class PagedGenerationServer:
         self._prefetch_wasted = 0
         self._prefetch_overlap_s = 0.0
         self._promote_ctx = None  # rid the in-progress attach serves
-        self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_heads,
-            cfg.hidden_size // cfg.num_heads, block_size=self.block_size,
-            num_blocks=int(num_blocks), dtype=dt, kv_dtype=kv_dtype,
-            tier=kv_tier)
+        if self._desc is None:
+            self.cache = PagedKVCache(
+                cfg.num_layers, cfg.num_heads,
+                cfg.hidden_size // cfg.num_heads,
+                block_size=self.block_size, num_blocks=int(num_blocks),
+                dtype=dt, kv_dtype=kv_dtype, tier=kv_tier)
+        else:
+            # built from the description: a latent pool under the same
+            # allocator, a state slot a server slot beside it
+            self.cache = PagedKVCache.for_description(
+                self._desc, block_size=self.block_size,
+                num_blocks=int(num_blocks), dtype=dt,
+                max_slots=self.max_slots)
         self._blocks_for = blocks_for
         # sharded serving (serving_dist round): a ShardedEngineConfig
         # (or True for defaults) places the snapshotted/quantized
@@ -1231,8 +1294,8 @@ class PagedGenerationServer:
                                                       self._mesh)
         # the decoder's kv_dtype MUST match the cache's — PagedDecoder
         # re-checks the pairing eagerly on every dispatch
-        self._decoder = PagedDecoder.for_config(
-            cfg, self.block_size, kv_dtype=kv_dtype,
+        self._decoder = PagedDecoder.for_model(
+            model, self.block_size, kv_dtype=kv_dtype,
             shardings=decode_shardings,
             collective_quant=collective_quant,
             sp_attention=self._sp_attention)
@@ -1242,11 +1305,12 @@ class PagedGenerationServer:
         # gauge and, for ring/ulysses, every dispatch is asserted
         # under the chunk-length-independent flat bound
         self._sp_peak_bytes = 0
+        heads = self._desc.heads if self._desc is not None \
+            else cfg.num_heads
         self._sp_bytes_kw = dict(
             sp=self._sp_degree,
             tp=(sharding.tp if sharding is not None else 1),
-            num_heads=cfg.num_heads,
-            head_dim=cfg.hidden_size // cfg.num_heads,
+            num_heads=heads, head_dim=cfg.hidden_size // heads,
             kv_quant=kv_dtype == "int8",
             itemsize=jnp.dtype(dt).itemsize)
         # per-slot sampling state (round 10): struct-of-arrays param
@@ -1295,6 +1359,14 @@ class PagedGenerationServer:
         # construction at every dispatch site
         self._decoded_tokens = 0
         self._replayed_tokens = 0
+        # the expert layers' counters (always on, reset with the window):
+        # running sums over the dispatches that routed a token, and the
+        # newest entries themselves in a bounded ring: (perf_counter,
+        # tokens, held picks, experts touched, max load, expert layers),
+        # the counts summed over the expert layers of a dispatch and the
+        # max taken over them
+        self._expert_sums = [0, 0, 0, 0, 0]   # the entry's fields 1..5
+        self._expert_ring = deque(maxlen=EXPERT_RING)
         # one-kernel round (r16): per-round dispatch accounting (both
         # engine paths — the split path reports its 1-3 dispatches per
         # round here too, so the fusion win is measurable), async
@@ -2571,7 +2643,16 @@ class PagedGenerationServer:
         # bucket family scales with it
         budget = self.prefill_chunk_tokens * self._sp_degree
         pairs = set()
-        for rows in range(1, min(self.max_slots, budget) + 1):
+        if self._one_plan_shape:
+            # one program a packed length: rows and table width are fixed
+            T = align
+            while T <= budget:
+                pairs.add((T, self._plan_rows(1)))
+                T *= 2
+            rows_range = ()
+        else:
+            rows_range = range(1, min(self.max_slots, budget) + 1)
+        for rows in rows_range:
             P = 1
             while P < rows:
                 P *= 2
@@ -2591,7 +2672,7 @@ class PagedGenerationServer:
                 T *= 2
         widths = []
         w = 1
-        while w < self._m_width:
+        while w < self._m_width and not self._one_plan_shape:
             widths.append(w)
             w *= 2
         widths.append(self._m_width)  # the min(pow2, m_width) cap
@@ -2603,19 +2684,20 @@ class PagedGenerationServer:
                     # count buffer is donated on accelerators, so a
                     # reused dict would hand back an invalidated array
                     sp = self._sp_store.warm_args(P, mode)
-                    tok, stopped, kc, vc, counts = \
+                    _tok, _stopped, kc, vc, counts, *_routed = \
                         self._decoder.packed_prefill(
                             self._params, jnp.zeros((T,), jnp.int32),
                             jnp.zeros((T,), jnp.int32),
                             jnp.full((T,), -1, jnp.int32),
-                            jnp.zeros((P, mcap), jnp.int32),
+                            jnp.asarray(self.cache.table_array(
+                                [None] * P, mcap)),
                             jnp.zeros((P,), jnp.int32),
                             self.cache.k_blocks, self.cache.v_blocks,
-                            sp, mode)
+                            sp, mode, state=self.cache.state)
                     # reinstall the round-tripped arrays (donated on
                     # accelerators); only trash-block rows were written
                     self._sp_store.swap_counts(counts)
-                    self.cache.swap_arrays(kc, vc)
+                    self._swap_cache(kc, vc)
                     n += 1
         _logger.info("warm_buckets: compiled %d packed-prefill "
                      "variants (%d shape pairs x %d widths x %d modes)",
@@ -2696,7 +2778,7 @@ class PagedGenerationServer:
     # ---- client API ----------------------------------------------------
     def submit(self, ids, max_new_tokens=None, sampling=None, *,
                meta=None, on_token=None, timeout_s=None, rid=None,
-               trace_ctx=None):
+               trace_ctx=None, on_routing=None):
         """Enqueue one prompt (any length <= max_prompt_len; NO padding
         needed). Returns a Future resolving to the UNPADDED
         [len + generated] int32 sequence (generation stops at EOS, a
@@ -2738,6 +2820,16 @@ class PagedGenerationServer:
         entry and journal record the request touches is stamped with
         trace_id / hop / cause (+ the replica name on a fleet).
 
+        on_routing: optional callable `(position, picks, state_slot)`
+        for a model with routed experts, invoked from the engine thread
+        after every dispatch that fed this request's tokens: `picks`
+        [expert layers, n, k] int32 are the experts the routers chose
+        for the n tokens from `position` on (a preempted request's
+        positions come again), `state_slot` the slot of the recurrent-
+        state store the sequence holds (0 without one); the slot keeps
+        the sequence's last state until another sequence takes it.  As
+        fast and as harmless as `on_token` must be.
+
         When the server was built with `shed_queue_depth=`, a submit
         arriving at a queue already that deep raises `AdmissionShed`
         (nothing enqueued) carrying a `retry_after_s` hint."""
@@ -2778,7 +2870,8 @@ class PagedGenerationServer:
                    t_submit=time.perf_counter(),
                    rid=(str(rid) if rid is not None
                         else f"p{next(_req_ids)}"), sampling=sampling,
-                   meta=meta, on_token=on_token, timeout_s=timeout_s,
+                   meta=meta, on_token=on_token, on_routing=on_routing,
+                   timeout_s=timeout_s,
                    trace=(trace_ctx if trace_ctx is not None
                           else TraceContext.mint()))
         # per-request PRNG stream seed: explicit seeds reproduce tokens
@@ -2901,6 +2994,9 @@ class PagedGenerationServer:
             self._spec_rounds_per_slot = 0
             self._decoded_tokens = 0
             self._replayed_tokens = 0
+            self._expert_sums = [0, 0, 0, 0, 0]
+            self._expert_ring.clear()
+            self.cache.reset_state_peak()
             self._rounds = 0
             self._round_dispatch_count = 0
             self._mixed_rounds = 0
@@ -3080,6 +3176,12 @@ class PagedGenerationServer:
                 # costs per dispatch and where a round that stood
                 # still spent it; reset-coherent
                 "round_phases": self._phases.snapshot(),
+                # the expert layers' counters (zeros for a model with
+                # none), summed over the layers of every dispatch that
+                # routed a token; `dispatches` is the newest EXPERT_RING
+                # of them: [perf_counter, tokens, held_picks,
+                # experts_touched, max_load, expert layers] each
+                "experts": self._expert_stats_locked(),
                 # reliability (r17): fault injection + recovery ladder
                 # + timeout/shed window counters — schema-stable
                 # (zeros when nothing ever failed), reset-coherent
@@ -3143,6 +3245,10 @@ class PagedGenerationServer:
                 "wall_s": dt,
             }
             out["kv_cache"] = self.cache.stats()
+            # the recurrent-state store beside the pool (zeros without)
+            out["state"] = {
+                k: out["kv_cache"]["state"][k]
+                for k in ("slots", "peak_used_slots")}
         # per-tenant cost attribution (ISSUE 17): evaluated OUTSIDE
         # the engine lock (the ledger has its own) — zeroed congruent
         # schema when attribution is off, reset-coherent
@@ -3266,6 +3372,88 @@ class PagedGenerationServer:
                 held = self.cache.blocks_held(slot["seq"])  # prefill runs
                 total += max(0, self._worst[slot["seq"]] - held)
         return total
+
+    def _expert_stats_locked(self):
+        tokens, picks, touched, max_load, layers = self._expert_sums
+        held = self._desc.held if self._desc is not None else 0
+        return {
+            "dispatches": [list(e) for e in self._expert_ring],
+            "tokens": tokens, "held_picks": picks,
+            "experts_touched": touched, "max_load": max_load,
+            # picks a held expert of one layer got, on average
+            "mean_load": picks / (layers * held) if layers and held
+            else 0.0,
+        }
+
+    def _swap_cache(self, kc, second):
+        """Install what a program returned in the cache's place: GPT-2's
+        programs return (kc, vc), a description's (kc, state)."""
+        if self._desc is None:
+            self.cache.swap_arrays(kc, second)
+        else:
+            self.cache.swap_arrays(kc, None, second)
+
+    @staticmethod
+    def _read_routed(rest):
+        """What a description's program returned after GPT-2's five
+        (`routed`, see `nn.decode_blocks`), its counters read back; None
+        for GPT-2's programs and for a description without experts."""
+        if not rest or rest[0] is None:
+            return None
+        return dict(rest[0], counts=np.asarray(rest[0]["counts"]))
+
+    def _plan_rows(self, n):
+        """Rows of a packed-prefill plan's tables for `n` chunks: the
+        power-of-two bucket, or every slot when the plan has one shape."""
+        if self._one_plan_shape:
+            n = self.max_slots
+        rows = 1
+        while rows < n:
+            rows *= 2
+        return rows
+
+    def _state_slot_free(self):
+        """Whether one more sequence can take a slot of the recurrent-
+        state store (always, for a cache without one): the free slots
+        less those that admitted requests will take with their first
+        block."""
+        if not self.cache.state_slots:
+            return True
+        waiting = sum(1 for s in self._slots if s is not None
+                      and not self.cache.has_seq(s["seq"]))
+        return self.cache.free_state_slots - waiting >= 1
+
+    def _note_routed(self, counts):
+        """Add one dispatch's expert counters (`routed["counts"]` read
+        back: [expert layers, 4]) to the sums and the ring."""
+        entry = (time.perf_counter(), int(counts[:, 0].sum()),
+                 int(counts[:, 1].sum()), int(counts[:, 2].sum()),
+                 int(counts[:, 3].max()), int(counts.shape[0]))
+        if not entry[1]:
+            return
+        with self._lock:
+            sums = self._expert_sums
+            for j in (0, 1, 2, 4):
+                sums[j] += entry[j + 1]
+            sums[3] = max(sums[3], entry[4])
+            self._expert_ring.append(entry)
+
+    def _tell_routing(self, rows, routed):
+        """Hand every request that asked (`submit(on_routing=)`) what its
+        rows' routers chose in this dispatch: `rows` is [(slot index,
+        first position, first row of `picks`, rows)]."""
+        asked = [(self._slots[i], p0, r0, n) for i, p0, r0, n in rows
+                 if self._slots[i] is not None
+                 and self._slots[i]["req"].on_routing is not None]
+        if not asked:
+            return
+        picks = np.asarray(routed["picks"])    # [expert layers, rows, k]
+        for s, p0, r0, n in asked:
+            try:
+                s["req"].on_routing(int(p0), picks[:, r0:r0 + n],
+                                    self.cache.state_slot(s["seq"]))
+            except Exception:  # noqa: BLE001 — a callback never stops the loop
+                _logger.exception("on_routing callback raised")
 
     def _worst_blocks(self, req):
         """Worst-case block reservation for `req`: the overrun slack
@@ -3441,7 +3629,8 @@ class PagedGenerationServer:
             # available counts LRU-retained prefix blocks: alloc paths
             # reclaim them before raising, so they back reservations
             if self.cache.available_block_count \
-                    - self._outstanding_blocks() < worst:
+                    - self._outstanding_blocks() < worst \
+                    or not self._state_slot_free():
                 break  # head-of-line: keep arrival order under pressure
             self._queue.pop(0)
             seq = self._install_slot_locked(i, req, worst)
@@ -3536,7 +3725,10 @@ class PagedGenerationServer:
                     continue
                 plan.append((i, s["fed"], n, off))
                 off += -(-n // align) * align
-                budget -= n
+                # one plan shape: the budget is the packed length itself
+                # (regions as aligned), so no stream outgrows its bucket
+                budget -= -(-n // align) * align \
+                    if self._one_plan_shape else n
             if not plan:
                 return
             T = align  # power-of-two bucket: compile count is logarithmic
@@ -3546,9 +3738,7 @@ class PagedGenerationServer:
             # plan's slots (row count bucketed to a power of two), so a
             # one-request churn round pays for one row's cache, not
             # max_slots of them
-            P = 1
-            while P < len(plan):
-                P *= 2
+            P = self._plan_rows(len(plan))
             toks = np.zeros((T,), np.int32)
             seg = np.zeros((T,), np.int32)
             pos = np.full((T,), -1, np.int32)  # -1 marks packing pad
@@ -3617,6 +3807,8 @@ class PagedGenerationServer:
                     while mcap < need:
                         mcap *= 2
                     mcap = min(mcap, self._m_width)
+                    if self._one_plan_shape:
+                        mcap = self._m_width
                     tables = self.cache.table_array(
                         [self._slots[plan[r][0]]["seq"]
                          if r < len(plan) else None for r in range(P)],
@@ -3639,24 +3831,30 @@ class PagedGenerationServer:
                         [r in done_set for r in range(P)], base_steps)
                 with self._phase("dispatch"):
                     self._maybe_fault("prefill")
-                    tok, stopped, kc, vc, counts = \
+                    tok, stopped, kc, vc, counts, *routed = \
                         self._decoder.packed_prefill(
                             self._params, jnp.asarray(toks),
                             jnp.asarray(seg), jnp.asarray(pos),
                             jnp.asarray(tables),
                             jnp.asarray(sample_idx), self.cache.k_blocks,
-                            self.cache.v_blocks, sp_args, sp_mode)
+                            self.cache.v_blocks, sp_args, sp_mode,
+                            state=self.cache.state)
                     self._sp_store.swap_counts(counts)
                 with self._phase("read_back"):
                     tok_h = np.asarray(tok)
                     stopped_h = np.asarray(stopped)
+                    routed = self._read_routed(routed)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-the-chunk path)
             self._dispatch_failure("prefill", e,
                                    [i for i, *_ in plan])
             return
         with self._phase("emit"):
-            self.cache.swap_arrays(kc, vc)
+            self._swap_cache(kc, vc)
+            if routed is not None:
+                self._note_routed(routed["counts"])
+                self._tell_routing([(i, start, o, n)
+                                    for i, start, n, o in plan], routed)
             self._dispatch_ok([self._slots[i]["req"].rid
                                for i, *_ in plan
                                if self._slots[i] is not None])
@@ -4622,13 +4820,15 @@ class PagedGenerationServer:
                 with self._phase("dispatch"):
                     self._maybe_fault("decode")
                     tables = jnp.asarray(tables)
+                    routed = ()
                     if k == 1:
-                        toks, stopped, kc, vc, counts = \
+                        toks, stopped, kc, vc, counts, *routed = \
                             self._decoder.step(
                                 self._params, jnp.asarray(tok),
                                 jnp.asarray(pos), jnp.asarray(act),
                                 tables, self.cache.k_blocks,
-                                self.cache.v_blocks, sp_args, sp_mode)
+                                self.cache.v_blocks, sp_args, sp_mode,
+                                state=self.cache.state)
                     else:
                         toks, stopped, kc, vc, counts = \
                             self._decoder.multistep(k, sp_mode)(
@@ -4639,6 +4839,7 @@ class PagedGenerationServer:
                 with self._phase("read_back"):
                     toks = np.asarray(toks)        # [S], or [k, S]
                     stops = np.asarray(stopped)
+                    routed = self._read_routed(routed)
                     if k == 1:
                         toks, stops = toks[None], stops[None]  # [1, S]
         except Exception as e:  # noqa: BLE001 — the recovery ladder
@@ -4647,7 +4848,11 @@ class PagedGenerationServer:
             return
         with self._phase("emit"):
             self._sp_store.swap_counts(counts)
-            self.cache.swap_arrays(kc, vc)
+            self._swap_cache(kc, vc)
+            if routed is not None:
+                self._note_routed(routed["counts"])
+                self._tell_routing([(i, pos[i], i, 1)
+                                    for i in active_idx], routed)
             self._dispatch_ok([self._slots[i]["req"].rid
                                for i in active_idx
                                if self._slots[i] is not None])

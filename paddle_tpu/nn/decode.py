@@ -1,8 +1,13 @@
 """paddle.nn.decode module path (ref: nn/decode.py) + the paged decode
 engine.
 
-`PagedDecoder` is the jitted prefill/step pair that runs a GPT-2-layout
-transformer against the block-pool KV cache (inference/kv_cache.py):
+`PagedDecoder` is the jitted prefill/step pair that runs a decoder against
+the block-pool cache (inference/kv_cache.py).  It takes one of two
+layouts: GPT-2's six-field tuple, described below, which this file builds
+every program for; or a `nn/decode_blocks.DecoderDescription` (latent and
+recurrent mixers, routed experts), which gets `packed_prefill` and
+`decode_step` from that module and raises, by name, for the rest.  One
+trunk for both is ROADMAP D1.  For the GPT-2 layout:
 
   * prefill — one causal pass over a right-padded prompt batch, writing
     each row's K/V into its block-table blocks and sampling token 0 at
@@ -955,6 +960,33 @@ def _jitted_paged_fns(spec, block_size, return_logits, donate, mode,
             jax.jit(step_fn, donate_argnums=ds))
 
 
+@functools.lru_cache(maxsize=64)
+def _jitted_block_programs(desc, block_size, return_logits, donate, mode):
+    """(packed_prefill, decode_step) jitted for a `DecoderDescription`
+    (`decode_blocks.build_block_programs`): the pool `kc` and the store
+    `state` donated, as GPT-2's pools are."""
+    import jax
+
+    from .decode_blocks import build_block_programs
+
+    packed_fn, step_fn = build_block_programs(desc, block_size,
+                                              return_logits, mode)
+    return (jax.jit(packed_fn, donate_argnums=(6, 7) if donate else ()),
+            jax.jit(step_fn, donate_argnums=(5, 6) if donate else ()))
+
+
+def _not_built(program, option):
+    """A program slot of a description that has no such program yet."""
+    def refuse(*_a, **_k):
+        raise ValueError(
+            f"PagedDecoder.{program} is built for the GPT-2 layout only: "
+            f"a description with latent or recurrent layers serves "
+            f"through packed_prefill and decode_step ({option} has no "
+            f"meaning beside it yet)")
+
+    return refuse
+
+
 @functools.lru_cache(maxsize=32)
 def _sharded_jits(spec, block_size, return_logits, donate, mode,
                   kv_quant, sh, cq=None, sp_attention="allgather"):
@@ -1147,7 +1179,25 @@ class PagedDecoder:
             raise ValueError(
                 "collective_quant requires shardings: quantized "
                 "collectives only exist on a sharded mesh")
-        self.spec = tuple(spec)
+        # GPT-2's six-field tuple, or a `decode_blocks.DecoderDescription`,
+        # which has packed_prefill and decode_step, unsharded and over
+        # dense caches, and says so here
+        from .decode_blocks import DecoderDescription
+
+        self.description = spec if isinstance(spec, DecoderDescription) \
+            else None
+        if self.description is not None:
+            for name, value, ok in (
+                    ("kv_dtype", kv_dtype, None),
+                    ("shardings", shardings, None),
+                    ("collective_quant", collective_quant, None),
+                    ("sp_attention", sp_attention, "allgather")):
+                if value != ok:
+                    raise ValueError(
+                        f"PagedDecoder({name}={value!r}) has no meaning "
+                        f"yet beside latent or recurrent layers: their "
+                        f"caches are dense and their programs unsharded")
+        self.spec = self.description or tuple(spec)
         self.block_size = int(block_size)
         self.return_logits = bool(return_logits)
         self.kv_dtype = kv_dtype
@@ -1189,6 +1239,12 @@ class PagedDecoder:
         """Eager dtype-consistency assert (CI/tooling satellite): the
         cache arrays must match the decoder's kv_dtype BEFORE any jit
         tracing, so a miswired server fails with the argument named."""
+        if self.description is not None:
+            if vc is not None:
+                raise ValueError(
+                    "a latent pool has no V: PagedDecoder takes vc=None "
+                    "and the store as state= for this description")
+            return   # a latent pool and a state store: dense by construction
         for name, arr in (("kc", kc), ("vc", vc)):
             got = hasattr(arr, "codes")
             if got != self._kv_quant:
@@ -1227,7 +1283,15 @@ class PagedDecoder:
             from ..observability import compile_tracker as _ct
             from ..observability import tracing as _tracing
 
-            if self._shardings is not None:
+            if self.description is not None:
+                packed, step = _jitted_block_programs(
+                    self.description, self.block_size, self.return_logits,
+                    self._donate, mode)
+                prefill = _not_built("prefill", "the padded-batch prefill")
+                verify = _not_built("packed_verify", "speculation")
+                unified = uniwin = _not_built("unified_round",
+                                              "unified_round")
+            elif self._shardings is not None:
                 (prefill, step, packed, verify, unified,
                  uniwin) = _sharded_jits(
                     self.spec, self.block_size, self.return_logits,
@@ -1346,16 +1410,24 @@ class PagedDecoder:
                                       sp)
 
     def step(self, params, tok, pos, active, tables, kc, vc, sp,
-             mode=GREEDY_MODE):
+             mode=GREEDY_MODE, state=None):
+        """One decode token a row.  GPT-2: (token, stopped, kc, vc,
+        counts[, logits]).  A `DecoderDescription` takes vc=None and its
+        cache's store as `state`, and returns (token, stopped, kc, state,
+        counts, routed[, logits]) (`decode_blocks`)."""
         self._check_kv(kc, vc)
-        return self._variant(mode)[1](params, tok, pos, active, tables,
-                                      kc, vc, sp)
+        return self._variant(mode)[1](
+            params, tok, pos, active, tables, kc,
+            vc if self.description is None else state, sp)
 
     def packed_prefill(self, params, toks, seg, pos, tables, sample_idx,
-                       kc, vc, sp, mode=GREEDY_MODE):
+                       kc, vc, sp, mode=GREEDY_MODE, state=None):
+        """A packed stream of prompt chunks; `state` and the returns as
+        `step`'s."""
         self._check_kv(kc, vc)
-        return self._variant(mode)[2](params, toks, seg, pos, tables,
-                                      sample_idx, kc, vc, sp)
+        return self._variant(mode)[2](
+            params, toks, seg, pos, tables, sample_idx, kc,
+            vc if self.description is None else state, sp)
 
     def packed_verify(self, params, toks, seg, pos, tables, sample_idx,
                       dlen, kc, vc, sp, mode=GREEDY_MODE):
@@ -1386,11 +1458,11 @@ class PagedDecoder:
 
     def multistep(self, n_steps, mode=GREEDY_MODE):
         """Fused n-token decode (see _build_multistep)."""
-        import jax
-
         from ..observability import compile_tracker as _ct
         from ..observability import tracing as _tracing
 
+        if self.description is not None:
+            return _not_built("multistep", "steps_per_dispatch > 1")
         if self._shardings is not None:
             key = (int(n_steps), mode)
             fn = self._msteps.get(key)
@@ -1428,3 +1500,11 @@ class PagedDecoder:
                 cfg.hidden_size // cfg.num_heads, cfg.hidden_size,
                 cfg.layer_norm_epsilon, cfg.tie_embeddings)
         return cls(spec, block_size, **kw)
+
+    @classmethod
+    def for_model(cls, model, block_size, **kw):
+        """Build from a model: its own `decoder_description()` where it
+        has one, else its GPT2Config-like `cfg`."""
+        if hasattr(model, "decoder_description"):
+            return cls(model.decoder_description(), block_size, **kw)
+        return cls.for_config(model.cfg, block_size, **kw)

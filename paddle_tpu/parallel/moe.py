@@ -105,3 +105,96 @@ def moe_shardings(mesh, params, ep_axis="ep"):
     spec = {"gate": P(), "w1": P(ep_axis), "b1": P(ep_axis),
             "w2": P(ep_axis), "b2": P(ep_axis)}
     return {k: NamedSharding(mesh, spec[k]) for k in params}
+
+
+# ---- dropless routed experts, for a layer that is told which it holds ----
+
+def _row_tile(n_tokens, top_k, held):
+    """Rows a tile of the grouped matmul: a power of two near the picks an
+    expert held here gets, between 16 (one packed bf16 sublane group: a
+    decode batch) and 128 (a prefill chunk), so that an expert's weights
+    are streamed for one tile or two."""
+    tm = 16
+    while tm < 128 and tm * held < n_tokens * top_k:
+        tm *= 2
+    return tm
+
+
+def routed_expert_ffn(x, valid, router_w, router_b, gate, up, down, *,
+                      held_first, top_k, scaling, renormalize=True):
+    """The routed half of an expert FFN on one chip of an expert-parallel
+    layer: route over ALL experts, compute the experts held here.
+
+    x [N, D] tokens, valid [N] bool (padding and idle rows route nowhere);
+    router_w [D, n_experts], router_b [n_experts] (added to the scores for
+    the choice alone); gate, up [held, D, F], down [held, F, D] the SwiGLU
+    weights of experts held_first .. held_first + held - 1.
+    s = sigmoid(x W_r); the top_k of s + router_b are chosen; a chosen
+    expert weighs s_i (over the chosen's sum when `renormalize`) times
+    `scaling`.  Returns (y [N, D], counts int32 [4], picks int32 [N, k]):
+    y is the weighted sum over the chosen experts HELD HERE (what the
+    others would add is their chips'), counts = (tokens routed, picks held
+    here, held experts with a pick, the most picks on one expert), picks
+    the experts each token chose (for a check that compares the choice).
+
+    Dropless: the picks held are sorted by expert into tiles of rows,
+    each tile one expert's (an expert's rows padded up to a tile), and one
+    grouped matmul takes them all (`moe_gmm` on the TPU; elsewhere the
+    same tiles against their experts' weights, gathered)."""
+    n, d = x.shape
+    held = gate.shape[0]
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.dot(x, router_w, preferred_element_type=f32))
+    _, idx = jax.lax.top_k(s + router_b.astype(f32), top_k)       # [N, k]
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    w = w * scaling
+    local = idx - held_first
+    mine = valid[:, None] & (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held).reshape(-1)              # [N*k]
+    picks = n * top_k
+    tm = _row_tile(n, top_k, held)
+    rows = -(-(picks + held * (tm - 1)) // tm) * tm               # static
+
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)
+    start = jnp.cumsum(sizes) - sizes         # of each group, among the sorted
+    padded = -(-sizes[:held] // tm) * tm
+    ends = jnp.cumsum(padded)                 # of each group's tiles, in rows
+    g_sorted = group[order]
+    rank = jnp.arange(picks) - start[g_sorted]
+    dest_sorted = jnp.where(
+        g_sorted < held,
+        (ends - padded)[jnp.minimum(g_sorted, held - 1)] + rank, rows)
+    row_token = jnp.zeros((rows,), jnp.int32).at[dest_sorted].set(
+        (order // top_k).astype(jnp.int32), mode="drop")
+    n_valid = ends[-1] // tm
+    tiles = jnp.arange(rows // tm)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, tiles * tm, side="right"), held - 1)
+    tile_expert = jnp.where(tiles < n_valid, tile_expert,
+                            tile_expert[jnp.maximum(n_valid - 1, 0)])
+    x_rows = x[row_token]                     # pad rows: token 0, never read
+    from ..ops import attention as _attention
+
+    if _attention._on_tpu():
+        from ..ops.pallas.moe_gmm import moe_gmm_kernel
+
+        y_rows = moe_gmm_kernel(x_rows, gate, up, down, tile_expert,
+                                n_valid[None], tm=tm)
+    else:
+        xt = x_rows.reshape(rows // tm, tm, d)
+        hid = jax.nn.silu(jnp.einsum("tmd,tdf->tmf", xt, gate[tile_expert])) \
+            * jnp.einsum("tmd,tdf->tmf", xt, up[tile_expert])
+        y_rows = jnp.einsum("tmf,tfd->tmd", hid,
+                            down[tile_expert]).reshape(rows, d)
+    dest = jnp.zeros((picks,), jnp.int32).at[order].set(
+        jnp.minimum(dest_sorted, rows - 1).astype(jnp.int32))
+    y_pick = y_rows[dest].reshape(n, top_k, d).astype(f32)
+    y = jnp.sum(jnp.where(mine[..., None], w[..., None] * y_pick, 0.0),
+                axis=1)
+    counts = jnp.stack([valid.sum(), mine.sum(),
+                        (sizes[:held] > 0).sum(),
+                        sizes[:held].max()]).astype(jnp.int32)
+    return y.astype(x.dtype), counts, idx.astype(jnp.int32)

@@ -298,6 +298,104 @@ def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
+def _sds(one_chip):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+
+def test_kda_decode_kernel_compiles(one_chip):
+    """The recurrence on [8 heads, 128, 128] float32 tiles of the store,
+    aliased in and out: the transposes and sublane reductions lower."""
+    from paddle_tpu.ops.pallas.kda_decode import kda_decode_kernel
+
+    s = _sds(one_chip)
+    row = s((16, 32, 128), jnp.float32)
+    assert _kernels(
+        lambda st, sl, q, k, v, a, b: kda_decode_kernel(st, 1, sl, q, k, v,
+                                                        a, b),
+        s((2, 9, 32, 128, 128), jnp.float32), s((16,), jnp.int32), row, row,
+        row, row, s((16, 32), jnp.float32)) == 1
+
+
+def test_mla_decode_kernel_compiles(one_chip):
+    """32 heads against [128, 640] blocks of latents (576 used), four table
+    entries a grid step."""
+    from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
+
+    s = _sds(one_chip)
+    assert _kernels(
+        lambda q, pool, tb, cx: mla_decode_kernel(q, pool, 0, tb, cx,
+                                                  scale=192 ** -0.5,
+                                                  lora=512),
+        s((16, 32, 640), jnp.bfloat16), s((1, 64, 128, 640), jnp.bfloat16),
+        s((16, 76), jnp.int32), s((16,), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("tm,rows", [(16, 368), (128, 3072)],
+                         ids=["decode", "prefill"])
+def test_moe_gmm_kernel_compiles(one_chip, tm, rows):
+    """The grouped SwiGLU at the published expert width (2304 x 1024), at
+    the decode tile and at the prefill tile (whose slabs need more VMEM
+    than the default scoped limit)."""
+    from paddle_tpu.ops.pallas.moe_gmm import moe_gmm_kernel
+
+    s = _sds(one_chip)
+    w = s((8, 2304, 1024), jnp.bfloat16)
+    assert _kernels(
+        lambda x, g, u, d, te, nv: moe_gmm_kernel(x, g, u, d, te, nv, tm=tm),
+        s((rows, 2304), jnp.bfloat16), w, w, s((8, 1024, 2304), jnp.bfloat16),
+        s((rows // tm,), jnp.int32), s((1,), jnp.int32)) == 1
+
+
+def test_stateful_decode_step_works_on_both_caches_in_place(one_chip,
+                                                            monkeypatch):
+    """Kimi-Linear's published widths at 5 layers and 8 held experts: the
+    decode step holds its kernels by name, the latent pool and the state
+    store are aliased to the outputs, and its temporaries are smaller than
+    one KDA layer's state (PR 25's lesson, for two caches: a 576-wide
+    latent row was re-laid around every kernel until it was padded to the
+    lanes)."""
+    import re
+
+    from paddle_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
+    from paddle_tpu.nn.decode_blocks import build_block_programs
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    s = _sds(one_chip)
+    model = KimiLinear(KimiLinearConfig(vocab_size=8192, held_layers=5,
+                                        held_experts=(0, 8)),
+                       dtype="bfloat16")
+    desc = model.decoder_description()
+    params = {k: s(v.shape, v.dtype)
+              for k, v in model.functional_state()[0].items()}
+    lay = desc.cache_layout()
+    slots, rows, blocks, width = 16, 16, 64, 8
+    pool = s((lay["pool_layers"], blocks, BS, lay["row_width"]), jnp.bfloat16)
+    store = {
+        "S": s((lay["state_layers"], slots + 1) + lay["state_shape"],
+               jnp.float32),
+        "conv": s((lay["state_layers"], slots + 1) + lay["conv_shape"],
+                  jnp.bfloat16)}
+    _packed, step = build_block_programs(desc, BS, False, (False, False))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(5, 6)).lower(
+            params, s((rows,), jnp.int32), s((rows,), jnp.int32),
+            s((rows,), jnp.bool_), s((rows, 1 + width), jnp.int32), pool,
+            store, {"stop": s((rows, 1), jnp.int32)}).compile()
+    text = compiled.as_text()
+    names = re.findall(r"%(kda_decode|mla_decode|moe_gmm)[.\d]* = ", text)
+    assert {n: names.count(n) for n in set(names)} == {
+        "kda_decode": 4, "mla_decode": 1, "moe_gmm": 4}
+    assert text.count("tpu_custom_call") == 9
+    # the pool, the state and the conv tails: in place (what the routers
+    # did is a result of its own, written anew)
+    assert len(re.findall(r"(?:may|must)-alias",
+                          text.split("\n", 1)[0])) == 3
+    one_layer_state = (slots + 1) * 32 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_state
+
+
 def test_flash_under_dp_mp_mesh_compiles(mesh4, monkeypatch):
     """Training under Fleet dp x mp: `scaled_dot_product_attention` traced
     with a current mesh runs the flash kernels per device (batch over dp,
