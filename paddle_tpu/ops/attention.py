@@ -229,13 +229,15 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
     mesh: the device mesh of a sharded engine whose pool is split on
         heads over `mp` (see `_paged_kernel`); None on one device.
 
-    Returns [B, H, Dh] in q's dtype. Dispatches to the Pallas ragged
+    Returns [B, H, Dh] in q's dtype. Dispatches to the Pallas decode
     kernel on TPU when shapes allow (`paged_attention_path`: head_dim
     lane-sized, block_size a lane multiple, per-device heads
     sublane-aligned); otherwise runs the XLA gather path, which
     materializes the [B, M*BS] gathered keys — correct everywhere, but
-    it reads the padded table width instead of streaming exactly the
-    live blocks."""
+    it reads the padded table width, where the kernel fetches a row's
+    live blocks and, once a row whose table has padding, the pad block
+    (its grid still steps over the whole width: the steps past a
+    row's context compute nothing and fetch nothing more)."""
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     B, H, Dh = q.shape

@@ -12,13 +12,21 @@ It holds:
     position) and a speculative verify region ([last_token,
     draft_1..k]) are all just ragged segments of the same stream, so a
     scheduler round mixing all three is ONE launch of this kernel;
-  * the DECODE entry (`paged_decode_attention_kernel`) — the
+  * the DECODE kernel (`paged_decode_attention_kernel`) — the
     one-token-per-sequence call of the standalone `step`/offline
-    paths: the same kernel body at a DECODE_TILE-row query tile.  (A
-    separate (B, M)-grid decode body passed interpret mode for twenty
-    PRs and was refused by the chip's compiler — Mosaic lowers no
-    head-batched dot whose left operand is a bare [H, Dh] — so it is
-    gone; PR 21.)
+    paths, with a body of its own (PR 27).  A decode row is one query
+    a head against a block all heads share, so the row's H heads are
+    the H rows of ONE block-diagonal query tile [H, H*Dh] (row h: head
+    h's query in head h's lanes, zeros elsewhere), and a grid step
+    (row, table column) is one score dot over the whole [BS, H*Dh]
+    block as it lies, one online-softmax update on the [H, BS] tile
+    (every row has the same horizon, ctx_len) and one value dot; the
+    block diagonal of the accumulator is the output row.  (Until PR
+    27 it was the stream kernel at an 8-row tile once a head: 32
+    eight-row dots and 16 softmax chains a step, 350 us a launch in
+    the serve cell where this body takes 136: PERF.md section 6.  A
+    (B, M)-grid body whose dots were batched over heads with a bare
+    [H, Dh] left operand never lowered and went in PR 21.)
 
 Shared machinery:
 
@@ -30,12 +38,14 @@ Shared machinery:
     [.., M*BS, ...] gather copy the XLA fallback builds nor a slice of
     one layer.  Scale tiles ride the SAME prefetched index as their
     codes.
-  * `_load_heads` — the heads of one lane tile of the block in
-    flight, each [BS, Dh], with the int8-KV dequant (quantized-serving
-    round): pools may be `QuantizedKV` (codes [L, N, BS, H*Dh] int8 +
-    per-vector scales [L, N, BS, H]); dequantization happens HERE on
-    the VMEM-resident block, so a bf16 copy of the cache never exists
-    in HBM.
+  * the int8-KV dequant (quantized-serving round): pools may be
+    `QuantizedKV` (codes [L, N, BS, H*Dh] int8 + per-vector scales
+    [L, N, BS, H]); dequantization happens on the VMEM-resident
+    block, so a bf16 copy of the cache never exists in HBM.  The
+    stream kernel scales each head's [BS, Dh] slice (`_load_heads`);
+    the decode kernel puts the codes into its dots and folds the
+    scales into the [H, BS] score and weight tiles, as the XLA path
+    folds them (`_scales_by_head`).
 
 Layout (matches inference/kv_cache.py):
     q:        [T, H, Dh] stream / [B, H, Dh] decode
@@ -77,11 +87,11 @@ program that handed this kernel a [BS, H, Dh] block: PERF.md section
 6, PR 25).  A block is one contiguous [BS, H*Dh] tile; head h is its
 lanes [h*Dh, (h+1)*Dh).
 
-Per (tile, kv-block) step and head the score tile is [QT, BS] from a
-dot over Dh, in an unrolled loop over the block's 128-lane tiles (two
-heads of 64 each); online-softmax state (m, l, acc) rides VMEM scratch
-across the M dimension exactly like flash_attention.py, one row of it
-per head.
+Stream kernel: per (tile, kv-block) step and head the score tile is
+[QT, BS] from a dot over Dh, in an unrolled loop over the block's
+128-lane tiles (two heads of 64 each); online-softmax state (m, l, acc)
+rides VMEM scratch across the M dimension exactly like
+flash_attention.py, one row of it per head.
 """
 from __future__ import annotations
 
@@ -96,8 +106,6 @@ from . import named_pallas_call
 
 NEG_INF = -1e30
 Q_TILE = 128     # stream query-tile (and packing alignment) size
-DECODE_TILE = 8  # query tile of a one-token decode row: one f32 sublane
-                 # group, the smallest tile Mosaic lowers the dots for
 
 
 def supported_shapes(head_dim, block_size, num_heads, total_tokens=None):
@@ -319,11 +327,11 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
             pltpu.VMEM((H, qt, 128), jnp.float32),
         ],
     )
-    # decode rows ride DECODE_TILE tiles, every other caller (chunk
-    # prefill, verify, the unified round's mixed stream) the wide ones:
-    # two names, so a device trace tells the two loads apart
+    # chunk prefill, verify and the unified round's mixed stream; the
+    # decode kernel has its own name, so a device trace tells the two
+    # loads apart
     out = named_pallas_call(
-        "paged_attn_decode" if qt == DECODE_TILE else "paged_attn_prefill",
+        "paged_attn_prefill",
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, T, Dh), q.dtype),
@@ -335,23 +343,133 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
 
 # ---- decode (one token per sequence) --------------------------------
 
+def _own_lanes(nh, e, dh):
+    """[H, H*Dh] mask: row h owns head h's lanes [h*Dh, (h+1)*Dh)."""
+    first = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 0) * dh
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 1)
+    return (lane >= first) & (lane < first + dh)
+
+
+def _scales_by_head(sref, nh):
+    """An int8 block's scale tile [BS, H] as float32 [H, BS], the shape of
+    the decode body's score tile: the product with an [H, H] identity,
+    which is exact (one non-zero term a sum) and is the transposed form
+    of dot Mosaic lowers for any width, where a [BS, H] tile narrower
+    than the lanes has no transpose."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (nh, nh), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (nh, nh), 1))
+    return jax.lax.dot_general(
+        eye.astype(sref.dtype), sref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *refs, scale, nm,
+                   dh, quant):
+    del layer_ref, tables_ref
+    if quant:
+        (k_ref, ks_ref, v_ref, vs_ref, o_ref, qbd_ref, acc_ref, m_ref,
+         l_ref) = refs
+    else:
+        k_ref, v_ref, o_ref, qbd_ref, acc_ref, m_ref, l_ref = refs
+    bi = pl.program_id(0)
+    mi = pl.program_id(1)
+    nh, e = qbd_ref.shape
+    bs = k_ref.shape[0]
+    ctx = ctx_ref[bi]
+
+    @pl.when(mi == 0)
+    def _init():
+        # the row's heads as ONE block-diagonal query tile: row h is head
+        # h's query in head h's lanes and zero elsewhere, so a dot over
+        # all H*Dh lanes of a pool block is every head's score at once
+        # (the zeros add exactly 0); selected in float32, the width of
+        # the mask: the VPU has no bf16 select to lose
+        q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), (nh, e))
+        qbd_ref[:] = jnp.where(_own_lanes(nh, e, dh), q,
+                               0.0).astype(qbd_ref.dtype)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(mi * bs < ctx)
+    def _compute():
+        dt = qbd_ref.dtype
+        # s[h, j] = sum_e qbd[h, e] * k[j, e]: the block as it lies
+        s = jax.lax.dot_general(
+            qbd_ref[...], k_ref[...].astype(dt), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [H, BS]
+        if quant:  # codes went into the dot; the scales fold in here
+            s = s * _scales_by_head(ks_ref, nh)
+        col = mi * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col < ctx, s, NEG_INF)  # one horizon for every row
+        m_prev = m_ref[:, 0:1]                                 # [H, 1]
+        l_prev = l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * _scales_by_head(vs_ref, nh)
+        # pv[h, e] = sum_j p[h, j] * v[j, e]: head h's lanes of row h are
+        # its output, the other lanes are other heads' values under head
+        # h's weights and are dropped at the flush
+        pv = jax.lax.dot_general(
+            p.astype(dt), v_ref[...].astype(dt), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                # [H, H*Dh]
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(mi == nm - 1)
+    def _flush():
+        l = jnp.maximum(l_ref[:, 0:1], 1e-30)  # ctx 0 flushes zeros
+        o = jnp.where(_own_lanes(nh, e, dh), acc_ref[:] / l, 0.0)
+        o_ref[:] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
                                   layer=None, *, scale=None,
                                   interpret=False):
     """Ragged paged decode attention: q [B, H, Dh], one token per
     sequence attending cache positions [0, ctx_len) of the pool stack's
-    `layer` (or of one layer's pool, layer=None).  A decode row is a
-    one-token segment of the stream, so this IS the stream kernel at a
-    DECODE_TILE-row query tile: row b sits at stream row b*DECODE_TILE
-    with pos = ctx_len - 1 and the tile's other rows are zero padding
-    whose output is dropped (ctx_len == 0 makes a pad tile, which
-    flushes zeros).  Returns [B, H, Dh] in q's dtype."""
-    B = q.shape[0]
-    stream = jnp.pad(q[:, None], ((0, 0), (0, DECODE_TILE - 1),
-                                  (0, 0), (0, 0)))
-    out = unified_ragged_attention_kernel(
-        stream.reshape((B * DECODE_TILE,) + q.shape[1:]), k_blocks,
-        v_blocks, tables, jnp.arange(B, dtype=jnp.int32),
-        ctx_lens.astype(jnp.int32) - 1, layer, scale=scale,
-        q_tile=DECODE_TILE, interpret=interpret)
-    return out[::DECODE_TILE]
+    `layer` (or of one layer's pool, layer=None; either may be
+    `QuantizedKV`).  Grid (row, table column); a step scores ALL heads
+    of the row against the [BS, H*Dh] block with one dot, updates one
+    online softmax on the [H, BS] tile and sums with one dot
+    (`_decode_kernel`).  q goes in and the output comes back as the
+    lane-dense [1, H*Dh] row they are in memory.  ctx_len == 0 (a pad
+    row) returns zeros.  Returns [B, H, Dh] in q's dtype."""
+    quant, operands = kv_operands(k_blocks, v_blocks, layer)
+    layer = jnp.reshape(jnp.asarray(0 if layer is None else layer,
+                                    jnp.int32), (1,))
+    B, H, Dh = q.shape
+    E = H * Dh
+    BS = operands[0].shape[2]
+    M = tables.shape[1]
+    scale = (Dh ** -0.5) if scale is None else float(scale)
+    row = pl.BlockSpec((None, 1, E), lambda b, m, ly, tb, cx: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer and tables steer the DMA
+        grid=(B, M),
+        in_specs=[row] + kv_operand_specs(
+            BS, H, Dh, quant, lambda b, m, ly, tb, cx: (ly[0], tb[b, m])),
+        out_specs=row,
+        scratch_shapes=[
+            pltpu.VMEM((H, E), q.dtype),       # the block-diagonal query
+            pltpu.VMEM((H, E), jnp.float32),   # acc: row h, head h's lanes
+            # m, l: one value a head, kept across a lane tile
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+        ],
+    )
+    out = named_pallas_call(
+        "paged_attn_decode",
+        functools.partial(_decode_kernel, scale=scale, nm=M, dh=Dh,
+                          quant=quant),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, E), q.dtype),
+        interpret=interpret,
+    )(layer, tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      q.reshape(B, 1, E), *operands)
+    return out.reshape(B, H, Dh)

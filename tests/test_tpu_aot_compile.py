@@ -69,7 +69,8 @@ def _kernels(fn, *args):
 LAYERS = 2  # depth of the pool stacks and programs compiled here
 
 
-def _pool(sharding, quant, spec=None):
+def _pool(sharding, quant, spec=None, heads=H, layers=LAYERS,
+          blocks=N_BLOCKS):
     """The K (or V) pool stack as shapes, [L, N, BS, H*Dh] as the engine
     holds it: dense bf16, or int8 codes with per-vector scales [L, N, BS, H]
     in the compute dtype (what a bf16 engine holds)."""
@@ -77,12 +78,12 @@ def _pool(sharding, quant, spec=None):
         sh = sharding if spec is None else NamedSharding(sharding, spec)
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
-    rows = (LAYERS, N_BLOCKS, BS)
+    rows = (layers, blocks, BS)
     if not quant:
-        return s(rows + (H * DH,), jnp.bfloat16)
+        return s(rows + (heads * DH,), jnp.bfloat16)
     from paddle_tpu.inference.kv_quant import QuantizedKV
-    return QuantizedKV(s(rows + (H * DH,), jnp.int8),
-                       s(rows + (H,), jnp.bfloat16))
+    return QuantizedKV(s(rows + (heads * DH,), jnp.int8),
+                       s(rows + (heads,), jnp.bfloat16))
 
 
 def _flash_args(one_chip, b, h, s, d):
@@ -137,29 +138,45 @@ def test_stream_kernel_compiles(one_chip, quant):
                     i32()) == 1
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
-def test_decode_path_compiles(one_chip, quant):
-    """The default engine loop's decode step: one token per sequence, the
-    stream kernel at DECODE_TILE rows (the path as repaired in PR 21)."""
+def _decode_kernels(one_chip, quant, heads):
     from paddle_tpu.ops.pallas.unified_attention import (
         paged_decode_attention_kernel)
 
     B = 32
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    q = jax.ShapeDtypeStruct((B, H, DH), jnp.bfloat16, sharding=one_chip)
-    assert _kernels(paged_decode_attention_kernel, q,
-                    _pool(one_chip, quant), _pool(one_chip, quant),
-                    i32(B, M), i32(B), i32()) == 1
+    q = jax.ShapeDtypeStruct((B, heads, DH), jnp.bfloat16,
+                             sharding=one_chip)
+    pool = _pool(one_chip, quant, heads=heads)
+    return _kernels(paged_decode_attention_kernel, q, pool, pool,
+                    i32(B, M), i32(B), i32())
 
 
-def _kernel_op_names(fn, *args):
-    """The HLO instruction names of the program's kernels, as the chip's
-    compiler gives them — what a device trace shows on `XLA Ops`."""
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(fn).lower(*args).compile().as_text()
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+def test_decode_path_compiles(one_chip, quant):
+    """The default engine loop's decode step: one token per sequence, all
+    16 heads of a [128, 1024] block in one [16, 1024] query tile (PR 27;
+    the (B, M)-grid body PR 21 deleted had a bare [H, Dh] left operand)."""
+    assert _decode_kernels(one_chip, quant, H) == 1
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+def test_decode_path_compiles_at_eight_heads_a_device(one_chip, quant):
+    """What `tp=2` hands each device under shard_map: an [8, 512] tile,
+    half a packed bf16 sublane group."""
+    assert _decode_kernels(one_chip, quant, H // 2) == 1
+
+
+def _kernel_names_in(text):
+    """The HLO instruction names of a compiled program's kernels, as the
+    chip's compiler gives them — what a device trace shows on `XLA Ops`."""
     return sorted(ln.split(" = ", 1)[0].split("%")[-1].rsplit(".", 1)[0]
                   for ln in text.splitlines()
                   if "tpu_custom_call" in ln and " = " in ln)
+
+
+def _kernel_op_names(fn, *args):
+    with jax.default_matmul_precision("default"):
+        return _kernel_names_in(jax.jit(fn).lower(*args).compile().as_text())
 
 
 def test_kernels_are_named_in_the_compiled_program(one_chip):
@@ -243,28 +260,19 @@ def _gpt2_medium_params(sharding, layers):
     return p
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
-@pytest.mark.parametrize("program", ["step", "packed_prefill"])
-def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
-                                                    program, quant):
-    """GPT-2-medium's width at depth 2, through the Pallas path, as the
-    chip's compiler schedules it: the donated pools are aliased to the
-    outputs, one kernel a layer reads the stack, and nothing the program
-    produces but the K/V scatters is as large as one layer's pool — no
-    slice of a layer, no copy, no re-laid copy of the stack (PR 25: the
-    parent's programs held three pools' worth of those)."""
-    import re
-
-    from test_pool_in_place import pool_sized_instructions
-
+def _compile_serving_program(one_chip, monkeypatch, program, quant,
+                             layers=LAYERS, blocks=N_BLOCKS):
+    """GPT-2-medium's `decode_step` (32 rows) or `packed_prefill` (512
+    tokens of 4 rows) through the Pallas path, its pools donated, as the
+    chip's compiler schedules it."""
     from paddle_tpu.nn import decode
     from paddle_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    spec = (LAYERS, H, DH, H * DH, 1e-5, True)
+    spec = (layers, H, DH, H * DH, 1e-5, True)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    pool = _pool(one_chip, quant)
-    params = _gpt2_medium_params(one_chip, LAYERS)
+    pool = _pool(one_chip, quant, layers=layers, blocks=blocks)
+    params = _gpt2_medium_params(one_chip, layers)
     if program == "step":
         _, fn = decode._build_paged_fns(spec, BS, False, (False, False),
                                         quant)
@@ -279,7 +287,25 @@ def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
                 pool, pool, {"stop": i32(4, 1)})
         donate = (6, 7)
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("program", ["step", "packed_prefill"])
+def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
+                                                    program, quant):
+    """GPT-2-medium's width at depth 2, through the Pallas path, as the
+    chip's compiler schedules it: the donated pools are aliased to the
+    outputs, one kernel a layer reads the stack, and nothing the program
+    produces but the K/V scatters is as large as one layer's pool — no
+    slice of a layer, no copy, no re-laid copy of the stack (PR 25: the
+    parent's programs held three pools' worth of those)."""
+    import re
+
+    from test_pool_in_place import pool_sized_instructions
+
+    compiled = _compile_serving_program(one_chip, monkeypatch, program,
+                                        quant)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == LAYERS
     n_pools = 4 if quant else 2   # codes and scales, or the blocks
@@ -296,6 +322,22 @@ def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
         # is narrower than the lanes): 1/64 of the codes, PERF.md section 7
         layer_bytes = 2 * layer_elems * 2
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def test_decode_step_at_the_serve_cell_size(one_chip, monkeypatch):
+    """The whole `decode_step` of `gpt2_medium.serve_closed32` (24 layers,
+    32 rows, 256 blocks): 24 kernels, each `paged_attn_decode`, and
+    temporaries under one layer's pool: the decode entry takes q and
+    returns its output as the [B, H*Dh] rows they are, so its wrapper
+    adds no padded, transposed or re-laid operand (PR 27; the parent's
+    8-row streams and [H, T, Dh] transposes were 22.8 MB here)."""
+    layers, blocks = 24, 256
+    compiled = _compile_serving_program(one_chip, monkeypatch, "step",
+                                        False, layers, blocks)
+    assert _kernel_names_in(compiled.as_text()) \
+        == ["paged_attn_decode"] * layers
+    one_layer_pool = 2 * blocks * BS * H * DH * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_pool
 
 
 def _sds(one_chip):

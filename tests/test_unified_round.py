@@ -45,6 +45,27 @@ def _mixed_stream_case(seed=0):
     return q, kb, vb, tables, seg, pos
 
 
+def _decode_cases():
+    """(id, int8 pool, pool stack with a traced layer / one layer's pool,
+    heads, head size, block, tables, contexts).  The first is the case of
+    the bit-equality test this one replaces; the others walk a block's
+    edges: a pad row (0), one token, exactly a block, one past it, the
+    whole table."""
+    first = ("dense-one_layer-4heads", False, False, 4, 8, 4,
+             [[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 2]], [11, 0, 16])
+    tables = [[0, 0, 0], [1, 0, 0], [2, 0, 0], [3, 4, 0], [5, 6, 7]]
+    lens = [0, 1, 8, 9, 24]
+    return [first] + [
+        (f"{'int8' if quant else 'dense'}-"
+         f"{'stack' if stacked else 'one_layer'}-{h}heads",
+         quant, stacked, h, 16, 8, tables, lens)
+        for quant in (False, True) for stacked in (True, False)
+        for h in (16, 8)]
+
+
+DECODE_CASES = _decode_cases()
+
+
 class TestUnifiedKernel:
     def test_interpret_kernel_matches_fallback_mixed_stream(self):
         import jax.numpy as jnp
@@ -86,33 +107,60 @@ class TestUnifiedKernel:
         valid = pos >= 0
         np.testing.assert_allclose(out[valid], ref[valid], atol=2e-4)
 
-    def test_decode_entry_is_the_stream_kernel_at_decode_tile(self):
-        """One kernel body: the one-token decode call must equal the
-        stream kernel fed the same rows as DECODE_TILE-row segments
-        (PR 21 deleted the separate decode body the chip's compiler
-        refused, and the two re-export shim modules with it)."""
+    @pytest.mark.parametrize("case", DECODE_CASES,
+                             ids=[c[0] for c in DECODE_CASES])
+    def test_decode_entry_matches_stream_kernel_and_gather(self, case):
+        """The decode entry has a body of its own since PR 27 (all heads
+        of a KV block in one pass: one score dot, one softmax, one value
+        dot): held, within float32 rounding, to the stream kernel fed the
+        same rows as one-token segments of 8-row tiles (the decode entry
+        until then) and to the XLA gather path, and to zeros where a row
+        sees nothing."""
+        import jax
         import jax.numpy as jnp
 
+        from paddle_tpu.inference.kv_quant import QuantizedKV, kv_encode
+        from paddle_tpu.ops import attention
         from paddle_tpu.ops.pallas import unified_attention as ua
 
+        _name, quant, stacked, h, dh, bs, tables, lens = case
+        tables, lens = np.asarray(tables, np.int32), np.asarray(lens)
         rs = np.random.RandomState(3)
-        b, h, dh, n, bs = 3, 4, 8, 9, 4
+        b, n, layers = len(lens), int(tables.max()) + 1, 3
         q = jnp.asarray(rs.randn(b, h, dh).astype(np.float32))
-        kb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
-        vb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
-        tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0],
-                                       [6, 7, 8, 2]], np.int32))
-        lens = jnp.asarray(np.array([11, 0, 16], np.int32))
-        out = ua.paged_decode_attention_kernel(q, kb, vb, tables, lens,
-                                               interpret=True)
-        qt = ua.DECODE_TILE
+
+        def pool():  # (what the entry takes, the same values as one layer)
+            x = jnp.asarray(rs.randn(layers, n, bs, h, dh)
+                            .astype(np.float32))
+            codes, scales = kv_encode(x) if quant else (x, None)
+            rows = codes.reshape(layers, n, bs, h * dh)
+            if quant:
+                return (QuantizedKV(rows, scales) if stacked
+                        else QuantizedKV(codes[1], scales[1]),
+                        QuantizedKV(codes[1], scales[1]))
+            return (rows if stacked else x[1]), x[1]
+
+        (k, k1), (v, v1) = pool(), pool()
+        tables, lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+        if stacked:  # the layer is a traced scalar in a program
+            out = jax.jit(lambda ly: ua.paged_decode_attention_kernel(
+                q, k, v, tables, lens, ly, interpret=True))(jnp.int32(1))
+        else:
+            out = ua.paged_decode_attention_kernel(q, k, v, tables, lens,
+                                                   interpret=True)
+        qt = 8
         stream = jnp.zeros((b, qt, h, dh), q.dtype).at[:, 0].set(q)
-        ref = ua.unified_ragged_attention_kernel(
-            stream.reshape(b * qt, h, dh), kb, vb, tables,
-            jnp.arange(b), lens - 1, q_tile=qt, interpret=True)[::qt]
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        assert not np.asarray(out[1]).any()  # ctx_len 0: a pad tile
-        assert not hasattr(ua, "_decode_kernel")
+        tiled = ua.unified_ragged_attention_kernel(
+            stream.reshape(b * qt, h, dh), k1, v1, tables, jnp.arange(b),
+            lens - 1, q_tile=qt, interpret=True)[::qt]
+        gathered = attention.paged_decode_attention(q, k1, v1, tables, lens)
+        out, seen = np.asarray(out), np.asarray(lens) > 0
+        assert seen.any() and not seen.all()
+        np.testing.assert_allclose(out, np.asarray(tiled), atol=2e-4)
+        np.testing.assert_allclose(out[seen], np.asarray(gathered)[seen],
+                                   atol=2e-4)
+        assert np.abs(out[seen]).max(axis=(1, 2)).all()
+        assert not out[~seen].any()  # ctx_len 0: a pad row, zeros
 
 
 def _serve(model, prompts, sampling_fn=None, timeout=300, **kw):
