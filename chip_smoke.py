@@ -165,7 +165,7 @@ def phase_eager(seed, platform):
 
 def make_train_step(cfg, optimizer):
     """bf16 compute on f32 master weights, the AdamW functional update:
-    the wiring of bench.py's training axis."""
+    the wiring of the benchmark's train cells (`benchmark/kinds/train.py`)."""
     import jax
     import jax.numpy as jnp
 

@@ -15,6 +15,7 @@ import ctypes
 import os
 import pickle
 import subprocess
+import tempfile
 import threading
 
 import numpy as np
@@ -28,10 +29,21 @@ _lib_lock = threading.Lock()
 
 
 def _build():
+    # Several processes may find no library at once (xdist workers, spawned
+    # loader workers): each compiles to a name of its own and renames it
+    # into place, so none ever loads a half-written file.
     cxx = os.environ.get("CXX", "g++")
-    cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO,
-           "-pthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
+    fd, tmp = tempfile.mkstemp(prefix=".libpaddle_tpu_native.", suffix=".so",
+                               dir=os.path.dirname(_SO))
+    os.close(fd)
+    try:
+        cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp,
+               "-pthread"]
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def get_lib():

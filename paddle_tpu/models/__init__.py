@@ -2,7 +2,7 @@
 
 Reference models: ERNIE/BERT-large pretraining + GPT-2 with fused attention
 (BASELINE.json configs; fluid transformer ops). These are the flagship models
-for bench.py and __graft_entry__.py.
+for the benchmark's cells (`benchmark/`) and __graft_entry__.py.
 """
 from __future__ import annotations
 

@@ -105,9 +105,7 @@ class GPT2(nn.Layer):
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                      bias_attr=False)
 
-    def hidden_states(self, input_ids, position_ids=None, attn_mask=None):
-        """Transformer body up to (and including) the final LayerNorm —
-        the pre-head activations the chunked CE consumes."""
+    def forward(self, input_ids, position_ids=None, attn_mask=None):
         s = input_ids.shape[1]
         if position_ids is None:
             position_ids = ops.arange(0, s, dtype="int32")
@@ -115,10 +113,7 @@ class GPT2(nn.Layer):
         x = self.drop(x)
         for block in self.h:
             x = block(x, attn_mask)
-        return self.ln_f(x)
-
-    def forward(self, input_ids, position_ids=None, attn_mask=None):
-        x = self.hidden_states(input_ids, position_ids, attn_mask)
+        x = self.ln_f(x)
         # head matmul on [B*S, E]: a 3-D head dot picks a sequence-minor
         # output layout on TPU and the loss's flatten then costs a full
         # [B,S,V] relayout copy (4.9ms/step at batch 16, r4 per-op profile
@@ -133,20 +128,6 @@ class GPT2(nn.Layer):
         return ops.reshape(logits2, [b, s, self.cfg.vocab_size])
 
     def loss(self, input_ids, labels):
-        import os
-        n_chunks = int(os.environ.get("PADDLE_TPU_CHUNKED_CE", "0"))
-        if n_chunks > 1 and self.cfg.tie_embeddings:
-            # vocab-chunked CE: never materializes [B*S, V] logits —
-            # flag-gated perf lever, parity-tested (ops/chunked_xent.py)
-            from ..ops._registry import apply_op
-            from ..ops.chunked_xent import chunked_softmax_xent
-            h = self.hidden_states(input_ids)
-            e = h.shape[-1]
-            return apply_op(
-                lambda hv, wv, lv: chunked_softmax_xent(
-                    hv.reshape(-1, e), wv, lv.reshape(-1), n_chunks),
-                "chunked_softmax_xent",
-                (h, self.wte.weight, labels), {})
         logits = self(input_ids)
         return ops.cross_entropy(
             ops.reshape(logits, [-1, self.cfg.vocab_size]),

@@ -1,7 +1,7 @@
 """paddle_tpu.observability — unified runtime telemetry (ISSUE 2) and
 the serving operations plane (ISSUE 10).
 
-Pillars, shared by serving, training, and bench:
+Pillars, shared by serving and training:
 
   * `metrics` — process-wide registry of counters/gauges/histograms
     with labels; Prometheus-text and JSON snapshot exporters; near-zero
@@ -19,8 +19,8 @@ Pillars, shared by serving, training, and bench:
     PADDLE_TPU_METRICS_PORT.
   * `compile_tracker` — exact XLA compile detection at the decode jit
     boundaries (`serving_xla_compiles_total{program,in_flight,shard}`),
-    always on, with a window API bench uses to prove measurement
-    windows compile-clean.
+    always on, with a window API the benchmark's cells use to prove
+    measurement windows compile-clean.
   * `flight_recorder` — bounded ring buffer of structured engine
     events + the stall watchdog that auto-dumps it (no-op when
     disabled, like all telemetry).
@@ -38,7 +38,8 @@ Pillars, shared by serving, training, and bench:
     exported as `slo_*` gauges and the `/slo` ops endpoint.
   * `timeline` — Chrome/Perfetto trace-event JSON export of the span
     sink + flight-recorder rings, per-replica-per-track
-    (`FleetRouter.export_timeline`, `bench.py served --timeline`).
+    (`FleetRouter.export_timeline`,
+    `PagedGenerationServer.export_timeline`).
   * `attribution` — ISSUE 17: per-tenant / per-request cost ledgers
     with exact integer conservation (device-seconds, KV
     block-seconds, host byte-seconds, wire bytes, compile time,

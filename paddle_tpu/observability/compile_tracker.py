@@ -1,9 +1,9 @@
 """XLA compile tracking at the jit boundaries (ISSUE 10 tentpole,
 part b).
 
-Two bench rounds were silently poisoned by untracked in-window XLA
-compiles (PERF.md r12/r13: one fresh packed-prefill bucket costs ~0.7s
-and lands on whatever requests are in flight). This module makes every
+Two measurement rounds were silently poisoned by untracked in-window
+XLA compiles (r12/r13: one fresh packed-prefill bucket costs ~0.7s and
+lands on whatever requests are in flight). This module makes every
 compile a first-class, attributable event:
 
   * `wrap(program, jit_fn)` returns a call-through wrapper that detects
@@ -29,8 +29,9 @@ detection is one C-level `_cache_size()` call, and a tracker that only
 counts while telemetry is enabled would misreport pre-enable buckets
 as fresh compiles. Metric emission still goes through the registry's
 enabled gate like everything else; the event log and `count_since()`
-window API work regardless, which is what lets `bench.py` prove a
-window compile-clean without enabling the full telemetry stack.
+window API work regardless, which is what lets a benchmark cell
+(`benchmark/metrics/compiles_in_window.serve.py`) prove a window
+compile-clean without enabling the full telemetry stack.
 """
 from __future__ import annotations
 
@@ -163,7 +164,7 @@ class CompileTracker:
 
     def count_since(self, mark, in_flight=None):
         """Compiles since `mark`, optionally only those with the given
-        in-flight flag — the bench's compile-clean-window assertion."""
+        in-flight flag — a cell's compile-clean-window assertion."""
         evs = self.events_since(mark)
         if in_flight is None:
             return len(evs)

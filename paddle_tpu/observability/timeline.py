@@ -21,9 +21,7 @@ https://ui.perfetto.dev open directly:
 
 Entry points: `write_chrome_trace(path, ...)` here,
 `FleetRouter.export_timeline(path)` /
-`PagedGenerationServer.export_timeline(path)` on the serving stack,
-and `bench.py served --timeline` which drops
-`telemetry/TELEMETRY_timeline.json` next to the other artifacts.
+`PagedGenerationServer.export_timeline(path)` on the serving stack.
 """
 from __future__ import annotations
 

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import functools as _functools
 import math as _math
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -739,47 +738,7 @@ def embedding(ids, weight, padding_idx=None, sparse=False):
         # padding row contributes no gradient (ref: lookup_table_v2_op padding_idx)
         frozen_row = jax.lax.stop_gradient(weight[padding_idx])
         weight = weight.at[padding_idx].set(frozen_row)
-    if _EMBED_ONEHOT_VJP:
-        return _embed_mm_vjp(weight, jnp.asarray(ids))
     return jnp.take(weight, jnp.asarray(ids), axis=0)
-
-
-# dW via one-hot matmul instead of scatter-add: XLA TPU lowers scatter with
-# duplicate indices poorly; the reduction runs on the MXU instead. The
-# one-hot only avoids materializing (XLA fuses iota==ids into the GEMM
-# operand) when the step is jitted — in pure eager mode each backward
-# builds the full [tokens, vocab] array, so this flag is meant for
-# jitted/@to_static training. Opt-in until the on-chip microbench
-# (scripts/raw_ops_bench.py §6) shows which side wins at model shapes.
-_EMBED_ONEHOT_VJP = _os.environ.get("PADDLE_TPU_EMBED_ONEHOT_VJP") == "1"
-
-
-@_functools.lru_cache(maxsize=None)
-def _embed_mm_vjp_for(vocab):
-    @jax.custom_vjp
-    def f(weight, ids):
-        return jnp.take(weight, ids, axis=0)
-
-    def fwd(weight, ids):
-        return jnp.take(weight, ids, axis=0), ids
-
-    def bwd(ids, g):
-        import numpy as _np
-        flat_ids = ids.reshape(-1)
-        gf = g.reshape(flat_ids.shape[0], g.shape[-1])
-        onehot = jax.nn.one_hot(flat_ids, vocab, dtype=gf.dtype)
-        dw = jax.lax.dot_general(onehot, gf, (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        # take() preserves dtype, so g's dtype == weight's dtype
-        return (dw.astype(g.dtype),
-                _np.zeros(ids.shape, jax.dtypes.float0))
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
-def _embed_mm_vjp(weight, ids):
-    return _embed_mm_vjp_for(weight.shape[0])(weight, ids)
 
 
 @defop(nondiff=True)

@@ -14,7 +14,6 @@ flash-attention training recipe (dq kernel + dkv kernel, delta = rowsum(dO·O)).
 from __future__ import annotations
 
 import functools
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -118,76 +117,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, nk,
                                       lse_ref.shape)
 
 
-def _fwd_kernel_lanes(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                      l_ref, *, scale, causal, nk):
-    """Forward variant with LANE-REPLICATED online-softmax state: m/l live as
-    [bq, 128] registers holding the row statistic in every lane (the stock
-    TPU kernel's layout), so the `s - m` / `acc * alpha` broadcasts are
-    register tiles instead of cross-lane broadcasts from a [bq, 1] slice.
-    Opt-in via PADDLE_TPU_FA_LANES=1 for on-chip A/B; requires bk % 128 == 0
-    and d <= 128 (the default 512/64 config qualifies)."""
-    bq, d = q_ref.shape
-    bk = k_ref.shape[0]
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    run = (ki * bk < (qi + 1) * bq) if causal else (ki >= 0)
-    diag = ((ki + 1) * bk > qi * bq) if causal else False
-
-    def _compute(apply_mask):
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if scale != 1.0:
-            s = s * scale
-        if apply_mask:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_ref[:]                      # [bq, 128] replicated
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=1)[:, None]    # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)     # [bq, 128] replicated
-        p = jnp.exp(s - jnp.tile(m_new, (1, bk // 128)))
-        alpha = jnp.exp(m_prev - m_new)        # [bq, 128]
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1)[:, None]
-        acc_ref[:] = acc_ref[:] * alpha[:, :d] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
-
-    if causal:
-        @pl.when(run & diag)
-        def _compute_diag():
-            _compute(True)
-
-        @pl.when(run & jnp.logical_not(diag))
-        def _compute_full():
-            _compute(False)
-    else:
-        @pl.when(run)
-        def _compute_all():
-            _compute(False)
-
-    @pl.when(ki == nk - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[:], 1e-30)       # [bq, 128] replicated
-        o_ref[:] = (acc_ref[:] / l[:, :d]).astype(o_ref.dtype)
-        lse_ref[:] = (m_ref[:, :LSE_LANES] +
-                      jnp.log(l[:, :LSE_LANES]))
-
-
-_FA_LANES = _os.environ.get("PADDLE_TPU_FA_LANES") == "1"
-
-
 def _divisor_block(size, block):
     """Largest block <= `block` that divides `size` — 128-aligned when
     possible (TPU lane width); sub-128 blocks only appear in interpret-mode
@@ -224,13 +153,8 @@ def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret,
     nk = sk // bk
     grid = (b * h, sq // bq, nk)
     has_bias = bias is not None
-    use_lanes = _FA_LANES and bk % 128 == 0 and d <= 128 and not has_bias
-    kernel = functools.partial(
-        _fwd_kernel_lanes if use_lanes else _fwd_kernel,
-        scale=scale, causal=causal, nk=nk)
-    if not use_lanes:
-        kernel = functools.partial(kernel, has_bias=has_bias)
-    ml_lanes = 128 if use_lanes else LSE_LANES
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                               nk=nk, has_bias=has_bias)
     mem_kwargs = {}
     if not interpret:
         mem_kwargs = {"memory_space": pltpu.VMEM}
@@ -263,8 +187,8 @@ def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret,
                          **mem_kwargs),
         ),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq, ml_lanes), jnp.float32),
-                        pltpu.VMEM((bq, ml_lanes), jnp.float32)],
+                        pltpu.VMEM((bq, LSE_LANES), jnp.float32),
+                        pltpu.VMEM((bq, LSE_LANES), jnp.float32)],
         interpret=interpret,
         **_compiler_params(("parallel", "parallel", "arbitrary")),
     )(*operands)

@@ -183,8 +183,8 @@ def enable_persistent_compilation_cache():
     directory.  Where `JAX_COMPILATION_CACHE_DIR` is set, jax already
     reads the directory from it and none is set in code; otherwise the
     cache is `<checkout>/.jax_cache`, one fixed path (the path is part
-    of the cache key).  One definition for chip_smoke.py, bench.py and
-    the perf/endurance scripts; a directory that cannot be created is
+    of the cache key).  One definition for chip_smoke.py and the
+    perf/endurance scripts; a directory that cannot be created is
     an error."""
     import os as _os
 

@@ -20,9 +20,9 @@ def sync(out):
 
 def gpt2_amp_setup():
     """Shared GPT-2-small AMP harness for the perf sections: returns
-    (cfg, params0, amp_loss, make_data) with the exact bf16-compute /
-    f32-master recipe bench.py times, so every sweep measures the same
-    configuration as the headline bench."""
+    (cfg, params0, amp_loss, make_data) with the bf16-compute /
+    f32-master recipe of the benchmark's train cells, so every sweep
+    measures one configuration."""
     import numpy as np
 
     from paddle_tpu.models.gpt2 import GPT2Config, build_train_step

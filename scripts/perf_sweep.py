@@ -1,6 +1,6 @@
 """Profile-driven perf sweep on the real TPU chip (VERDICT r2 next #1).
 
-Measures, with the same device_get-scalar barrier bench.py uses:
+Measures, behind a device_get-scalar barrier (`_bench_util.sync`):
   1. step-time decomposition: fwd / fwd+bwd / full train step
   2. per-chip batch sweep at seq=1024
   3. flash-attention block_q/block_k sweep (microbench, B=8 H=12 S=1024 D=64)
@@ -124,24 +124,6 @@ def section_flash_blocks():
         except Exception as e:  # noqa: BLE001
             print(f"blocks=({bq},{bk}): FAILED {type(e).__name__}: "
                   f"{str(e)[:100]}", flush=True)
-
-    # A/B the lane-replicated m/l forward variant at the default blocks
-    import paddle_tpu.ops.pallas.flash_attention as fa_mod
-    orig_lanes = fa_mod._FA_LANES
-    try:
-        for lanes in (False, True):
-            fa_mod._FA_LANES = lanes
-
-            def fwd_step(c):
-                qc = q + (c * 1e-30).astype(q.dtype)
-                o = flash_attention(qc, k, v, True, None, 512, 512)
-                return o.astype(jnp.float32).mean()
-
-            t_f = _scan_timer(fwd_step, jnp.zeros((), jnp.float32))
-            print(f"lanes_variant={lanes}: fwd={t_f*1e3:.2f}ms "
-                  f"({flops_f/t_f/1e12:.0f}TF/s)", flush=True)
-    finally:
-        fa_mod._FA_LANES = orig_lanes
 
 
 def section_longseq():

@@ -143,40 +143,6 @@ def main():
           f"~{byts/t/1e9:.0f}GB/s", flush=True)
     mark("hbm")
 
-    # 6. embedding bwd: gather+scatter-add vs one-hot matmul at GPT-2-small
-    # shapes (16384 tokens, vocab 50257, d 768). XLA TPU scatter can be
-    # orders slower than MXU work — if `embed bwd scatter` >> `embed bwd
-    # onehot`, the model should embed via one-hot matmul.
-    V, E, T = 50257, 768, 16384
-    wte = jax.random.normal(jax.random.fold_in(key, 3), (V, E), jnp.bfloat16)
-    ids = jax.random.randint(jax.random.fold_in(key, 4), (T,), 0, V)
-
-    # both variants share the same take() forward — the difference
-    # isolates the backward: XLA scatter-add vs fused one-hot GEMM
-    # (paddle_tpu.ops.nn_ops._embed_mm_vjp, the flagged model path)
-    from paddle_tpu.ops import nn_ops
-
-    def embed_gather(c, wt):
-        w = wt + c.astype(jnp.bfloat16)
-        g = jax.grad(lambda ww: jnp.take(ww, ids, axis=0).astype(
-            jnp.float32).sum())(w)
-        return g.astype(jnp.float32).mean()
-
-    t = scan_time_args(embed_gather, z, wte, inner=5)
-    print(f"embed bwd scatter [16384 of 50257x768]: {t*1e3:.3f}ms",
-          flush=True)
-
-    def embed_onehot(c, wt):
-        w = wt + c.astype(jnp.bfloat16)
-        g = jax.grad(lambda ww: nn_ops._embed_mm_vjp(ww, ids).astype(
-            jnp.float32).sum())(w)
-        return g.astype(jnp.float32).mean()
-
-    t = scan_time_args(embed_onehot, z, wte, inner=5)
-    print(f"embed bwd onehot  [16384 of 50257x768]: {t*1e3:.3f}ms",
-          flush=True)
-    mark("embed")
-
 
 if __name__ == "__main__":
     main()

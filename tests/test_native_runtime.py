@@ -1,18 +1,18 @@
 """Native C++ runtime tests (queue, arena, prefetching DataLoader)."""
+import os
+import shutil
+
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.io import native_loader as native
 
-native = pytest.importorskip("paddle_tpu.io.native_loader")
-
-try:
-    native.get_lib()
-    HAVE_CC = True
-except Exception:
-    HAVE_CC = False
-
-pytestmark = pytest.mark.skipif(not HAVE_CC, reason="no C++ toolchain")
+# With a compiler on the host a failing build or load is an error of the
+# build step, not a reason to skip.
+pytestmark = pytest.mark.skipif(
+    shutil.which(os.environ.get("CXX", "g++")) is None,
+    reason="no C++ compiler on this host")
 
 
 class TestByteQueue:

@@ -178,6 +178,43 @@ class TestContractionGrads:
 
         check_grad(fn, _any((4, 3)))
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_embedding_weight_grad_repeated_ids(self, dtype):
+        """Ids that repeat accumulate in d(weight): it equals the one-hot
+        matmul of the upstream cotangent, computed here in float64 — for
+        the scatter-add of `jnp.take`'s backward, the one path the lookup
+        has."""
+        rs = np.random.RandomState(0)
+        vocab, width = 31, 7
+        ids = rs.randint(0, vocab, (5, 4))
+        assert np.unique(ids).size < ids.size      # some id repeats
+        w = P.to_tensor(rs.randn(vocab, width).astype(np.float32)) \
+            .astype(dtype)
+        w.stop_gradient = False
+        proj = P.to_tensor(rs.rand(5, 4, width).astype(np.float32) + 0.5) \
+            .astype(dtype)
+        (P.nn.functional.embedding(P.to_tensor(ids), w) * proj).sum() \
+            .backward()
+        assert str(w.grad.dtype).endswith(dtype)
+        onehot = np.eye(vocab, dtype=np.float64)[ids.reshape(-1)]
+        want = onehot.T @ np.asarray(proj.astype("float32").numpy(),
+                                     np.float64).reshape(-1, width)
+        tol = 1e-6 if dtype == "float32" else 2.0 ** -7
+        np.testing.assert_allclose(
+            np.asarray(w.grad.astype("float32").numpy(), np.float64), want,
+            rtol=tol, atol=tol)
+
+    def test_embedding_negative_padding_idx_normalized(self):
+        """padding_idx=-1 names row vocab-1 (the reference's
+        lookup_table_v2), whose gradient stays zero; direct op callers
+        (static.nn.embedding) pass it through raw."""
+        w = P.to_tensor(np.ones((5, 3), np.float32), stop_gradient=False)
+        x = P.to_tensor(np.array([[4, 1]], np.int64))
+        P.ops.embedding(x, w, padding_idx=-1).sum().backward()
+        g = w.grad.numpy()
+        np.testing.assert_allclose(g[4], 0.0)
+        assert np.abs(g[1]).sum() > 0
+
     def test_conv2d_functional(self):
         check_grad(
             lambda x, w: P.nn.functional.conv2d(x, w, stride=1, padding=1),

@@ -1,17 +1,23 @@
-"""Quantized serving hot path (tentpole round): W8A16 weights in the
-engine + int8 paged KV cache.
+"""Quantized serving hot path: W8A16 weights in the engine + int8 paged
+KV cache.
 
-The PARITY SUITE the feature is gated behind: on the fixed-seed served
-workloads below, W8A16 and W8A16+int8-KV greedy tokens must MATCH the
-bf16 outputs token-for-token across plain decode, chunked packed
-prefill, speculative-decode verification, prefix-cache ON/OFF, and
-preempt/resume — and final-step logits must stay within the documented
-tolerance (per-vector int8 absmax: |delta| bounded by the absmax/254
-round-trip error propagated once through attention; empirically < 2%
-of the logit scale on these configs, asserted at 5% headroom).
-Quantization CAN flip an argmax in general — the guarantee is exact
-parity on these pinned workloads plus bounded logit drift, which is
-the policy documented in docs/SERVING.md ("Quantized serving").
+The parity policy the feature is gated behind (docs/SERVING.md,
+"Quantized serving"): int8 moves a logit by a bounded amount — per-vector
+absmax round-trip error propagated once through attention, empirically
+under 2% of the logit scale on these configs, asserted at LOGIT_TOL = 5%
+— so it CAN flip an argmax wherever the unquantized model's two best
+logits lie closer than that. The served tests below therefore hold W8A16
+and W8A16+int8-KV greedy tokens to the unquantized run's **up to the
+reference's first near-tie** (top-2 margin under LOGIT_TOL of that step's
+logit scale, read from one full float32 forward on this host): there
+either candidate passes and the tail, which follows from that choice, is
+not compared (tests/near_tie.py). Prompts are drawn from each test's
+seeded stream until the reference meets no near-tie, and every case
+asserts that at least 80% of the generated tokens were compared — no
+token, seed or stop position is pinned to another host's rounding.
+Covered: plain decode, chunked packed prefill, speculative-decode
+verification, prefix-cache ON/OFF, preempt/resume; final-step logits
+within the tolerance.
 
 Plus the satellites: quantize->dequantize round-trip error bound for
 the absmax scheme, scale-buffer lockstep under CoW, the eager
@@ -24,8 +30,11 @@ from paddle_tpu.inference import PagedGenerationServer, QuantizedKV
 from paddle_tpu.inference.kv_cache import PagedKVCache
 from paddle_tpu.inference.kv_quant import kv_decode, kv_encode
 from paddle_tpu.models.gpt2 import GPT2, GPT2Config
+from paddle_tpu.spec_decode import SpecConfig
 
-LOGIT_TOL = 0.05  # documented tolerance: see docs/SERVING.md
+from near_tie import (LOGIT_TOL, clear_prompt, compare_to_first_near_tie,
+                      compare_workload, ngram_drafts_during,
+                      uniform_prompts)
 
 
 @pytest.fixture(scope="module")
@@ -205,45 +214,103 @@ QUANT_MODES = [
 ]
 
 
-class TestServedParity:
-    """Greedy token parity vs bf16 on the pinned served workloads."""
+@pytest.fixture(scope="module")
+def chunked_workload(tiny_model):
+    """Prompts longer than the chunk budget (2-3 chunk dispatches each)
+    and their unquantized served reference."""
+    model, cfg = tiny_model
+    rs = np.random.RandomState(0)
+    prompts = [clear_prompt(model, uniform_prompts(rs, cfg.vocab_size, n), 8)
+               for n in (36, 30, 25, 21)]
+    return prompts, _serve(model, prompts, prefill_chunk_tokens=16)[0]
 
-    def _prompts(self, cfg, n=5, lo=4, hi=20, seed=7):
-        rs = np.random.RandomState(seed)
-        return [rs.randint(1, cfg.vocab_size,
-                           (int(rs.randint(lo, hi)),)).astype(np.int32)
-                for _ in range(n)]
+
+@pytest.fixture(scope="module")
+def prefix_workload(tiny_model):
+    """Five prompts over one 14-token prefix, and their reference."""
+    model, cfg = tiny_model
+    rs = np.random.RandomState(11)
+    prefix = rs.randint(1, cfg.vocab_size, (14,))
+
+    def draw(tail):
+        return lambda n: np.concatenate(
+            [np.tile(prefix, (n, 1)),
+             rs.randint(1, cfg.vocab_size, (n, tail))], axis=1)
+
+    prompts = [clear_prompt(model, draw(int(rs.randint(2, 8))), 8)
+               for _ in range(5)]
+    return prompts, _serve(model, prompts)[0]
+
+
+class TestNearTieRule:
+    """The comparator itself, on logits written by hand: a prompt of 2,
+    4 new tokens, the third of them decided by 0.01 between 5 and 6."""
+
+    def _logits(self):
+        lg = np.zeros((6, 8), np.float32)
+        for row, tok in ((1, 3), (2, 4), (4, 7)):
+            lg[row, tok] = 1.0
+        lg[3, 5], lg[3, 6] = 1.0, 0.99
+        return lg
+
+    @pytest.mark.parametrize("out,verdict", [
+        ([1, 2, 3, 4, 5, 7], 2),          # the reference itself
+        ([1, 2, 3, 4, 6, 0], 2),          # the other side of the tie
+        ([1, 2, 3, 4, 6], 2),             # ... and a tail that ended
+        ([1, 2, 3, 4, 2, 7], "fail"),     # neither candidate
+        ([1, 2, 3, 0, 5, 7], "fail"),     # differs where the choice is clear
+        ([1, 2, 3, 4], "fail"),           # ended before the tie
+    ])
+    def test_compares_up_to_the_first_near_tie(self, out, verdict):
+        ref = [1, 2, 3, 4, 5, 7]
+        if verdict == "fail":
+            with pytest.raises(AssertionError):
+                compare_to_first_near_tie(ref, out, 2, self._logits())
+        else:
+            assert compare_to_first_near_tie(
+                ref, out, 2, self._logits()) == verdict
+
+    def test_clear_reference_is_compared_whole(self):
+        lg = self._logits()
+        lg[3, 6] = 0.5
+        ref = [1, 2, 3, 4, 5, 7]
+        assert compare_to_first_near_tie(ref, ref, 2, lg) == 4
+        with pytest.raises(AssertionError):
+            compare_to_first_near_tie(ref, ref[:5] + [0], 2, lg)
+
+    def test_hollow_comparison_is_refused(self, tiny_model):
+        """A workload whose reference ties at once compares nothing: the
+        share assertion must say so rather than pass."""
+        model, cfg = tiny_model
+        p = np.arange(1, 9, dtype=np.int32)
+        ref = model.generate(p[None], 4).numpy()[0]
+        with pytest.raises(AssertionError, match="near-tie"):
+            compare_workload(model, [ref], [ref], [p], tol=1e9)
+
+
+class TestServedParity:
+    """Greedy tokens against the unquantized engine's, up to the
+    reference's first near-tie (tests/near_tie.py; module docstring)."""
 
     @pytest.mark.parametrize("name,qkw", QUANT_MODES)
-    def test_decode_and_chunked_prefill_parity(self, tiny_model, name,
+    def test_decode_and_chunked_prefill_parity(self, tiny_model,
+                                               chunked_workload, name,
                                                qkw):
-        """Plain decode + multi-chunk packed prefill: prompts longer
-        than the chunk budget force 2-3 chunk dispatches per prompt.
-        (PINNED workload — quantization can flip an argmax in general;
-        the parity policy asserts exact greedy agreement on these
-        fixed seeds, see module docstring.)"""
+        """Plain decode + multi-chunk packed prefill."""
         model, cfg = tiny_model
-        ids = np.random.RandomState(0).randint(
-            1, cfg.vocab_size, (4, 36)).astype(np.int32)
-        prompts = [ids[i, :n] for i, n in enumerate((36, 30, 25, 21))]
-        ref, _ = _serve(model, prompts, prefill_chunk_tokens=16)
+        prompts, ref = chunked_workload
         out, st = _serve(model, prompts, prefill_chunk_tokens=16, **qkw)
-        for a, b in zip(ref, out):
-            np.testing.assert_array_equal(a, b)
+        compare_workload(model, ref, out, prompts)
         assert st["quantization"]["enabled"] is True
 
     @pytest.mark.parametrize("name,qkw", QUANT_MODES[:2])
-    def test_prefix_cache_on_off_parity(self, tiny_model, name, qkw):
-        """Prefix-cache ON (shared prefix pool, publish + attach + CoW)
-        must equal cache-OFF must equal bf16 — the scale buffers ride
-        the shared blocks."""
+    def test_prefix_cache_on_off_parity(self, tiny_model, prefix_workload,
+                                        name, qkw):
+        """Prefix-cache ON (shared prefix pool, publish + attach + CoW),
+        cache OFF and a warm index all agree with the unquantized
+        engine — the scale buffers ride the shared blocks."""
         model, cfg = tiny_model
-        rs = np.random.RandomState(11)
-        prefix = rs.randint(1, cfg.vocab_size, (14,)).astype(np.int32)
-        prompts = [np.concatenate([prefix, rs.randint(
-            1, cfg.vocab_size, (int(rs.randint(2, 8)),)
-        ).astype(np.int32)]) for _ in range(5)]
-        ref, _ = _serve(model, prompts)
+        prompts, ref = prefix_workload
         off, _ = _serve(model, prompts, **qkw)
         on, st_on = _serve(model, prompts, enable_prefix_cache=True,
                            **qkw)
@@ -261,10 +328,8 @@ class TestServedParity:
             assert srv.cache.stats()["prefix_cache"]["hit_tokens"] > 0
         finally:
             srv.stop()
-        for a, b, c, d in zip(ref, off, on, warm):
-            np.testing.assert_array_equal(a, b)
-            np.testing.assert_array_equal(a, c)
-            np.testing.assert_array_equal(a, d)
+        for out in (off, on, warm):
+            compare_workload(model, ref, out, prompts)
 
     @pytest.mark.parametrize("name,qkw", QUANT_MODES[:2])
     def test_spec_decode_verify_parity(self, tiny_model, name, qkw):
@@ -272,21 +337,31 @@ class TestServedParity:
         over a quantized engine. TWO guarantees, asserted separately:
         the ENGINE invariant — quantized speculative output is
         token-identical to quantized non-speculative output no matter
-        the acceptance pattern (holds for ANY weights) — and the
-        pinned-workload parity vs the bf16 server."""
+        the acceptance pattern (one engine, one arithmetic) — and
+        parity with the unquantized server up to a near-tie."""
         model, cfg = tiny_model
         rs = np.random.RandomState(3)
-        prompts = []
-        for _ in range(4):
-            motif = rs.randint(1, cfg.vocab_size, (3,)).astype(np.int32)
-            prompts.append(np.tile(motif, 5)[:15])
-        ref, _ = _serve(model, prompts, max_new=10)
+
+        def draw(n):  # a 3-token motif repeated: the n-gram drafter's food
+            return np.tile(rs.randint(1, cfg.vocab_size, (n, 3)),
+                           (1, 5))[:, :15]
+
+        # the counters asserted below need a proposal: take the first
+        # workload of the stream on whose tokens, as this host computes
+        # them, the default drafter finds one
+        for _ in range(16):
+            prompts = [clear_prompt(model, draw, 10) for _ in range(4)]
+            ref, _ = _serve(model, prompts, max_new=10)
+            if any(ngram_drafts_during(r, 15, SpecConfig()) for r in ref):
+                break
+        else:
+            raise AssertionError("no workload the n-gram drafter drafts on")
         qplain, _ = _serve(model, prompts, max_new=10, **qkw)
         qspec, st = _serve(model, prompts, max_new=10,
                            speculation=True, **qkw)
-        for a, b, c in zip(ref, qplain, qspec):
+        for b, c in zip(qplain, qspec):
             np.testing.assert_array_equal(b, c)  # engine invariant
-            np.testing.assert_array_equal(a, b)  # pinned parity
+        compare_workload(model, ref, qplain, prompts)
         assert st["speculation"]["verify_dispatches"] >= 1
         assert st["speculation"]["proposed_tokens"] > 0
 
@@ -294,13 +369,14 @@ class TestServedParity:
     def test_preempt_resume_parity(self, tiny_model, name, qkw):
         """Preempt-then-resume through the quantized pool: swap-out
         publishes int8 blocks + scales, resume attaches them — output
-        token-identical to the uninterrupted bf16 run."""
+        token-identical to the uninterrupted quantized run, which
+        agrees with the unquantized model up to a near-tie."""
         from paddle_tpu.frontend import FrontDoor
 
         model, cfg = tiny_model
-        rs = np.random.RandomState(2)  # pinned parity-stable workload
-        pv = rs.randint(1, cfg.vocab_size, (1, 7)).astype(np.int32)[0]
-        pi = rs.randint(1, cfg.vocab_size, (1, 4)).astype(np.int32)[0]
+        rs = np.random.RandomState(2)
+        pv = clear_prompt(model, uniform_prompts(rs, cfg.vocab_size, 7), 24)
+        pi = clear_prompt(model, uniform_prompts(rs, cfg.vocab_size, 4), 3)
 
         def run(**skw):
             fd = FrontDoor(model, max_slots=1, block_size=4,
@@ -323,8 +399,8 @@ class TestServedParity:
             return out_v, out_i
 
         # engine invariant: preempted == uninterrupted on the SAME
-        # quantized engine (holds for any weights); then pinned parity
-        # of the uninterrupted quantized run vs the bf16 model
+        # quantized engine (one arithmetic); then the uninterrupted
+        # quantized run against the unquantized model
         (qref_v,), (qref_i,) = (
             _serve(model, [pv], max_new=24, max_slots=1,
                    max_prompt_len=16, **qkw)[0],
@@ -333,19 +409,24 @@ class TestServedParity:
         out_v, out_i = run(**qkw)
         np.testing.assert_array_equal(out_v, qref_v)
         np.testing.assert_array_equal(out_i, qref_i)
-        np.testing.assert_array_equal(
-            out_v, model.generate(pv[None], 24).numpy()[0])
-        np.testing.assert_array_equal(
-            out_i, model.generate(pi[None], 3).numpy()[0])
+        compare_workload(
+            model, [model.generate(pv[None], 24).numpy()[0],
+                    model.generate(pi[None], 3).numpy()[0]],
+            [qref_v, qref_i], [pv, pi])
 
     def test_sampled_requests_deterministic_quantized(self, tiny_model):
         """Fixed-seed sampled traffic on the quantized engine is
         deterministic (counter-based PRNG is dtype-agnostic): two
-        identical quantized servers agree token-for-token."""
+        identical quantized servers agree token-for-token. Both runs
+        are one engine on one host, so nothing here is compared across
+        arithmetics and equality is exact."""
         from paddle_tpu.sampling import SamplingParams
 
         model, cfg = tiny_model
-        prompts = self._prompts(cfg, n=3, seed=17)
+        rs = np.random.RandomState(17)
+        prompts = [rs.randint(1, cfg.vocab_size,
+                              (int(rs.randint(4, 20)),)).astype(np.int32)
+                   for _ in range(3)]
         sp = SamplingParams(temperature=0.8, top_p=0.9, seed=123)
         a, _ = _serve(model, prompts, sampling=sp, kv_dtype="int8",
                       quantization="w8a16")
@@ -372,7 +453,11 @@ class TestLogitTolerance:
         wq = model.quantize_weights(params)
         rs = np.random.RandomState(2)
         B, S, new, bs = 3, 12, 5, 4
-        ids = rs.randint(1, cfg.vocab_size, (B, S)).astype(np.int32)
+        # rows whose reference meets no near-tie: each run feeds itself
+        # its own tokens, so a flipped tie would compare two different
+        # sequences' logits
+        draw = uniform_prompts(rs, cfg.vocab_size, S)
+        ids = np.stack([clear_prompt(model, draw, new) for _ in range(B)])
         lens = np.full((B,), S, np.int32)
 
         def run(p, kvd):
@@ -407,7 +492,7 @@ class TestLogitTolerance:
 
         t_ref, l_ref = run(params, None)
         t_q, l_q = run(wq, "int8")
-        np.testing.assert_array_equal(t_ref, t_q)  # greedy parity
+        np.testing.assert_array_equal(t_ref, t_q)  # no tie: one sequence
         delta = np.abs(l_q - l_ref)
         scale = np.abs(l_ref).max()
         assert delta.max() <= LOGIT_TOL * max(scale, 1.0), \
@@ -569,11 +654,16 @@ class TestOfflinePagedKV8:
     def test_generate_paged_kv8_matches_bf16(self, tiny_model):
         """models/gpt2.py seam: the offline paged path serves the same
         quantized configuration (kv_quant='int8', optionally stacked
-        on weight_quant) with greedy parity on the pinned seed."""
+        on weight_quant), greedy tokens as the unquantized path's up to
+        a near-tie."""
         model, cfg = tiny_model
         rs = np.random.RandomState(0)
-        ids = rs.randint(1, cfg.vocab_size, (3, 9)).astype(np.int32)
         lens = [9, 6, 4]
+        prompts = [clear_prompt(
+            model, uniform_prompts(rs, cfg.vocab_size, n), 6) for n in lens]
+        ids = np.zeros((3, 9), np.int32)
+        for i, p in enumerate(prompts):
+            ids[i, :len(p)] = p
         ref = model.generate(ids, 6, kv_cache="paged", block_size=4,
                              prompt_lens=lens).numpy()
         kv8 = model.generate(ids, 6, kv_cache="paged", block_size=4,
@@ -581,5 +671,6 @@ class TestOfflinePagedKV8:
         both = model.generate(ids, 6, kv_cache="paged", block_size=4,
                               prompt_lens=lens, kv_quant="int8",
                               weight_quant="int8").numpy()
-        np.testing.assert_array_equal(ref, kv8)
-        np.testing.assert_array_equal(ref, both)
+        rows = lambda out: [out[i, :n + 6] for i, n in enumerate(lens)]
+        for out in (kv8, both):
+            compare_workload(model, rows(ref), rows(out), prompts)

@@ -17,6 +17,9 @@ from paddle_tpu.sampling import SamplingParams
 from paddle_tpu.spec_decode import (DraftModelDrafter, NgramDrafter,
                                     SpecConfig, build_verify_plan)
 
+from near_tie import (clear_prompt, compare_workload, ngram_drafts_during,
+                      uniform_prompts)
+
 
 @pytest.fixture(scope="module")
 def tiny_model():
@@ -259,12 +262,22 @@ class TestSpecParity:
 
     def test_greedy_ngram_matches_plain(self, tiny_model):
         model, cfg = tiny_model
+        spec = SpecConfig(max_draft_tokens=3)
+        # the first workload of the seeded stream in whose plain run the
+        # n-gram drafter finds something to propose (a random model need
+        # not repeat itself), checked on this host's own tokens
         rs = np.random.RandomState(1)
-        prompts = [rs.randint(1, cfg.vocab_size, (n,)).astype(np.int32)
-                   for n in (3, 7, 5, 9)]
-        subs = [(p, None) for p in prompts]
-        ref, _ = _serve(model, subs)
-        out, st = _serve(model, subs, spec=SpecConfig(max_draft_tokens=3))
+        for _ in range(16):
+            prompts = [rs.randint(1, cfg.vocab_size, (n,)).astype(np.int32)
+                       for n in (3, 7, 5, 9)]
+            subs = [(p, None) for p in prompts]
+            ref, _ = _serve(model, subs)
+            if any(ngram_drafts_during(r, p.size, spec)
+                   for r, p in zip(ref, prompts)):
+                break
+        else:
+            raise AssertionError("no workload the n-gram drafter drafts on")
+        out, st = _serve(model, subs, spec=spec)
         for i, (a, b) in enumerate(zip(ref, out)):
             np.testing.assert_array_equal(a, b, err_msg=f"row {i}")
         sp = st["speculation"]
@@ -394,15 +407,30 @@ class TestSpecParity:
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(a, r)  # == non-speculative
 
-    def test_stop_token_inside_accepted_prefix(self, tiny_model):
+    @pytest.fixture(scope="class")
+    def distinct_start(self, tiny_model):
+        """A prompt whose served continuation starts with three distinct
+        tokens (a random tiny model repeats itself, and a stop token that
+        also came earlier would end the request there): the first such
+        prompt of a seeded stream, checked on this host's own run."""
+        model, cfg = tiny_model
+        rs = np.random.RandomState(7)
+        for _ in range(64):
+            p = rs.randint(1, cfg.vocab_size, (4,)).astype(np.int32)
+            ref = _serve(model, [(p, None)], max_new_tokens=6)[0][0]
+            if len(set(ref[p.size:p.size + 3].tolist())) == 3:
+                return p, ref
+        raise AssertionError("no prompt with three distinct first tokens")
+
+    @pytest.mark.parametrize("position", [1, 2, 3])
+    def test_stop_token_inside_accepted_prefix(self, tiny_model,
+                                               distinct_start, position):
         """A stop token emitted mid-prefix must end the request there —
         accepted drafts beyond it are discarded, matching plain
         decode's behavior exactly."""
         model, cfg = tiny_model
-        rs = np.random.RandomState(7)
-        p = rs.randint(1, cfg.vocab_size, (4,)).astype(np.int32)
-        ref = _serve(model, [(p, None)], max_new_tokens=6)[0][0]
-        stop = int(ref[p.size + 2])      # third generated token
+        p, ref = distinct_start
+        stop = int(ref[p.size + position - 1])
         sp = SamplingParams(stop_token_ids=(stop,))
         plain = _serve(model, [(p, sp)], max_new_tokens=6)[0][0]
         # K=7 reuses the oracle test's compiled verify width
@@ -410,7 +438,7 @@ class TestSpecParity:
                           drafter=ReplayDrafter([ref]))
         out, st = _serve(model, [(p, sp)], max_new_tokens=6, spec=spec)
         np.testing.assert_array_equal(out[0], plain)
-        assert out[0].size == p.size + 3
+        assert out[0].size == p.size + position
         assert out[0][-1] == stop
         assert st["stop_reasons"]["stop_token"] == 1
 
@@ -425,10 +453,15 @@ class TestSpecParity:
 
         model, cfg = tiny_model
         rs = np.random.RandomState(8)
+        # the drafter replays this engine's own continuation, so the very
+        # first decode round holds a proposal and dispatches a verify
+        rep = np.tile(np.array([5, 6, 7], np.int32), 4)
+        served = _serve(model, [(rep, None)], max_new_tokens=4)[0]
         srv = PagedGenerationServer(
             model, max_slots=2, block_size=4, max_prompt_len=16,
             max_new_tokens=4, recovery=False,
-            speculation=SpecConfig(max_draft_tokens=3))
+            speculation=SpecConfig(max_draft_tokens=3,
+                                   drafter=ReplayDrafter(served)))
         boom = {"armed": True}
         real = srv._decoder.packed_verify
 
@@ -440,17 +473,16 @@ class TestSpecParity:
         monkeypatch.setattr(srv._decoder, "packed_verify", flaky)
         srv.start()
         try:
-            # repetitive prompt guarantees an n-gram proposal on the
-            # very first decode round
-            rep = np.tile(np.array([5, 6, 7], np.int32), 4)
             bad = srv.submit(rep)
             with pytest.raises(RuntimeError, match="injected"):
                 bad.result(timeout=300)
             assert srv.cache.stats()["used_blocks"] == 0
-            p = rs.randint(1, cfg.vocab_size, (4,)).astype(np.int32)
-            ref = model.generate(p[None], 4).numpy()[0]
-            np.testing.assert_array_equal(
-                srv.submit(p).result(timeout=300), ref)
+            # dense against paged: equal up to a near-tie of the reference
+            p = clear_prompt(model,
+                             uniform_prompts(rs, cfg.vocab_size, 4), 4)
+            compare_workload(
+                model, [model.generate(p[None], 4).numpy()[0]],
+                [srv.submit(p).result(timeout=300)], [p])
         finally:
             srv.stop()
 
