@@ -829,6 +829,21 @@ class PagedGenerationServer:
     logarithmic in the packed token budget rather than per
     prompt-length bucket.
 
+    ONE DISPATCH IN FLIGHT (the default loop): decode step N+1 is
+    queued on the device before step N is read back, its continuing
+    rows taking their input token from step N's result on the device
+    (`decode_step`'s `prev`), and a prefill is queued behind the step
+    in flight and read after the next one is queued — the device holds
+    its next program while the host reads, emits, admits and plans.
+    A finish by length costs nothing; a stop only the read reveals
+    costs one row of the step already queued (dropped, counted as
+    replay). Where the host must be authoritative (stop, host ops,
+    swap-out, timeouts, a failed dispatch, idle) the engine reads what
+    is in flight first; an engine with a drafter or
+    steps_per_dispatch > 1 reads every dispatch where it issues it.
+    Tokens are those of the synchronous order. `_round_split`,
+    docs/SERVING.md "The round's order", stats()["dispatch_ahead"].
+
     prefill_chunk_tokens: max REAL prompt tokens per packed prefill
         dispatch (default 512). Smaller bounds decode ITL tighter
         during bursts; larger finishes prefills (TTFT) sooner.
@@ -1384,6 +1399,18 @@ class PagedGenerationServer:
         self._pending = None
         self._carry = None
         self._zero_carry = None
+        # one dispatch in flight (the default loop; `_pending` is the
+        # decode step issued and not yet read): decode dispatches
+        # issued, those issued while the one before was unread, the
+        # reads with nothing queued behind them by the seam that asked
+        # (both loops that keep one in flight), the rows a finish left
+        # in a dispatch already queued; and the instant up to which
+        # dispatch time is charged to the residents (`_charge_read`)
+        self._decode_issued = 0
+        self._decode_ahead = 0
+        self._drains: dict[str, int] = {}
+        self._late_rows = 0
+        self._charged_to = 0.0
         # steady-state device-argument reuse (async window rounds): the
         # whole plan argument set is round-invariant per (slots, seqs,
         # drafts) signature — caching the uploaded arrays is most of
@@ -1810,12 +1837,14 @@ class PagedGenerationServer:
                 led.charge_wire(delta, parts, kind="collective")
 
     def _attr_begin(self, parts):
-        """Note the dispatch about to run (compile-charge target) and
-        the decoder's wire-byte level before it."""
+        """Note the dispatch about to run (compile-charge target) and,
+        once, the decoder's wire-byte level: from there on every byte
+        is charged at the next read (`_charge_dispatch` moves the
+        level), also those of a dispatch issued before that read."""
         if self._ledger is None:
             return
         self._attr_parts = parts
-        if self._decoder.tp_degree > 1:
+        if self._decoder.tp_degree > 1 and self._wire_mark is None:
             self._wire_mark = self._decoder.wire_stats()["bytes_total"]
 
     @staticmethod
@@ -2168,7 +2197,7 @@ class PagedGenerationServer:
         # async: resolve the round already in flight FIRST, so the
         # resume snapshots include its tokens (it dispatched before
         # the failure and its outputs are real)
-        self._drain_pending()
+        self._drain_pending("failure")
         with self._lock:
             self._dispatch_retries += 1
             self._consec_failures += 1
@@ -2291,7 +2320,7 @@ class PagedGenerationServer:
         for r in expired:
             self._fail_timeout_req(r, now)
         if any(s is not None and dead(s["req"]) for s in self._slots):
-            self._drain_pending()  # async: host state goes authoritative
+            self._drain_pending("timeout")  # host state authoritative
             for i, s in enumerate(self._slots):
                 if s is None or not dead(s["req"]):
                     continue
@@ -2827,8 +2856,12 @@ class PagedGenerationServer:
         for the n tokens from `position` on (a preempted request's
         positions come again), `state_slot` the slot of the recurrent-
         state store the sequence holds (0 without one); the slot keeps
-        the sequence's last state until another sequence takes it.  As
-        fast and as harmless as `on_token` must be.
+        the sequence's last state until another sequence takes it (one
+        step past it where a stop token or stop string ended the
+        request: the step queued behind the one that revealed the stop
+        carried its row).  Called when the dispatch is read back, a
+        round after it was issued, with the positions it was issued
+        with.  As fast and as harmless as `on_token` must be.
 
         When the server was built with `shed_queue_depth=`, a submit
         arriving at a queue already that deep raises `AdmissionShed`
@@ -2928,9 +2961,12 @@ class PagedGenerationServer:
             lane=meta.lane if meta is not None else None,
             tenant=meta.tenant if meta is not None else None,
             **self._tr(req))
+        # stamped with the instant the request's own clocks start from
+        # (TTFT, latency): the engine thread may hold the lock above for
+        # a round, and a first token must not precede its submit
         _tracing.event("request_submitted", request_id=req.rid,
-                       prompt_len=int(ids.size), budget=budget,
-                       **self._tr(req))
+                       ts=req.t_submit, prompt_len=int(ids.size),
+                       budget=budget, **self._tr(req))
         return req.future
 
     def start(self):
@@ -3001,6 +3037,10 @@ class PagedGenerationServer:
             self._round_dispatch_count = 0
             self._mixed_rounds = 0
             self._overlap_s = 0.0
+            self._decode_issued = 0
+            self._decode_ahead = 0
+            self._drains = {}
+            self._late_rows = 0
             self._phases.reset()
             self._compile_mark = _compile_tracker.mark()
             self._last_error = None  # a fresh window is healthy again
@@ -3176,6 +3216,22 @@ class PagedGenerationServer:
                 # costs per dispatch and where a round that stood
                 # still spent it; reset-coherent
                 "round_phases": self._phases.snapshot(),
+                # one dispatch in flight (the default loop): decode
+                # dispatches issued and how many of them were queued
+                # before the one before was read, the reads that had
+                # nothing queued behind them by the seam that asked,
+                # and the rows a late finish (a stop only the read
+                # revealed) left in a dispatch already queued —
+                # reset-coherent; a drafter or steps_per_dispatch > 1
+                # issues none ahead
+                "dispatch_ahead": {
+                    "decode_dispatches": self._decode_issued,
+                    "issued_ahead": self._decode_ahead,
+                    "ahead_share": (self._decode_ahead
+                                    / (self._decode_issued or 1)),
+                    "drains": dict(self._drains),
+                    "dropped_rows": self._late_rows,
+                },
                 # the expert layers' counters (zeros for a model with
                 # none), summed over the layers of every dispatch that
                 # routed a token; `dispatches` is the newest EXPERT_RING
@@ -3577,7 +3633,7 @@ class PagedGenerationServer:
         so the victim's token list and published K/V are
         authoritative (the drain may complete the victim's request —
         then there is nothing to evict and this returns None)."""
-        self._drain_pending()
+        self._drain_pending("preempt")
         if self._slots[i] is None:
             return None
         s = self._slots[i]
@@ -3621,8 +3677,8 @@ class PagedGenerationServer:
         if self._sched is not None:
             return self._admit_sched_locked()
         picked = []
-        for i, slot in enumerate(self._slots):
-            if slot is not None or not self._queue:
+        for i in range(self.max_slots):
+            if not self._slot_free(i) or not self._queue:
                 continue
             req = self._queue[0]
             worst = self._worst_blocks(req)
@@ -3655,8 +3711,8 @@ class PagedGenerationServer:
             if req is None:
                 break
             worst = self._worst_blocks(req)
-            free_i = next((i for i, s in enumerate(self._slots)
-                           if s is None), None)
+            free_i = next((i for i in range(self.max_slots)
+                           if self._slot_free(i)), None)
 
             def short():
                 return (self.cache.available_block_count
@@ -3674,8 +3730,8 @@ class PagedGenerationServer:
                     victim = self._preempt_slot_locked(j)
                     if victim is not None:
                         self._sched.requeue(victim, now)
-                    free_i = next((i for i, s in enumerate(self._slots)
-                                   if s is None), None)
+                    free_i = next((i for i in range(self.max_slots)
+                                   if self._slot_free(i)), None)
                     if free_i is not None and not short():
                         break
                 if free_i is None or short():
@@ -3695,7 +3751,11 @@ class PagedGenerationServer:
         of two), bulk-grow the chunk's block tables, and run the
         packed_prefill program — K/V lands directly in each sequence's
         paged blocks. Slots whose FINAL chunk is in this dispatch
-        sample their first token here (that is their TTFT)."""
+        sample their first token here (that is their TTFT). The
+        dispatch is ISSUED here, behind whatever decode step is in
+        flight (the donated pool orders them on the device), and read
+        by `_read_prefill`: returns what that needs, or None when there
+        was nothing to feed or the dispatch failed."""
         with self._phase("plan"):
             jnp = self._jnp
             align = self._pack_align
@@ -3839,35 +3899,55 @@ class PagedGenerationServer:
                             jnp.asarray(sample_idx), self.cache.k_blocks,
                             self.cache.v_blocks, sp_args, sp_mode,
                             state=self.cache.state)
+                    # the pool, the store and the sampler's counts chain
+                    # from program to program on the device: the next
+                    # dispatch takes these, read or not
                     self._sp_store.swap_counts(counts)
-                with self._phase("read_back"):
-                    tok_h = np.asarray(tok)
-                    stopped_h = np.asarray(stopped)
-                    routed = self._read_routed(routed)
+                    self._swap_cache(kc, vc)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-the-chunk path)
             self._dispatch_failure("prefill", e,
                                    [i for i, *_ in plan])
+            return None
+        return {"where": "prefill", "t0": t0, "parts": parts,
+                "rows": {i: self._slots[i]["seq"] for i, *_ in plan},
+                "plan": plan, "done_rows": done_rows, "decoding": decoding,
+                "out": (tok, stopped, routed)}
+
+    def _read_prefill(self, rec):
+        """Read one packed prefill dispatch back and emit its first
+        tokens: the rows whose prompt it completed join the NEXT decode
+        step. The rows of a slot that no longer holds the planned
+        sequence are dropped."""
+        try:
+            with self._phase("read_back"):
+                tok, stopped, routed = rec["out"]
+                tok_h = np.asarray(tok)
+                stopped_h = np.asarray(stopped)
+                routed = self._read_routed(routed)
+        except Exception as e:  # noqa: BLE001 — the recovery ladder
+            self._read_failure(rec, e)
             return
         with self._phase("emit"):
-            self._swap_cache(kc, vc)
+            plan = [p for p in rec["plan"]
+                    if self._holds(p[0], rec["rows"][p[0]])]
             if routed is not None:
                 self._note_routed(routed["counts"])
                 self._tell_routing([(i, start, o, n)
                                     for i, start, n, o in plan], routed)
             self._dispatch_ok([self._slots[i]["req"].rid
-                               for i, *_ in plan
-                               if self._slots[i] is not None])
+                               for i, *_ in plan])
             t_now = time.perf_counter()
-            self._charge_dispatch(t_now - t0, parts)
+            t0 = rec["t0"]
+            self._charge_read(rec, t_now)
             if self._ledger is not None:
                 # feed the measured prefill unit cost (EMA) — the rate the
                 # prefix-cache savings credit is priced at
                 self._ledger.note_prefill_cost(
                     int((t_now - t0) * 1e9),
-                    int(sum(p[2] for p in plan)))
+                    int(sum(p[2] for p in rec["plan"])))
             self._ops_progress += 1
-            if decoding:
+            if rec["decoding"]:
                 _m_decode_stall.observe(t_now - t0)
             _m_prefill_dispatches.inc()
             # goodput: a resumed request's chunk re-feeds already-generated
@@ -3890,7 +3970,9 @@ class PagedGenerationServer:
                 s = self._slots[i]
                 s["fed"] = start + n
                 s["chunks"] += 1
-            for i, r in done_rows:
+            for i, r in rec["done_rows"]:
+                if not self._holds(i, rec["rows"][i]):
+                    continue
                 s = self._slots[i]
                 req = s["req"]
                 if req.ttft is None:
@@ -3937,6 +4019,26 @@ class PagedGenerationServer:
                 s["t_last"] = t_now
                 self._slot_token(i, int(tok_h[r]),
                                  device_stopped=bool(stopped_h[r]))
+
+    def _read_failure(self, rec, e):
+        """A dispatch failed where it was read: what was queued behind
+        it took its pool and its tokens, so that goes with it, unread,
+        and the recovery ladder takes the rows of both."""
+        behind, self._pending = self._pending, None
+        rows = dict(behind["rows"]) if behind is not None else {}
+        rows.update(rec["rows"])
+        self._dispatch_failure(
+            rec["where"], e,
+            [i for i, seq in rows.items() if self._holds(i, seq)])
+
+    def _charge_read(self, rec, t_now):
+        """Charge a dispatch's residents the wall time from its issue
+        to this read, less what the dispatch read before it was
+        charged already: with one in flight the intervals overlap, and
+        a second of the device is billed once."""
+        self._charge_dispatch(
+            t_now - max(rec["t0"], self._charged_to), rec["parts"])
+        self._charged_to = t_now
 
     def _slot_token(self, i, tok, device_stopped=False):
         """Record one generated token for slot i; completes the request
@@ -4049,7 +4151,7 @@ class PagedGenerationServer:
                 if self._stop:
                     # async: resolve the in-flight round so no future
                     # is stranded mid-stream
-                    self._drain_pending()
+                    self._drain_pending("stop")
                     self._fail_host_ops_locked(
                         RuntimeError("server stopped"))
                     return
@@ -4058,13 +4160,17 @@ class PagedGenerationServer:
                     # exports/imports on THIS thread at the round
                     # boundary — the in-flight round is drained first
                     # so its write-back cannot overwrite an import
-                    self._drain_pending()
+                    self._drain_pending("host_op")
                     self._run_host_ops_locked()
                 if self._any_timeouts:
                     self._expire_timeouts_locked(time.perf_counter())
                 self._admit_locked()
                 if all(s is None for s in self._slots):
-                    self._drain_pending()  # defensive: no-op when idle
+                    if self._pending is not None:
+                        # only rows of requests that finished are left in
+                        # it: read it, and admit into the slots it held
+                        self._drain_pending("idle")
+                        continue
                     with self._phase("idle_wait"):
                         self._lock.wait(timeout=0.1)
                     continue
@@ -4092,11 +4198,19 @@ class PagedGenerationServer:
             self._maybe_sample_capacity()
 
     def _round_split(self):
-        """One scheduler round of the SPLIT path (the pre-r16 loop
-        body): at most one packed chunk-prefill dispatch, then one
-        verify and/or one plain decode dispatch."""
+        """One scheduler round of the SPLIT path (the default loop): at
+        most one packed chunk-prefill dispatch, then one verify and/or
+        one plain decode dispatch, with ONE DISPATCH KEPT IN FLIGHT.
+        The decode step of this round is queued on the device before
+        the step of the last round is read back, and the prefill's
+        first tokens are read after that, so the device holds its next
+        program while the host reads, emits, admits and plans
+        (docs/SERVING.md "The round's order"). An engine that needs the
+        host's tokens before it can dispatch again (a drafter, a scan
+        of k steps) reads what it issues at once: `_keeps_in_flight`."""
         d0 = (self._prefill_dispatches + self._steps
               + self._spec_dispatches)
+        flying = self._pending
         # ---- packed/chunked prefill: at most ONE chunk dispatch
         # per round, interleaved with the decode dispatch below, so
         # in-flight decode never stalls longer than one chunk budget
@@ -4104,12 +4218,16 @@ class PagedGenerationServer:
             pre_idx = [i for i, s in enumerate(self._slots)
                        if s is not None
                        and s["fed"] < s["prompt"].size]
-        if pre_idx:
-            self._prefill_packed(pre_idx)
+        pre = self._prefill_packed(pre_idx) if pre_idx else None
+        if pre is not None and not self._keeps_in_flight:
+            self._read_prefill(pre)
+            pre = None
         with self._phase("plan"):
             _m_slots_busy.labels(server="paged").set(
                 sum(s is not None for s in self._slots))
-            # decode phase: prompt fully fed (first token sampled)
+            # decode phase: prompt fully fed (first token sampled). A
+            # slot whose last chunk is in flight is not there yet: its
+            # rows join the decode one step later
             active_idx = [i for i, s in enumerate(self._slots)
                           if s is not None
                           and s["fed"] >= s["prompt"].size]
@@ -4127,6 +4245,11 @@ class PagedGenerationServer:
                          and self._slots[i] is not None]
             if plain_idx:
                 self._decode_plain(plain_idx)
+        if flying is not None and self._pending is flying:
+            # the step in flight got no successor (its rows end with it)
+            self._drain_pending("no_successor")
+        if pre is not None:
+            self._read_prefill(pre)
         # tier prefetch-ahead: promote the NEXT queued requests' cold
         # blocks now, before the coming round boundary's admission
         # pass runs attach_prefix (one `look` check when disabled)
@@ -4137,6 +4260,30 @@ class PagedGenerationServer:
         if d1 > d0:
             self._note_round(d1 - d0,
                              mixed=bool(pre_idx) and bool(active_idx))
+
+    @property
+    def _keeps_in_flight(self):
+        """Whether the default loop may queue a dispatch before it has
+        read the last one: not with a drafter (a draft is made from the
+        host's tokens) and not with a scan of k steps (its successor's
+        positions wait for how many of the k were kept)."""
+        return self._drafter is None and self.steps_per_dispatch == 1
+
+    def _holds(self, i, seq):
+        """Whether slot `i` still holds the sequence a dispatch was
+        issued for (a finish, a timeout or a failure may have taken it
+        before the dispatch is read)."""
+        s = self._slots[i]
+        return s is not None and s["seq"] == seq
+
+    def _slot_free(self, i):
+        """Whether slot `i` can take a request: empty, and in no
+        dispatch still in flight (a row that finished by a stop only
+        the read revealed keeps its slot until its last dispatch is
+        read)."""
+        return self._slots[i] is None and (
+            self._pending is None or self._unified
+            or i not in self._pending["rows"])
 
     # ---- one-kernel round (r16) -----------------------------------------
 
@@ -4181,15 +4328,23 @@ class PagedGenerationServer:
         else:
             self._carry = None  # chain broken: reseed from host state
 
-    def _drain_pending(self):
-        """Async mode: resolve the in-flight round NOW so host state is
-        authoritative (preemption swap-out, engine stop, idle). Breaks
-        the device chain — the carry reseeds from host state at the
-        next plan. No-op when nothing is in flight."""
+    def _drain_pending(self, why):
+        """Resolve the dispatch in flight NOW so host state is
+        authoritative (preemption swap-out, host ops, timeouts, a
+        failed dispatch, engine stop, idle); `why` names the seam, for
+        `stats()["dispatch_ahead"]["drains"]`. The async unified loop
+        breaks its device chain here (the carry reseeds from host state
+        at the next plan). No-op when nothing is in flight."""
         pending, self._pending = self._pending, None
-        if pending is not None:
+        if pending is None:
+            return
+        with self._lock:
+            self._drains[why] = self._drains.get(why, 0) + 1
+        if self._unified:
             self._carry = None
             self._process_round(*pending)
+        else:
+            self._read_decode(pending)
 
     def _seed_carry(self):
         """(Re)build the slot-indexed device carry from host state —
@@ -4644,7 +4799,7 @@ class PagedGenerationServer:
             for r, row in enumerate(plan["rows"]):
                 i = row["slot"]
                 s = self._slots[i]
-                live = s is not None and s["seq"] == row["seq"]
+                live = self._holds(i, row["seq"])
                 if row["kind"] == "chunk":
                     if not row["done"]:
                         continue
@@ -4757,22 +4912,55 @@ class PagedGenerationServer:
 
     def _decode_plain(self, active_idx):
         """One plain decode dispatch (k tokens per slot with multi-step
-        scheduling) for the given decode-phase slots — the pre-round-11
-        decode body, extracted so the scheduler can interleave it with
-        the speculative verify dispatch."""
+        scheduling) for the given decode-phase slots, ISSUED BEFORE THE
+        ONE IN FLIGHT IS READ: a row that is in the step in flight
+        takes its input token from that step's result on the device
+        (`decode_step`'s `prev`), its position and PRNG step counter
+        one further than the host's, and is left out if its budget ends
+        with the step in flight. Then the step in flight is read and
+        emitted, and the new one stays in flight for the next round
+        (`_keeps_in_flight`; else it is read at once)."""
+        rec = self._issue_decode(active_idx)
+        if rec is None:
+            return   # the round reads what is in flight (`_round_split`)
+        flying, self._pending = self._pending, rec
+        if flying is not None:
+            self._read_decode(flying)
+        if not self._keeps_in_flight:
+            self._pending = None
+            self._read_decode(rec)
+
+    def _issue_decode(self, active_idx):
+        """Plan and dispatch one decode step; returns what
+        `_read_decode` needs, or None when no row goes on or the
+        dispatch failed (the recovery ladder has then read the step in
+        flight)."""
         with self._phase("plan"):
             jnp = self._jnp
             k = self.steps_per_dispatch
+            flying = self._pending
             tok = np.zeros((self.max_slots,), np.int32)
             pos = np.zeros((self.max_slots,), np.int32)
             act = np.zeros((self.max_slots,), bool)
             steps = np.zeros((self.max_slots,), np.int32)
+            rows = {}
             for i in active_idx:
                 s = self._slots[i]
-                tok[i] = s["toks"][-1]
-                pos[i] = s["pos"] + len(s["toks"]) - 1
+                n = len(s["toks"])
+                if flying is not None and flying["rows"].get(i) == s["seq"]:
+                    # one token of this row is on the device, unread
+                    n += 1
+                    if n >= s["budget"]:
+                        continue   # it ends with the step in flight
+                    tok[i] = -1    # decode_step takes `prev` there
+                else:
+                    tok[i] = s["toks"][-1]
+                pos[i] = s["pos"] + n - 1
                 act[i] = True
-                steps[i] = len(s["toks"])  # PRNG step counter
+                steps[i] = n  # PRNG step counter
+                rows[i] = s["seq"]
+            if not rows:
+                return None
             # per-slot sampling buffers + the static dispatch mode: ONE
             # jitted dispatch serves the whole mixed batch; all-greedy
             # residents take the argmax fast path
@@ -4788,19 +4976,18 @@ class PagedGenerationServer:
                     self._fastpath_dispatches += 1
             if self._recorder.enabled:
                 self._recorder.record(
-                    "decode_dispatch", slots=len(active_idx), k=k,
+                    "decode_dispatch", slots=len(rows), k=k,
                     sampled=bool(sp_mode[0]),
                     free_blocks=self.cache.available_block_count)
             parts = self._cost_parts(
-                [(self._slots[i]["req"], k) for i in active_idx])
+                [(self._slots[i]["req"], k) for i in rows])
             self._attr_begin(parts)
         self._phases.kind("decode")
         t0 = time.perf_counter()
         try:
             with _tracing.span(
                     "decode_dispatch", k=k, round=self._phases.round,
-                    request_ids=[self._slots[i]["req"].rid
-                                 for i in active_idx]
+                    request_ids=[self._slots[i]["req"].rid for i in rows]
                     if _tracing.enabled() else (), **self._rattr()):
                 with self._phase("plan"):
                     self._maybe_fault("slow_dispatch")
@@ -4811,9 +4998,7 @@ class PagedGenerationServer:
                     # the recovery path instead of killing the engine
                     # thread
                     self.cache.ensure_many(
-                        [(self._slots[i]["seq"], self._slots[i]["pos"]
-                          + len(self._slots[i]["toks"]) - 1 + k)
-                         for i in active_idx])
+                        [(seq, int(pos[i]) + k) for i, seq in rows.items()])
                     tables = self.cache.table_array(
                         [s["seq"] if s is not None else None
                          for s in self._slots], self._m_width)
@@ -4828,7 +5013,9 @@ class PagedGenerationServer:
                                 jnp.asarray(pos), jnp.asarray(act),
                                 tables, self.cache.k_blocks,
                                 self.cache.v_blocks, sp_args, sp_mode,
-                                state=self.cache.state)
+                                state=self.cache.state,
+                                prev=None if flying is None
+                                else flying["out"][0])
                     else:
                         toks, stopped, kc, vc, counts = \
                             self._decoder.multistep(k, sp_mode)(
@@ -4836,38 +5023,61 @@ class PagedGenerationServer:
                                 jnp.asarray(pos), jnp.asarray(act),
                                 tables, self.cache.k_blocks,
                                 self.cache.v_blocks, sp_args)
-                with self._phase("read_back"):
-                    toks = np.asarray(toks)        # [S], or [k, S]
-                    stops = np.asarray(stopped)
-                    routed = self._read_routed(routed)
-                    if k == 1:
-                        toks, stops = toks[None], stops[None]  # [1, S]
+                    # the pool, the store and the sampler's counts chain
+                    # from program to program on the device: the next
+                    # dispatch takes these, read or not
+                    self._sp_store.swap_counts(counts)
+                    self._swap_cache(kc, vc)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
-            self._dispatch_failure("decode", e, list(active_idx))
+            self._dispatch_failure("decode", e, list(rows))
+            return None
+        with self._lock:
+            self._decode_issued += 1
+            if flying is not None:
+                self._decode_ahead += 1
+        self._ops_progress += 1
+        return {"where": "decode", "t0": t0, "parts": parts, "rows": rows,
+                "pos": pos, "out": (toks, stopped, routed)}
+
+    def _read_decode(self, rec):
+        """Read one decode dispatch back and emit its tokens. A row
+        whose slot no longer holds the sequence it was issued for (a
+        stop token, a stop string or a timeout that only the read of
+        the step before revealed) is dropped, and counted as replay."""
+        try:
+            with self._phase("read_back"):
+                toks, stopped, routed = rec["out"]
+                toks = np.asarray(toks)        # [S], or [k, S]
+                stops = np.asarray(stopped)
+                routed = self._read_routed(routed)
+                if toks.ndim == 1:
+                    toks, stops = toks[None], stops[None]  # [1, S]
+        except Exception as e:  # noqa: BLE001 — the recovery ladder
+            self._read_failure(rec, e)
             return
         with self._phase("emit"):
-            self._sp_store.swap_counts(counts)
-            self._swap_cache(kc, vc)
+            rows = rec["rows"]
+            live = [i for i, seq in rows.items() if self._holds(i, seq)]
             if routed is not None:
                 self._note_routed(routed["counts"])
-                self._tell_routing([(i, pos[i], i, 1)
-                                    for i in active_idx], routed)
-            self._dispatch_ok([self._slots[i]["req"].rid
-                               for i in active_idx
-                               if self._slots[i] is not None])
+                self._tell_routing([(i, rec["pos"][i], i, 1)
+                                    for i in live], routed)
+            self._dispatch_ok([self._slots[i]["req"].rid for i in live])
             t_now = time.perf_counter()
-            self._charge_dispatch(t_now - t0, parts)
+            self._charge_read(rec, t_now)
             self._ops_progress += 1
-            decoded = toks.shape[0] * len(active_idx)
-            discarded = 0
+            decoded = toks.shape[0] * len(rows)
+            late = len(rows) - len(live)
+            discarded = toks.shape[0] * late
             with self._lock:
                 self._steps += 1
-                self._active_integral += len(active_idx)
+                self._active_integral += len(rows)
                 self._fill_integral += self.cache.block_fill()
                 self._decoded_tokens += decoded
+                self._late_rows += late
             _m_decoded.inc(decoded)
-            for i in active_idx:
+            for i in live:
                 s = self._slots[i]
                 t_prev = s["t_last"] if s["t_last"] is not None else t_now
                 consumed = 0

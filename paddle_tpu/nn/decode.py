@@ -359,13 +359,18 @@ def _build_paged_fns(spec, block_size, return_logits, mode,
             return tok, stopped, kc, vc, counts, logits
         return tok, stopped, kc, vc, counts
 
-    def step_fn(params, tok, pos, active, tables, kc, vc, sp):
+    def step_fn(params, tok, pos, active, tables, kc, vc, sp, prev=None):
         """One decode token per sequence. tok [B] is written at cache
         position pos [B]; attention sees positions [0, pos]. Idle slots
-        (active False) write to trash and emit token 0."""
+        (active False) write to trash and emit token 0. A row whose
+        tok is negative goes on from `prev` [B], the tokens the step
+        before this one returned, still on the device: the engine
+        queues a step before it has read the last one back."""
         from ..ops.attention import paged_decode_attention
 
         B = tok.shape[0]
+        if prev is not None:
+            tok = jnp.where(tok < 0, prev, tok)
         dt = params["ln_f.weight"].dtype
         embed, head = make_embed_head(params, dt)
         x = embed(tok) + params["wpe.weight"][pos]     # [B, E]
@@ -1029,7 +1034,7 @@ def _sharded_jits(spec, block_size, return_logits, donate, mode,
         prefill_fn, in_shardings=(pr, rep, rep, rep, kv, kv, rep),
         out_shardings=out5, donate_argnums=(4, 5) if donate else ())
     step = jax.jit(
-        step_fn, in_shardings=(pr, rep, rep, rep, rep, kv, kv, rep),
+        step_fn, in_shardings=(pr, rep, rep, rep, rep, kv, kv, rep, rep),
         out_shardings=out5, donate_argnums=(5, 6) if donate else ())
     packed = jax.jit(
         packed_fn,
@@ -1228,6 +1233,7 @@ class PagedDecoder:
             self._tp = int(dict(shardings.mesh.shape).get("mp", 1))
         self._variants = {}
         self._msteps = {}
+        self._prev0 = {}   # rows -> the zeros `step` takes for no `prev`
 
     @property
     def tp_degree(self):
@@ -1410,15 +1416,34 @@ class PagedDecoder:
                                       sp)
 
     def step(self, params, tok, pos, active, tables, kc, vc, sp,
-             mode=GREEDY_MODE, state=None):
+             mode=GREEDY_MODE, state=None, prev=None):
         """One decode token a row.  GPT-2: (token, stopped, kc, vc,
         counts[, logits]).  A `DecoderDescription` takes vc=None and its
         cache's store as `state`, and returns (token, stopped, kc, state,
-        counts, routed[, logits]) (`decode_blocks`)."""
+        counts, routed[, logits]) (`decode_blocks`).  `prev` is the
+        token result of the step before, for the rows whose `tok` is
+        negative (`step_fn`); None where no row is."""
         self._check_kv(kc, vc)
+        if prev is None:
+            prev = self._no_prev(tok.shape[0])
         return self._variant(mode)[1](
             params, tok, pos, active, tables, kc,
-            vc if self.description is None else state, sp)
+            vc if self.description is None else state, sp, prev)
+
+    def _no_prev(self, rows):
+        """The `prev` of a step that follows none: zeros, placed as a
+        step's own token result is, so that the first step of a chain
+        and the later ones are one executable."""
+        z = self._prev0.get(rows)
+        if z is None:
+            import jax
+            import jax.numpy as jnp
+
+            z = jnp.zeros((rows,), jnp.int32)
+            if self._shardings is not None:
+                z = jax.device_put(z, self._shardings.rep)
+            self._prev0[rows] = z
+        return z
 
     def packed_prefill(self, params, toks, seg, pos, tables, sample_idx,
                        kc, vc, sp, mode=GREEDY_MODE, state=None):

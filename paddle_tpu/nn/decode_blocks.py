@@ -304,9 +304,9 @@ def _block_fns(desc):
 def build_block_programs(desc, block_size, return_logits, mode):
     """(packed_prefill_fn, step_fn) for a description, raw and jittable:
     `nn.decode`'s own signatures (see `_build_packed_prefill` and
-    `_build_paged_fns`) with the store `state` where GPT-2's take the V
-    pool; kc, state, a table row and `routed` are as this module's
-    docstring says.  They return (token, stopped, kc, state, counts,
+    `_build_paged_fns`, `prev` of its step included) with the store
+    `state` where GPT-2's take the V pool; kc, state, a table row and
+    `routed` are as this module's docstring says.  They return (token, stopped, kc, state, counts,
     routed), and the logits after that with `return_logits`."""
     import jax
     import jax.numpy as jnp
@@ -371,8 +371,10 @@ def build_block_programs(desc, block_size, return_logits, mode):
             return tok, stopped, kc, state, counts, routed, logits
         return tok, stopped, kc, state, counts, routed
 
-    def step_fn(params, tok, pos, active, tables, kc, state, sp):
+    def step_fn(params, tok, pos, active, tables, kc, state, sp, prev=None):
         B = tok.shape[0]
+        if prev is not None:   # as `nn.decode`'s step: a negative tok
+            tok = jnp.where(tok < 0, prev, tok)   # goes on from `prev`
         btab = tables[:, 1:]
         ctx = {
             "valid": active, "btab": btab,

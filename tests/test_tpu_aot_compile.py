@@ -276,9 +276,11 @@ def _compile_serving_program(one_chip, monkeypatch, program, quant,
     if program == "step":
         _, fn = decode._build_paged_fns(spec, BS, False, (False, False),
                                         quant)
+        # with `prev`, as the engine dispatches it (PR 29): a row whose
+        # token is negative goes on from the step before's result
         args = (params, i32(32), i32(32),
                 jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip),
-                i32(32, M), pool, pool, {"stop": i32(32, 1)})
+                i32(32, M), pool, pool, {"stop": i32(32, 1)}, i32(32))
         donate = (5, 6)
     else:
         fn = decode._build_packed_prefill(spec, BS, False, (False, False),
@@ -424,7 +426,8 @@ def test_stateful_decode_step_works_on_both_caches_in_place(one_chip,
         compiled = jax.jit(step, donate_argnums=(5, 6)).lower(
             params, s((rows,), jnp.int32), s((rows,), jnp.int32),
             s((rows,), jnp.bool_), s((rows, 1 + width), jnp.int32), pool,
-            store, {"stop": s((rows, 1), jnp.int32)}).compile()
+            store, {"stop": s((rows, 1), jnp.int32)},
+            s((rows,), jnp.int32)).compile()   # `prev`, as the engine's
     text = compiled.as_text()
     names = re.findall(r"%(kda_decode|mla_decode|moe_gmm)[.\d]* = ", text)
     assert {n: names.count(n) for n in set(names)} == {
