@@ -290,13 +290,17 @@ class PagedKVCache:
         attached below the pool — cold retained blocks demote to host
         RAM instead of being dropped, and prefix matches promote them
         back. None (default) keeps the pre-tier behaviour exactly.
-    state_layout, state_slots: a recurrent-state store beside the pool
+    state_layout, state_slots: a slot-indexed store beside the pool
         (`nn.decode_blocks.DecoderDescription.cache_layout()`; build
-        with `for_description`).  The pool is then ONE array of rows
-        `num_heads * head_dim` wide (`k_blocks`: latents, not heads;
-        `v_blocks` is None) under the same allocator and block tables,
-        and `state` is the store: {"S": [L, slots + 1, H, D, D] float32,
-        "conv": [L, slots + 1, K-1, C]} indexed by a slot that a
+        with `for_description`).  The pool's rows are `num_heads *
+        head_dim` wide under the same allocator and block tables: ONE
+        array of latent rows (`k_blocks`; `v_blocks` is None), or K rows
+        and V rows of `num_heads` K/V heads where the layout says
+        `values` (fewer than the model's query heads, where groups of
+        them share one).  `state` is the store, {name: [layers, slots +
+        1, *shape]} as the layout's `store` lists them (a recurrent
+        state "S" in float32 and conv tails for KDA layers; conv and
+        value tails alone for CCA layers), indexed by a slot that a
         sequence takes with its first block and gives back in `free`
         (slot 0 is the trash slot).  A slot is not zeroed on the device
         when it changes hands: a program that starts a sequence at
@@ -349,17 +353,18 @@ class PagedKVCache:
             self.k_blocks = jnp.zeros(shape, dt)
             self.v_blocks = jnp.zeros(shape, dt)
         else:
-            # one pool of rows (a latent pool: `num_heads * head_dim` is
-            # the row's width, there is no V) and, beside it, the
-            # slot-indexed store of what is not keys and values
-            st = (state_layout["state_layers"], int(state_slots) + 1)
+            # a pool of rows (latents: `num_heads * head_dim` is the
+            # row's width and there is no V; or K rows and V rows) and,
+            # beside it, the slot-indexed store of what is not keys and
+            # values
             self.k_blocks = jnp.zeros(shape, dt)
-            self.v_blocks = None
+            self.v_blocks = jnp.zeros(shape, dt) \
+                if state_layout["values"] else None
             self.state = {
-                "S": jnp.zeros(st + tuple(state_layout["state_shape"]),
-                               jnp.float32),
-                "conv": jnp.zeros(st + tuple(state_layout["conv_shape"]),
-                                  dt)}
+                name: jnp.zeros((layers, int(state_slots) + 1)
+                                + tuple(slot_shape), dtype or dt)
+                for name, (layers, slot_shape, dtype)
+                in state_layout["store"].items()}
         if state_layout is None:
             self.state = None
         # the recurrent-state store's slots (slot 0 reserved: trash), each
@@ -422,9 +427,12 @@ class PagedKVCache:
     def for_description(cls, desc, *, block_size, num_blocks, dtype,
                         max_slots):
         """The cache a `nn.decode_blocks.DecoderDescription` needs: a
-        latent pool with a state store of `max_slots` slots."""
+        latent pool, or a K/V pool by the description's K/V heads, with a
+        store of `max_slots` slots."""
         layout = desc.cache_layout()
-        return cls(layout["pool_layers"], 1, layout["row_width"],
+        heads = desc.cca.kv_heads if desc.values else 1
+        return cls(layout["pool_layers"], heads,
+                   layout["row_width"] // heads,
                    block_size=block_size, num_blocks=num_blocks,
                    dtype=dtype, state_layout=layout, state_slots=max_slots)
 
@@ -1462,6 +1470,13 @@ class PagedKVCache:
             "kv_dtype": self.stats_kv_dtype(),
             "pool_bytes_total": self.pool_bytes_total,
             "pool_bytes_per_token": self.bytes_per_token,
+            # the heads a pool row holds (K/V heads: fewer than the
+            # model's query heads where groups share one; 1 for a latent
+            # row) and the bytes a cached token takes over all layers,
+            # so that a reader counts K/V traffic without the model
+            "kv_heads": self.num_heads,
+            "bytes_per_token": self.pool_bytes_total
+            // (self.num_blocks * self.block_size),
             # device shards the pool arrays are placed over (1 =
             # unsharded); per-shard bytes are what one HBM must hold
             "shards": self._shard_count,

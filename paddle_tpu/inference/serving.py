@@ -1040,9 +1040,10 @@ class PagedGenerationServer:
 
         self._jnp, self._jax = jnp, jax
         cfg = model.cfg
-        # a model of latent and recurrent layers hands over its own
-        # description (`nn.decode_blocks`); a model without one is the
-        # GPT-2 layout its cfg spells out.  A description serves through
+        # a model of latent, recurrent or grouped-head convolutional
+        # layers hands over its own description (`nn.decode_blocks`); a
+        # model without one is the GPT-2 layout its cfg spells out.  A
+        # description serves through
         # the default loop alone, over dense device-resident caches:
         # every option that would need its state shared, rolled back,
         # moved, quantized or sharded is refused here, by name.
@@ -1065,8 +1066,9 @@ class PagedGenerationServer:
                 if value != ok:
                     raise ValueError(
                         f"PagedGenerationServer({name}={value!r}) has no "
-                        f"meaning yet beside a recurrent-state or latent "
-                        f"cache: this model serves through the default "
+                        f"meaning yet beside a slot-indexed store (recurrent "
+                        f"state, conv tails) or a latent or grouped-head "
+                        f"pool: this model serves through the default "
                         f"loop (packed_prefill + decode_step) with "
                         f"{name}={ok!r}")
         self.max_new = int(max_new_tokens)
@@ -1320,8 +1322,8 @@ class PagedGenerationServer:
         # gauge and, for ring/ulysses, every dispatch is asserted
         # under the chunk-length-independent flat bound
         self._sp_peak_bytes = 0
-        heads = self._desc.heads if self._desc is not None \
-            else cfg.num_heads
+        heads = cfg.num_heads if self._desc is None \
+            else self._desc.query_heads
         self._sp_bytes_kw = dict(
             sp=self._sp_degree,
             tp=(sharding.tp if sharding is not None else 1),
@@ -2713,7 +2715,7 @@ class PagedGenerationServer:
                     # count buffer is donated on accelerators, so a
                     # reused dict would hand back an invalidated array
                     sp = self._sp_store.warm_args(P, mode)
-                    _tok, _stopped, kc, vc, counts, *_routed = \
+                    _tok, _stopped, *rest = \
                         self._decoder.packed_prefill(
                             self._params, jnp.zeros((T,), jnp.int32),
                             jnp.zeros((T,), jnp.int32),
@@ -2725,8 +2727,7 @@ class PagedGenerationServer:
                             sp, mode, state=self.cache.state)
                     # reinstall the round-tripped arrays (donated on
                     # accelerators); only trash-block rows were written
-                    self._sp_store.swap_counts(counts)
-                    self._swap_cache(kc, vc)
+                    self._chain(rest)
                     n += 1
         _logger.info("warm_buckets: compiled %d packed-prefill "
                      "variants (%d shape pairs x %d widths x %d modes)",
@@ -3441,13 +3442,21 @@ class PagedGenerationServer:
             else 0.0,
         }
 
-    def _swap_cache(self, kc, second):
-        """Install what a program returned in the cache's place: GPT-2's
-        programs return (kc, vc), a description's (kc, state)."""
+    def _chain(self, rest):
+        """Install what a program returned after (token, stopped) for the
+        next dispatch, read or not: its caches in the cache's place
+        (GPT-2's programs return (kc, vc), a description's (kc, state) or,
+        with a pool of K and V rows, (kc, vc, state)) and the sampler's
+        counts; what is left, a description's `routed`, is returned."""
         if self._desc is None:
-            self.cache.swap_arrays(kc, second)
+            caches, rest = rest[:2], rest[2:]
+        elif self.cache.v_blocks is None:
+            caches, rest = (rest[0], None, rest[1]), rest[2:]
         else:
-            self.cache.swap_arrays(kc, None, second)
+            caches, rest = rest[:3], rest[3:]
+        self._sp_store.swap_counts(rest[0])
+        self.cache.swap_arrays(*caches)
+        return rest[1:]
 
     @staticmethod
     def _read_routed(rest):
@@ -3891,7 +3900,7 @@ class PagedGenerationServer:
                         [r in done_set for r in range(P)], base_steps)
                 with self._phase("dispatch"):
                     self._maybe_fault("prefill")
-                    tok, stopped, kc, vc, counts, *routed = \
+                    tok, stopped, *rest = \
                         self._decoder.packed_prefill(
                             self._params, jnp.asarray(toks),
                             jnp.asarray(seg), jnp.asarray(pos),
@@ -3900,10 +3909,8 @@ class PagedGenerationServer:
                             self.cache.v_blocks, sp_args, sp_mode,
                             state=self.cache.state)
                     # the pool, the store and the sampler's counts chain
-                    # from program to program on the device: the next
-                    # dispatch takes these, read or not
-                    self._sp_store.swap_counts(counts)
-                    self._swap_cache(kc, vc)
+                    # from program to program on the device
+                    routed = self._chain(rest)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-the-chunk path)
             self._dispatch_failure("prefill", e,
@@ -5005,9 +5012,8 @@ class PagedGenerationServer:
                 with self._phase("dispatch"):
                     self._maybe_fault("decode")
                     tables = jnp.asarray(tables)
-                    routed = ()
                     if k == 1:
-                        toks, stopped, kc, vc, counts, *routed = \
+                        toks, stopped, *rest = \
                             self._decoder.step(
                                 self._params, jnp.asarray(tok),
                                 jnp.asarray(pos), jnp.asarray(act),
@@ -5017,17 +5023,15 @@ class PagedGenerationServer:
                                 prev=None if flying is None
                                 else flying["out"][0])
                     else:
-                        toks, stopped, kc, vc, counts = \
+                        toks, stopped, *rest = \
                             self._decoder.multistep(k, sp_mode)(
                                 self._params, jnp.asarray(tok),
                                 jnp.asarray(pos), jnp.asarray(act),
                                 tables, self.cache.k_blocks,
                                 self.cache.v_blocks, sp_args)
                     # the pool, the store and the sampler's counts chain
-                    # from program to program on the device: the next
-                    # dispatch takes these, read or not
-                    self._sp_store.swap_counts(counts)
-                    self._swap_cache(kc, vc)
+                    # from program to program on the device
+                    routed = self._chain(rest)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
             self._dispatch_failure("decode", e, list(rows))

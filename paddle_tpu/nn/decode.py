@@ -968,16 +968,20 @@ def _jitted_paged_fns(spec, block_size, return_logits, donate, mode,
 @functools.lru_cache(maxsize=64)
 def _jitted_block_programs(desc, block_size, return_logits, donate, mode):
     """(packed_prefill, decode_step) jitted for a `DecoderDescription`
-    (`decode_blocks.build_block_programs`): the pool `kc` and the store
-    `state` donated, as GPT-2's pools are."""
+    (`decode_blocks.build_block_programs`): its caches ((kc, state), or
+    (kc, vc, state) where the pool holds K and V rows) donated, as
+    GPT-2's pools are."""
     import jax
 
     from .decode_blocks import build_block_programs
 
     packed_fn, step_fn = build_block_programs(desc, block_size,
                                               return_logits, mode)
-    return (jax.jit(packed_fn, donate_argnums=(6, 7) if donate else ()),
-            jax.jit(step_fn, donate_argnums=(5, 6) if donate else ()))
+    n = 3 if desc.values else 2
+    return (jax.jit(packed_fn, donate_argnums=tuple(range(6, 6 + n))
+                    if donate else ()),
+            jax.jit(step_fn, donate_argnums=tuple(range(5, 5 + n))
+                    if donate else ()))
 
 
 def _not_built(program, option):
@@ -1246,11 +1250,16 @@ class PagedDecoder:
         cache arrays must match the decoder's kv_dtype BEFORE any jit
         tracing, so a miswired server fails with the argument named."""
         if self.description is not None:
-            if vc is not None:
+            values = self.description.values
+            if vc is not None and not values:
                 raise ValueError(
                     "a latent pool has no V: PagedDecoder takes vc=None "
                     "and the store as state= for this description")
-            return   # a latent pool and a state store: dense by construction
+            if vc is None and values:
+                raise ValueError(
+                    "this description's pool holds K and V rows: "
+                    "PagedDecoder takes both, and the store as state=")
+            return   # pools and a state store: dense by construction
         for name, arr in (("kc", kc), ("vc", vc)):
             got = hasattr(arr, "codes")
             if got != self._kv_quant:
@@ -1418,17 +1427,26 @@ class PagedDecoder:
     def step(self, params, tok, pos, active, tables, kc, vc, sp,
              mode=GREEDY_MODE, state=None, prev=None):
         """One decode token a row.  GPT-2: (token, stopped, kc, vc,
-        counts[, logits]).  A `DecoderDescription` takes vc=None and its
-        cache's store as `state`, and returns (token, stopped, kc, state,
-        counts, routed[, logits]) (`decode_blocks`).  `prev` is the
+        counts[, logits]).  A `DecoderDescription` takes its cache's store
+        as `state` and, for a latent pool, vc=None, and returns (token,
+        stopped, kc, state, counts, routed[, logits]), with vc between kc
+        and state where its pool holds K and V rows (`decode_blocks`).
+        `prev` is the
         token result of the step before, for the rows whose `tok` is
         negative (`step_fn`); None where no row is."""
         self._check_kv(kc, vc)
         if prev is None:
             prev = self._no_prev(tok.shape[0])
         return self._variant(mode)[1](
-            params, tok, pos, active, tables, kc,
-            vc if self.description is None else state, sp, prev)
+            params, tok, pos, active, tables, *self._caches(kc, vc, state),
+            sp, prev)
+
+    def _caches(self, kc, vc, state):
+        """The cache arguments of a program: GPT-2's (kc, vc), a
+        description's (kc, state) or (kc, vc, state)."""
+        if self.description is None:
+            return kc, vc
+        return (kc, state) if vc is None else (kc, vc, state)
 
     def _no_prev(self, rows):
         """The `prev` of a step that follows none: zeros, placed as a
@@ -1451,8 +1469,8 @@ class PagedDecoder:
         `step`'s."""
         self._check_kv(kc, vc)
         return self._variant(mode)[2](
-            params, toks, seg, pos, tables, sample_idx, kc,
-            vc if self.description is None else state, sp)
+            params, toks, seg, pos, tables, sample_idx,
+            *self._caches(kc, vc, state), sp)
 
     def packed_verify(self, params, toks, seg, pos, tables, sample_idx,
                       dlen, kc, vc, sp, mode=GREEDY_MODE):

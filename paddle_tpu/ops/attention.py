@@ -35,21 +35,25 @@ def _manual_mesh(mesh):
 
 
 def paged_attention_path(head_dim, block_size, num_heads,
-                         total_tokens=None, mesh=None):
+                         total_tokens=None, mesh=None, kv_heads=None):
     """Which implementation the paged-pool attention ops take for these
     shapes on this backend: "pallas", "pallas/shard_map" (the kernel
     per device, heads split over the mesh's mp axis) or "xla" (the
-    gather path).  Chosen by the platform and the kernel's shape gate
-    alone — a lowering error in the chosen path raises, it never
-    selects another path."""
+    gather path).  `kv_heads` are the pool's K/V heads where whole groups
+    of the `num_heads` query heads share one (None: a K/V head a query
+    head).  Chosen by the platform and the kernel's shape gate alone — a
+    lowering error in the chosen path raises, it never selects another
+    path."""
     from .pallas.unified_attention import supported_shapes
 
     if not _on_tpu():
         return "xla"
+    kv_heads = num_heads if kv_heads is None else kv_heads
     mesh = _manual_mesh(mesh)
     mp = 1 if mesh is None else dict(mesh.shape).get("mp", 1)
-    if num_heads % mp or not supported_shapes(
-            head_dim, block_size, num_heads // mp, total_tokens):
+    if num_heads % mp or kv_heads % mp or not supported_shapes(
+            head_dim, block_size, num_heads // mp, total_tokens,
+            kv_heads // mp):
         return "xla"
     return "pallas" if mesh is None else "pallas/shard_map"
 
@@ -103,6 +107,21 @@ def _block_size(blocks, layer):
     """BS of a pool stack [L, N, BS, H*Dh] or (layer None) of one
     layer's pool [N, BS, H, Dh]."""
     return blocks.shape[1 if layer is None else 2]
+
+
+def _pool_heads(blocks, layer, head_dim):
+    """K/V heads of a pool stack [L, N, BS, Hkv*Dh] or (layer None) of
+    one layer's pool [N, BS, Hkv, Dh]: as many as the query heads, or
+    fewer, each shared by a whole group of them."""
+    return blocks.shape[2] if layer is None \
+        else blocks.shape[-1] // head_dim
+
+
+def _per_query_head(kv, heads, axis):
+    """K or V gathered with its Hkv heads on `axis`, each repeated for
+    the group of query heads that share it (as it is when Hkv = heads)."""
+    group = heads // kv.shape[axis]
+    return kv if group == 1 else jnp.repeat(kv, group, axis=axis)
 
 
 def _xla_attention(q, k, v, mask=None, scale=None, causal=False):
@@ -242,9 +261,10 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
     kcodes = k_blocks.codes if quant else k_blocks
     B, H, Dh = q.shape
     BS = _block_size(kcodes, layer)
+    Hkv = _pool_heads(kcodes, layer, Dh)
     M = block_tables.shape[1]
     sc = (Dh ** -0.5) if scale is None else scale
-    if paged_attention_path(Dh, BS, H, mesh=mesh) != "xla":
+    if paged_attention_path(Dh, BS, H, mesh=mesh, kv_heads=Hkv) != "xla":
         from .pallas.unified_attention import paged_decode_attention_kernel
         return _paged_kernel(paged_decode_attention_kernel, mesh, q,
                              k_blocks, v_blocks, layer, block_tables,
@@ -269,9 +289,10 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
         w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bhs,bhsd->bhd", w * vs.astype(q.dtype),
                           v.astype(q.dtype))
-    # XLA gather path: [B, M, BS, H*Dh] -> [B, H, M*BS, Dh]
-    k = gather(k_blocks).reshape(B, M * BS, H, Dh).transpose(0, 2, 1, 3)
-    v = gather(v_blocks).reshape(B, M * BS, H, Dh).transpose(0, 2, 1, 3)
+    # XLA gather path: [B, M, BS, Hkv*Dh] -> [B, H, M*BS, Dh]
+    k, v = (_per_query_head(
+        gather(blocks).reshape(B, M * BS, Hkv, Dh).transpose(0, 2, 1, 3),
+        H, 1) for blocks in (k_blocks, v_blocks))
     s = jnp.einsum("bhd,bhsd->bhs", q, k).astype(jnp.float32) * sc
     valid = jnp.arange(M * BS)[None, :] < ctx_lens[:, None]  # [B, M*BS]
     s = jnp.where(valid[:, None, :], s, -1e30)
@@ -332,9 +353,11 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
     kcodes = k_blocks.codes if quant else k_blocks
     T, H, Dh = q.shape
     BS = _block_size(kcodes, layer)
+    Hkv = _pool_heads(kcodes, layer, Dh)
     B, M = block_tables.shape
     sc = (Dh ** -0.5) if scale is None else scale
-    if allow_pallas and paged_attention_path(Dh, BS, H, T, mesh) != "xla":
+    if allow_pallas and paged_attention_path(Dh, BS, H, T, mesh,
+                                             Hkv) != "xla":
         from .pallas.unified_attention import (
             Q_TILE, unified_ragged_attention_kernel)
         return _paged_kernel(unified_ragged_attention_kernel, mesh, q,
@@ -352,10 +375,10 @@ def ragged_prefill_attention(q, k_blocks, v_blocks, block_tables, seg, pos,
         vs = gather(v_blocks.scales).reshape(B, M * BS, H) \
             .transpose(2, 0, 1)
     else:
-        k = gather(k_blocks).reshape(B, M * BS, H, Dh) \
-            .transpose(2, 0, 1, 3)                        # [H, B, C, Dh]
-        v = gather(v_blocks).reshape(B, M * BS, H, Dh) \
-            .transpose(2, 0, 1, 3)
+        k, v = (_per_query_head(
+            gather(blocks).reshape(B, M * BS, Hkv, Dh)
+            .transpose(2, 0, 1, 3), H, 0)                 # [H, B, C, Dh]
+            for blocks in (k_blocks, v_blocks))
         ks = vs = None
     qh = q.transpose(1, 0, 2)                             # [H, T, Dh]
     s = jnp.einsum("htd,hbcd->htbc", qh, k).astype(jnp.float32) * sc

@@ -47,7 +47,15 @@ Shared machinery:
     scales into the [H, BS] score and weight tiles, as the XLA path
     folds them (`_scales_by_head`).
 
-Layout (matches inference/kv_cache.py):
+Grouped heads (PR 30): the pool may hold fewer K/V heads than q has
+query heads (`Hkv = pool width / Dh`, `group = H / Hkv`): query heads
+g*group .. (g+1)*group - 1 attend K/V head g.  The stream kernel scores
+each of them against its K/V head's lanes; the decode kernel's query tile
+is [H, Hkv*Dh], row h in the lanes of K/V head h // group, and its q and
+output are then [H, Dh] tiles.  With Hkv = H (group 1) both are the
+kernels they were.
+
+Layout (matches inference/kv_cache.py; H query heads, Hkv <= H in a pool):
     q:        [T, H, Dh] stream / [B, H, Dh] decode
     k_blocks: [L, N, BS, H*Dh] + layer   the pool stack, every token's
                                          heads side by side on the
@@ -108,13 +116,18 @@ NEG_INF = -1e30
 Q_TILE = 128     # stream query-tile (and packing alignment) size
 
 
-def supported_shapes(head_dim, block_size, num_heads, total_tokens=None):
+def supported_shapes(head_dim, block_size, num_heads, total_tokens=None,
+                     kv_heads=None):
     """Shape gate for the compiled TPU kernel (interpret mode takes
-    any): head_dim lane-sized, block_size a lane multiple, heads
-    sublane-aligned; a packed stream additionally needs its length
-    query-tile aligned."""
+    any): head_dim lane-sized, block_size a lane multiple, query heads
+    sublane-aligned and whole groups of the pool's `kv_heads` (None: as
+    many as query heads), a pool row whole lane tiles; a packed stream
+    additionally needs its length query-tile aligned."""
+    kv_heads = num_heads if kv_heads is None else kv_heads
     ok = (head_dim in (32, 64, 128, 256) and block_size % 128 == 0
-          and num_heads % 8 == 0)
+          and num_heads % 8 == 0 and kv_heads > 0
+          and num_heads % kv_heads == 0
+          and (kv_heads * head_dim) % 128 == 0)
     if total_tokens is not None:
         ok = ok and total_tokens % Q_TILE == 0
     return ok
@@ -162,6 +175,17 @@ def kv_operands(k_blocks, v_blocks, layer):
     return quant, operands
 
 
+def _kv_heads(pool, num_heads, head_dim):
+    """K/V heads of a pool stack [L, N, BS, Hkv*Dh] that `num_heads` query
+    heads of `head_dim` attend: whole groups of them share a K/V head."""
+    hkv, rest = divmod(pool.shape[-1], head_dim)
+    if rest or hkv == 0 or num_heads % hkv:
+        raise ValueError(
+            f"a pool row of {pool.shape[-1]} values is not K/V heads of "
+            f"{head_dim} that {num_heads} query heads share in whole groups")
+    return hkv
+
+
 def _load_heads(ref, sref, g, per, dh, dt):
     """The `per` heads of lane group `g` of the pool block in VMEM, each
     [BS, Dh]: heads g*per .. g*per + per - 1 are the lanes
@@ -187,7 +211,7 @@ def _load_heads(ref, sref, g, per, dh, dt):
 # ---- stream kernel (prefill chunks / decode rows / verify regions) ----
 
 def _stream_kernel(layer_ref, tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
-                   *refs, scale, nm, qt, quant, tile_base):
+                   *refs, scale, nm, qt, quant, tile_base, group):
     if quant:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -196,9 +220,10 @@ def _stream_kernel(layer_ref, tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
     qi = pl.program_id(0)
     mi = pl.program_id(1)
     nh, _, dh = q_ref.shape
-    # heads side by side in one 128-lane tile of a pool row: the loop
+    nkv = nh // group
+    # K/V heads side by side in one 128-lane tile of a pool row: the loop
     # below steps over such tiles, so its index may address the lanes
-    per = max(1, min(nh, 128 // dh))
+    per = max(1, min(nkv, 128 // dh))
 
     @pl.when(mi == 0)
     def _init():
@@ -243,15 +268,18 @@ def _stream_kernel(layer_ref, tile_seg_ref, tile_pos_ref, tables_ref, q_ref,
         def lane_group(g, carry):
             ks = _load_heads(k_ref, ks_ref, g, per, dh, q_ref.dtype)
             vs = _load_heads(v_ref, vs_ref, g, per, dh, q_ref.dtype)
-            for i in range(per):
-                one_head(g * per + i, ks[i], vs[i])
+            for i in range(per):        # a K/V head, then its query heads
+                kv = g * per + i
+                for r in range(group):
+                    one_head(kv if group == 1 else kv * group + r,
+                             ks[i], vs[i])
             return carry
 
         # one traced body, unrolled by the lowering: Python-unrolling the
         # heads cost every program 0.5 s of tracing (56 programs a warm
         # set-up), a rolled loop cost the kernel a fifth of its speed (the
         # scheduler overlaps the heads' dots only in straight-line code)
-        jax.lax.fori_loop(0, nh // per, lane_group, 0, unroll=True)
+        jax.lax.fori_loop(0, nkv // per, lane_group, 0, unroll=True)
 
     @pl.when(mi == nm - 1)
     def _flush():
@@ -294,6 +322,7 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
     tile_base = int(tile_base)
     T, H, Dh = q.shape
     BS = operands[0].shape[2]
+    Hkv = _kv_heads(operands[0], H, Dh)
     M = tables.shape[1]
     if T % qt:
         raise ValueError(f"packed length {T} not a multiple of the "
@@ -309,10 +338,11 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
     q_spec = pl.BlockSpec((H, qt, Dh),
                           lambda qi, m, ly, ts, tp, tb: (0, qi, 0))
     in_specs = [q_spec] + kv_operand_specs(
-        BS, H, Dh, quant,
+        BS, Hkv, Dh, quant,
         lambda qi, m, ly, ts, tp, tb: (ly[0], tb[ts[qi + tile_base], m]))
     kernel = functools.partial(_stream_kernel, scale=scale, nm=M,
-                               qt=qt, quant=quant, tile_base=tile_base)
+                               qt=qt, quant=quant, tile_base=tile_base,
+                               group=H // Hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # layer, tile_seg, tile_pos, tables steer the DMA
         num_scalar_prefetch=4,
@@ -344,10 +374,22 @@ def unified_ragged_attention_kernel(q, k_blocks, v_blocks, tables,
 # ---- decode (one token per sequence) --------------------------------
 
 def _own_lanes(nh, e, dh):
-    """[H, H*Dh] mask: row h owns head h's lanes [h*Dh, (h+1)*Dh)."""
-    first = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 0) * dh
+    """[H, Hkv*Dh] mask: row h owns the lanes of its K/V head, [g*Dh,
+    (g+1)*Dh) with g = h // group (group = H / Hkv; head h's own lanes
+    when every query head has a K/V head)."""
+    group = nh * dh // e
+    if group == 1:
+        first = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 0) * dh
+        lane = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 1)
+        return (lane >= first) & (lane < first + dh)
+    row = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (nh, e), 1)
-    return (lane >= first) & (lane < first + dh)
+    own = None       # one term a K/V head: no vector division to lower
+    for g in range(e // dh):
+        mine = ((row >= g * group) & (row < (g + 1) * group)
+                & (lane >= g * dh) & (lane < (g + 1) * dh))
+        own = mine if own is None else own | mine
+    return own
 
 
 def _scales_by_head(sref, nh):
@@ -377,14 +419,20 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *refs, scale, nm,
     bs = k_ref.shape[0]
     ctx = ctx_ref[bi]
 
+    grouped = nh * dh != e     # fewer K/V heads than query heads
+
     @pl.when(mi == 0)
     def _init():
         # the row's heads as ONE block-diagonal query tile: row h is head
-        # h's query in head h's lanes and zero elsewhere, so a dot over
-        # all H*Dh lanes of a pool block is every head's score at once
-        # (the zeros add exactly 0); selected in float32, the width of
-        # the mask: the VPU has no bf16 select to lose
-        q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), (nh, e))
+        # h's query in its K/V head's lanes and zero elsewhere, so a dot
+        # over all Hkv*Dh lanes of a pool block is every head's score at
+        # once (the zeros add exactly 0); selected in float32, the width
+        # of the mask: the VPU has no bf16 select to lose
+        if grouped:   # q is the [H, Dh] tile: once beside itself a K/V head
+            q = jnp.concatenate([q_ref[...].astype(jnp.float32)]
+                                * (e // dh), axis=1)
+        else:         # q is the [1, H*Dh] row it is in memory
+            q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), (nh, e))
         qbd_ref[:] = jnp.where(_own_lanes(nh, e, dh), q,
                                0.0).astype(qbd_ref.dtype)
         acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -424,7 +472,11 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *refs, scale, nm,
     def _flush():
         l = jnp.maximum(l_ref[:, 0:1], 1e-30)  # ctx 0 flushes zeros
         o = jnp.where(_own_lanes(nh, e, dh), acc_ref[:] / l, 0.0)
-        o_ref[:] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
+        if grouped:   # row h's output lies in its K/V head's lanes
+            o_ref[:] = sum(o[:, g * dh:(g + 1) * dh]
+                           for g in range(e // dh)).astype(o_ref.dtype)
+        else:
+            o_ref[:] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -438,26 +490,33 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
     of the row against the [BS, H*Dh] block with one dot, updates one
     online softmax on the [H, BS] tile and sums with one dot
     (`_decode_kernel`).  q goes in and the output comes back as the
-    lane-dense [1, H*Dh] row they are in memory.  ctx_len == 0 (a pad
-    row) returns zeros.  Returns [B, H, Dh] in q's dtype."""
+    lane-dense [1, H*Dh] row they are in memory (as the [H, Dh] tile
+    where the pool holds fewer K/V heads than q has heads: a row of the
+    query tile is then Hkv*Dh wide).  ctx_len == 0 (a pad row) returns
+    zeros.  Returns [B, H, Dh] in q's dtype."""
     quant, operands = kv_operands(k_blocks, v_blocks, layer)
     layer = jnp.reshape(jnp.asarray(0 if layer is None else layer,
                                     jnp.int32), (1,))
     B, H, Dh = q.shape
-    E = H * Dh
+    Hkv = _kv_heads(operands[0], H, Dh)
+    if quant and Hkv != H:
+        raise ValueError("the decode kernel folds an int8 pool's scales "
+                         "a query head: it takes no grouped heads")
+    E = Hkv * Dh
     BS = operands[0].shape[2]
     M = tables.shape[1]
     scale = (Dh ** -0.5) if scale is None else float(scale)
-    row = pl.BlockSpec((None, 1, E), lambda b, m, ly, tb, cx: (b, 0, 0))
+    tile = (1, H * Dh) if Hkv == H else (H, Dh)
+    row = pl.BlockSpec((None,) + tile, lambda b, m, ly, tb, cx: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer and tables steer the DMA
         grid=(B, M),
         in_specs=[row] + kv_operand_specs(
-            BS, H, Dh, quant, lambda b, m, ly, tb, cx: (ly[0], tb[b, m])),
+            BS, Hkv, Dh, quant, lambda b, m, ly, tb, cx: (ly[0], tb[b, m])),
         out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((H, E), q.dtype),       # the block-diagonal query
-            pltpu.VMEM((H, E), jnp.float32),   # acc: row h, head h's lanes
+            pltpu.VMEM((H, E), jnp.float32),   # acc: row h, its own lanes
             # m, l: one value a head, kept across a lane tile
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
@@ -468,8 +527,8 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
         functools.partial(_decode_kernel, scale=scale, nm=M, dh=Dh,
                           quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, E), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + tile, q.dtype),
         interpret=interpret,
     )(layer, tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q.reshape(B, 1, E), *operands)
+      q.reshape((B,) + tile), *operands)
     return out.reshape(B, H, Dh)

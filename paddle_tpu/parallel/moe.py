@@ -123,15 +123,31 @@ def _row_tile(n_tokens, top_k, held):
 def routed_expert_ffn(x, valid, router_w, router_b, gate, up, down, *,
                       held_first, top_k, scaling, renormalize=True):
     """The routed half of an expert FFN on one chip of an expert-parallel
-    layer: route over ALL experts, compute the experts held here.
+    layer, under a sigmoid router: s = sigmoid(x W_r) over ALL experts
+    (router_w [D, n_experts]), the selection scores s + router_b
+    (router_b [n_experts], for the choice alone); then `dispatch_experts`
+    on them, whose arguments and results these are."""
+    s = jax.nn.sigmoid(jnp.dot(x, router_w,
+                               preferred_element_type=jnp.float32))
+    return dispatch_experts(
+        x, valid, s, s + router_b.astype(jnp.float32), gate, up, down,
+        held_first=held_first, top_k=top_k, scaling=scaling,
+        renormalize=renormalize)
+
+
+def dispatch_experts(x, valid, scores, select, gate, up, down, *,
+                     held_first, top_k, scaling=1.0, renormalize=False):
+    """Route over ALL experts by a router's scores, compute the experts
+    held here: one chip's share of an expert-parallel layer, whatever the
+    router.
 
     x [N, D] tokens, valid [N] bool (padding and idle rows route nowhere);
-    router_w [D, n_experts], router_b [n_experts] (added to the scores for
-    the choice alone); gate, up [held, D, F], down [held, F, D] the SwiGLU
-    weights of experts held_first .. held_first + held - 1.
-    s = sigmoid(x W_r); the top_k of s + router_b are chosen; a chosen
-    expert weighs s_i (over the chosen's sum when `renormalize`) times
-    `scaling`.  Returns (y [N, D], counts int32 [4], picks int32 [N, k]):
+    scores, select [N, n_experts] float32 from the caller's router: the
+    top_k of `select` are chosen, and a chosen expert weighs its `scores`
+    entry (over the chosen's sum when `renormalize`) times `scaling`;
+    gate, up [held, D, F], down [held, F, D] the SwiGLU weights of experts
+    held_first .. held_first + held - 1.
+    Returns (y [N, D], counts int32 [4], picks int32 [N, k]):
     y is the weighted sum over the chosen experts HELD HERE (what the
     others would add is their chips'), counts = (tokens routed, picks held
     here, held experts with a pick, the most picks on one expert), picks
@@ -144,9 +160,8 @@ def routed_expert_ffn(x, valid, router_w, router_b, gate, up, down, *,
     n, d = x.shape
     held = gate.shape[0]
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x, router_w, preferred_element_type=f32))
-    _, idx = jax.lax.top_k(s + router_b.astype(f32), top_k)       # [N, k]
-    w = jnp.take_along_axis(s, idx, axis=-1)
+    _, idx = jax.lax.top_k(select, top_k)                         # [N, k]
+    w = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, -1, keepdims=True)
     w = w * scaling

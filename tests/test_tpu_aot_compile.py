@@ -416,11 +416,8 @@ def test_stateful_decode_step_works_on_both_caches_in_place(one_chip,
     lay = desc.cache_layout()
     slots, rows, blocks, width = 16, 16, 64, 8
     pool = s((lay["pool_layers"], blocks, BS, lay["row_width"]), jnp.bfloat16)
-    store = {
-        "S": s((lay["state_layers"], slots + 1) + lay["state_shape"],
-               jnp.float32),
-        "conv": s((lay["state_layers"], slots + 1) + lay["conv_shape"],
-                  jnp.bfloat16)}
+    store = {name: s((layers, slots + 1) + shape, dt or jnp.bfloat16)
+             for name, (layers, shape, dt) in lay["store"].items()}
     _packed, step = build_block_programs(desc, BS, False, (False, False))
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(step, donate_argnums=(5, 6)).lower(
@@ -462,3 +459,69 @@ def test_flash_under_dp_mp_mesh_compiles(mesh4, monkeypatch):
         return out._value.astype(jnp.float32).sum()
 
     assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+
+
+def _zaya_programs(one_chip, layers, rows, blocks, width, tokens=512):
+    """ZAYA1-8B's `decode_step` and `packed_prefill` at the published
+    widths and `layers` deep, as shapes on the described chip: (compiled
+    step, compiled packed prefill, one layer's K pool in bytes)."""
+    from paddle_tpu.models.zaya import Zaya, ZayaConfig
+    from paddle_tpu.nn.decode_blocks import build_block_programs
+
+    s = _sds(one_chip)
+    # one layer's weights are built (4 of its experts), the rest are shapes
+    model = Zaya(ZayaConfig(vocab_size=8192, held_layers=1,
+                            held_experts=(0, 4)), dtype="bfloat16")
+    one = model.functional_state()[0]
+    params = {}
+    for k, v in one.items():
+        shape = (16,) + v.shape[1:] if ".experts." in k else v.shape
+        for i in range(layers if k.startswith("layers.0.") else 1):
+            params[k.replace("layers.0.", f"layers.{i}.")] = s(shape, v.dtype)
+    params["embed.weight"] = s((262272, 2048), jnp.bfloat16)
+    cfg = ZayaConfig(held_layers=layers)
+    model.cfg = cfg
+    desc = model.decoder_description()
+    lay = desc.cache_layout()
+    pool = s((lay["pool_layers"], blocks, BS, lay["row_width"]), jnp.bfloat16)
+    store = {name: s((n, rows + 1) + shape, dt or jnp.bfloat16)
+             for name, (n, shape, dt) in lay["store"].items()}
+    packed, step = build_block_programs(desc, BS, False, (False, False))
+    i32 = lambda *sh: s(sh, jnp.int32)
+    with jax.default_matmul_precision("default"):
+        c_step = jax.jit(step, donate_argnums=(5, 6, 7)).lower(
+            params, i32(rows), i32(rows), s((rows,), jnp.bool_),
+            i32(rows, 1 + width), pool, pool, store,
+            {"stop": i32(rows, 1)}, i32(rows)).compile()
+        c_packed = jax.jit(packed, donate_argnums=(6, 7, 8)).lower(
+            params, i32(tokens), i32(tokens), i32(tokens),
+            i32(rows, 1 + width), i32(rows), pool, pool, store,
+            {"stop": i32(rows, 1)}).compile()
+    return c_step, c_packed, blocks * BS * lay["row_width"] * 2
+
+
+def test_grouped_head_programs_work_on_pools_and_tails_in_place(
+        one_chip, monkeypatch):
+    """ZAYA1-8B's two serving programs at the published widths, 2 layers
+    deep, through the Pallas path: a paged kernel and a `moe_gmm` a layer
+    by name (8 query heads on the pool's 2 K/V heads), the K pool, the V
+    pool and the three tail arrays aliased to the outputs, and
+    temporaries under one layer's pool (no pool-sized copy, no re-laid
+    stack)."""
+    import re
+
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    layers = 2
+    step, packed, one_layer_pool = _zaya_programs(
+        one_chip, layers, rows=128, blocks=2560, width=40)
+    for compiled, kernel in ((step, "paged_attn_decode"),
+                             (packed, "paged_attn_prefill")):
+        text = compiled.as_text()
+        names = _kernel_names_in(text)
+        assert sorted(names) == sorted([kernel, "moe_gmm"] * layers), names
+        assert len(re.findall(r"(?:may|must)-alias",
+                              text.split("\n", 1)[0])) == 5
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < one_layer_pool
