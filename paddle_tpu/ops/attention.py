@@ -253,10 +253,10 @@ def paged_decode_attention(q, k_blocks, v_blocks, block_tables, ctx_lens,
     lane-sized, block_size a lane multiple, per-device heads
     sublane-aligned); otherwise runs the XLA gather path, which
     materializes the [B, M*BS] gathered keys — correct everywhere, but
-    it reads the padded table width, where the kernel fetches a row's
-    live blocks and, once a row whose table has padding, the pad block
-    (its grid still steps over the whole width: the steps past a
-    row's context compute nothing and fetch nothing more)."""
+    it reads the padded table width, where the kernel's grid is the
+    launch's live (row, block) pairs: it fetches a row's live blocks and
+    nothing else, and names no pad block (a row of context 0 takes one
+    step, in which it writes its zeros)."""
     quant = _is_quantized_kv(k_blocks)
     kcodes = k_blocks.codes if quant else k_blocks
     B, H, Dh = q.shape

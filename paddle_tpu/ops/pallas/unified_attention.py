@@ -17,22 +17,31 @@ It holds:
     paths, with a body of its own (PR 27).  A decode row is one query
     a head against a block all heads share, so the row's H heads are
     the H rows of ONE block-diagonal query tile [H, H*Dh] (row h: head
-    h's query in head h's lanes, zeros elsewhere), and a grid step
-    (row, table column) is one score dot over the whole [BS, H*Dh]
-    block as it lies, one online-softmax update on the [H, BS] tile
-    (every row has the same horizon, ctx_len) and one value dot; the
-    block diagonal of the accumulator is the output row.  (Until PR
-    27 it was the stream kernel at an 8-row tile once a head: 32
-    eight-row dots and 16 softmax chains a step, 350 us a launch in
-    the serve cell where this body takes 136: PERF.md section 6.  A
-    (B, M)-grid body whose dots were batched over heads with a bare
-    [H, Dh] left operand never lowered and went in PR 21.)
+    h's query in head h's lanes, zeros elsewhere), and a grid step is
+    one score dot over the whole [BS, H*Dh] block as it lies, one
+    online-softmax update on the [H, BS] tile (every row has the same
+    horizon, ctx_len) and one value dot; the block diagonal of the
+    accumulator is the output row.  Its grid is ONE axis over the
+    launch's live (row, block) pairs (PR 31): `decode_work_list`
+    turns the contexts into a flat list of each step's row and table
+    column, scalar-prefetched beside the tables, and the grid's bound,
+    how many of them the launch has, is read on the device, so one
+    compiled program serves every mix of contexts and a step is never
+    spent past a row's context (a row of context 0 keeps one step, in
+    which it writes its zeros).  (Until PR 31 the grid was rows x
+    table width: 150 of a launch's 256 steps in the GPT-2 serve cell
+    and 3 of 4 in the ZAYA cell did nothing but cost their fixed part,
+    PERF.md section 6.  Until PR 27 the decode entry was the stream
+    kernel at an 8-row tile once a head.  A (B, M)-grid body whose dots
+    were batched over heads with a bare [H, Dh] left operand never
+    lowered and went in PR 21.)
 
 Shared machinery:
 
   * `kv_operand_specs` — the scalar-prefetched block-index BlockSpec
     construction: the k/v (and int8 scale) index maps read
-    `(layer[0], tables[row, m])` from prefetched scalars, so the
+    `(layer[0], tables[row, m])` from prefetched scalars (the decode
+    kernel's row and m themselves from its prefetched work list), so the
     pipeline DMAs exactly the pool blocks each query's sequence names,
     out of the WHOLE layer stack, and never materializes the
     [.., M*BS, ...] gather copy the XLA fallback builds nor a slice of
@@ -405,16 +414,44 @@ def _scales_by_head(sref, nh):
         preferred_element_type=jnp.float32)
 
 
-def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *refs, scale, nm,
-                   dh, quant):
+def _live_blocks(ctx, bs, width):
+    """Grid steps a row of context `ctx` takes: its live blocks (never more
+    than its table is wide), and one where it has none, so that a pad row
+    still flushes its zeros.  On [B] contexts where the work list is built
+    and on one scalar in the body: the two agree by construction."""
+    return jnp.clip(jax.lax.div(ctx + (bs - 1), bs), 1, width)
+
+
+def decode_work_list(ctx_lens, block_size, width):
+    """The decode launch's grid: its live (row, table column) pairs as two
+    flat int32 lists of the static length B * width, rows ascending and a
+    row's columns 0 .. n - 1 (n = `_live_blocks`), and how many of them
+    the launch steps over.  Entries past that count are never read by a
+    grid step; they stay inside the table.  A few integer ops on
+    `ctx_lens` alone, the same for every layer of a program, which XLA
+    keeps once (tests/test_tpu_aot_compile.py)."""
+    n = _live_blocks(ctx_lens.astype(jnp.int32), block_size, width)   # [B]
+    ends = jnp.cumsum(n)
+    step = jnp.arange(n.shape[0] * width, dtype=jnp.int32)[:, None]
+    done = ends[None, :] <= step          # [B * width, B]: rows behind us
+    rows = jnp.sum(done, axis=1, dtype=jnp.int32)
+    cols = step[:, 0] - jnp.sum(jnp.where(done, n[None, :], 0), axis=1,
+                                dtype=jnp.int32)
+    return (jnp.minimum(rows, n.shape[0] - 1), jnp.minimum(cols, width - 1),
+            ends[-1])
+
+
+def _decode_kernel(layer_ref, tables_ref, ctx_ref, rows_ref, cols_ref, q_ref,
+                   *refs, scale, nm, dh, quant):
     del layer_ref, tables_ref
     if quant:
         (k_ref, ks_ref, v_ref, vs_ref, o_ref, qbd_ref, acc_ref, m_ref,
          l_ref) = refs
     else:
         k_ref, v_ref, o_ref, qbd_ref, acc_ref, m_ref, l_ref = refs
-    bi = pl.program_id(0)
-    mi = pl.program_id(1)
+    # grid step i of the launch's work list: block `mi` of row `bi`
+    bi = rows_ref[pl.program_id(0)]
+    mi = cols_ref[pl.program_id(0)]
     nh, e = qbd_ref.shape
     bs = k_ref.shape[0]
     ctx = ctx_ref[bi]
@@ -468,7 +505,7 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *refs, scale, nm,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(mi == nm - 1)
+    @pl.when(mi == _live_blocks(ctx, bs, nm) - 1)   # the row's last step
     def _flush():
         l = jnp.maximum(l_ref[:, 0:1], 1e-30)  # ctx 0 flushes zeros
         o = jnp.where(_own_lanes(nh, e, dh), acc_ref[:] / l, 0.0)
@@ -486,14 +523,17 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
     """Ragged paged decode attention: q [B, H, Dh], one token per
     sequence attending cache positions [0, ctx_len) of the pool stack's
     `layer` (or of one layer's pool, layer=None; either may be
-    `QuantizedKV`).  Grid (row, table column); a step scores ALL heads
-    of the row against the [BS, H*Dh] block with one dot, updates one
-    online softmax on the [H, BS] tile and sums with one dot
-    (`_decode_kernel`).  q goes in and the output comes back as the
-    lane-dense [1, H*Dh] row they are in memory (as the [H, Dh] tile
-    where the pool holds fewer K/V heads than q has heads: a row of the
-    query tile is then Hkv*Dh wide).  ctx_len == 0 (a pad row) returns
-    zeros.  Returns [B, H, Dh] in q's dtype."""
+    `QuantizedKV`).  The grid is the launch's live (row, block) pairs
+    (`decode_work_list`), its length read on the device: a step scores
+    ALL heads of its row against the [BS, H*Dh] block with one dot,
+    updates one online softmax on the [H, BS] tile and sums with one dot
+    (`_decode_kernel`); a row's steps are consecutive, so the pipeline
+    fetches the next row's first block behind this row's last.  q goes in
+    and the output comes back as the lane-dense [1, H*Dh] row they are in
+    memory (as the [H, Dh] tile where the pool holds fewer K/V heads than
+    q has heads: a row of the query tile is then Hkv*Dh wide).
+    ctx_len == 0 (a pad row) takes one step and returns zeros.  Returns
+    [B, H, Dh] in q's dtype."""
     quant, operands = kv_operands(k_blocks, v_blocks, layer)
     layer = jnp.reshape(jnp.asarray(0 if layer is None else layer,
                                     jnp.int32), (1,))
@@ -506,13 +546,17 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
     BS = operands[0].shape[2]
     M = tables.shape[1]
     scale = (Dh ** -0.5) if scale is None else float(scale)
+    rows, cols, steps = decode_work_list(ctx_lens, BS, M)
     tile = (1, H * Dh) if Hkv == H else (H, Dh)
-    row = pl.BlockSpec((None,) + tile, lambda b, m, ly, tb, cx: (b, 0, 0))
+    row = pl.BlockSpec((None,) + tile,
+                       lambda i, ly, tb, cx, rw, cl: (rw[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # layer and tables steer the DMA
-        grid=(B, M),
+        # layer, tables and the work list steer the DMA
+        num_scalar_prefetch=5,
+        grid=(steps,),
         in_specs=[row] + kv_operand_specs(
-            BS, Hkv, Dh, quant, lambda b, m, ly, tb, cx: (ly[0], tb[b, m])),
+            BS, Hkv, Dh, quant,
+            lambda i, ly, tb, cx, rw, cl: (ly[0], tb[rw[i], cl[i]])),
         out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((H, E), q.dtype),       # the block-diagonal query
@@ -529,6 +573,6 @@ def paged_decode_attention_kernel(q, k_blocks, v_blocks, tables, ctx_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + tile, q.dtype),
         interpret=interpret,
-    )(layer, tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q.reshape((B,) + tile), *operands)
+    )(layer, tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), rows,
+      cols, q.reshape((B,) + tile), *operands)
     return out.reshape(B, H, Dh)

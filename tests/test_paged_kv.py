@@ -428,9 +428,14 @@ class TestPagedDenseParity:
 
 
 class TestPagedAttentionKernel:
-    def test_pallas_kernel_matches_xla_gather(self):
+    @pytest.mark.parametrize("lens", [[11, 5, 16], [0, 0, 13], [16, 16, 16],
+                                      [1, 4, 5]],
+                             ids=["ragged", "one_live_row", "every_row_full",
+                                  "block_edges"])
+    def test_pallas_kernel_matches_xla_gather(self, lens):
         """Ragged Pallas kernel (interpret mode on CPU) vs the XLA
-        gather path, ragged lengths + 0-padded tables."""
+        gather path: ragged lengths, a launch of one live row, of full
+        rows, of a block's edges; a row that sees nothing is zeros."""
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import paged_decode_attention
@@ -442,14 +447,16 @@ class TestPagedAttentionKernel:
         q = jnp.asarray(rs.randn(b, h, dh).astype(np.float32))
         kb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
         vb = jnp.asarray(rs.randn(n, bs, h, dh).astype(np.float32))
-        tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0],
+        tables = jnp.asarray(np.array([[1, 2, 3, 8], [4, 5, 1, 3],
                                        [6, 7, 8, 2]], np.int32))
-        lens = jnp.asarray(np.array([11, 5, 16], np.int32))
+        seen = np.array(lens) > 0
+        lens = jnp.asarray(np.array(lens, np.int32))
         ref = paged_decode_attention(q, kb, vb, tables, lens)
         out = paged_decode_attention_kernel(q, kb, vb, tables, lens,
                                             interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-6)
+        np.testing.assert_allclose(np.asarray(out)[seen],
+                                   np.asarray(ref)[seen], atol=2e-6)
+        assert not np.asarray(out)[~seen].any()
 
     def test_xla_gather_ignores_trash_blocks(self):
         """Positions beyond ctx_len must not influence the output even if

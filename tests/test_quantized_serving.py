@@ -575,7 +575,11 @@ class TestQuantizedPallasKernels:
         cv, sv = kv_encode(jnp.asarray(vb))
         return QuantizedKV(ck, sk), QuantizedKV(cv, sv)
 
-    def test_quant_decode_kernel_matches_fallback(self):
+    @pytest.mark.parametrize("lens", [[11, 5, 16], [0, 0, 13], [16, 16, 16],
+                                      [1, 4, 5]],
+                             ids=["ragged", "one_live_row", "every_row_full",
+                                  "block_edges"])
+    def test_quant_decode_kernel_matches_fallback(self, lens):
         import jax.numpy as jnp
 
         from paddle_tpu.ops.attention import paged_decode_attention
@@ -587,14 +591,19 @@ class TestQuantizedPallasKernels:
         q = jnp.asarray(rs.randn(b, h, dh).astype(np.float32))
         kq, vq = self._quant_pool(rs.randn(n, bs, h, dh),
                                   rs.randn(n, bs, h, dh))
-        tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0],
+        tables = jnp.asarray(np.array([[1, 2, 3, 8], [4, 5, 1, 3],
                                        [6, 7, 8, 2]], np.int32))
-        lens = jnp.asarray(np.array([11, 5, 16], np.int32))
+        # every table entry names a block of the pool, so any context up
+        # to the table's width is a case; the kernel's grid is the live
+        # (row, block) pairs, one step for a row that sees nothing
+        seen = np.array(lens) > 0
+        lens = jnp.asarray(np.array(lens, np.int32))
         ref = paged_decode_attention(q, kq, vq, tables, lens)
         out = paged_decode_attention_kernel(q, kq, vq, tables, lens,
                                             interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5)
+        np.testing.assert_allclose(np.asarray(out)[seen],
+                                   np.asarray(ref)[seen], atol=2e-5)
+        assert not np.asarray(out)[~seen].any()
 
     def test_quant_ragged_prefill_kernel_matches_fallback(self):
         import jax.numpy as jnp

@@ -138,24 +138,27 @@ def test_stream_kernel_compiles(one_chip, quant):
                     i32()) == 1
 
 
-def _decode_kernels(one_chip, quant, heads):
+def _decode_kernels(one_chip, quant, heads, kv_heads=None, dh=DH, rows=32,
+                    width=M, blocks=N_BLOCKS):
     from paddle_tpu.ops.pallas.unified_attention import (
         paged_decode_attention_kernel)
 
-    B = 32
+    kv_heads = heads if kv_heads is None else kv_heads
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    q = jax.ShapeDtypeStruct((B, heads, DH), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((rows, heads, dh), jnp.bfloat16,
                              sharding=one_chip)
-    pool = _pool(one_chip, quant, heads=heads)
+    pool = _pool(one_chip, quant, heads=kv_heads * dh // DH, blocks=blocks)
     return _kernels(paged_decode_attention_kernel, q, pool, pool,
-                    i32(B, M), i32(B), i32())
+                    i32(rows, width), i32(rows), i32())
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
 def test_decode_path_compiles(one_chip, quant):
     """The default engine loop's decode step: one token per sequence, all
     16 heads of a [128, 1024] block in one [16, 1024] query tile (PR 27;
-    the (B, M)-grid body PR 21 deleted had a bare [H, Dh] left operand)."""
+    the (B, M)-grid body PR 21 deleted had a bare [H, Dh] left operand),
+    over a grid whose one bound, the launch's live (row, block) pairs, is
+    read on the device (PR 31)."""
     assert _decode_kernels(one_chip, quant, H) == 1
 
 
@@ -166,12 +169,36 @@ def test_decode_path_compiles_at_eight_heads_a_device(one_chip, quant):
     assert _decode_kernels(one_chip, quant, H // 2) == 1
 
 
+def test_decode_path_compiles_at_group_four(one_chip):
+    """`zaya1_8b_l16.serve_reason128`'s launch: 8 query heads on 2 K/V
+    heads of 128, 128 rows, a table 40 wide over 2,560 blocks: the work
+    list's 5,120 rows and columns beside the tables in SMEM."""
+    assert _decode_kernels(one_chip, False, 8, kv_heads=2, dh=128, rows=128,
+                           width=40, blocks=2560) == 1
+
+
 def _kernel_names_in(text):
     """The HLO instruction names of a compiled program's kernels, as the
     chip's compiler gives them — what a device trace shows on `XLA Ops`."""
     return sorted(ln.split(" = ", 1)[0].split("%")[-1].rsplit(".", 1)[0]
                   for ln in text.splitlines()
                   if "tpu_custom_call" in ln and " = " in ln)
+
+
+# what `decode_work_list` is in a program compiled for the described chip:
+# one fusion for rows and columns' sums and one each for their bounds
+WORK_LIST_FUSIONS = 3
+
+
+def _work_list_fusions(text, steps):
+    """The fusions of a compiled program's entry computation that produce
+    the decode launch's work list (the only int32 arrays `steps` = rows x
+    table width long): it depends on the contexts alone, so every layer's
+    launch must share ONE, not build its own."""
+    entry = text[text.index("\nENTRY "):]
+    return [ln.split(" = ", 1)[0].strip() for ln in entry.splitlines()
+            if " fusion(" in ln
+            and f"s32[{steps}]" in ln.split(" = ", 1)[1].split(" fusion(")[0]]
 
 
 def _kernel_op_names(fn, *args):
@@ -236,11 +263,13 @@ def test_head_sharded_kernels_compile_on_four_devices(mesh4, monkeypatch):
         lambda q, k, v, tb, seg, pos: attention.ragged_prefill_attention(
             q, k, v, tb, seg, pos, mesh=mesh4, layer=1),
         heads(T), kv, kv, i32(B, M), i32(T), i32(T)) == 1
-    kv8 = _pool(mesh4, True, pool)
-    assert _kernels(
-        lambda q, k, v, tb, ctx: attention.paged_decode_attention(
-            q, k, v, tb, ctx, mesh=mesh4, layer=1),
-        heads(B), kv8, kv8, i32(B, M), i32(B)) == 1
+    # the decode kernel builds its work list per device from the replicated
+    # contexts and reads its grid bound there (PR 31), dense and int8
+    for kvq in (kv, _pool(mesh4, True, pool)):
+        assert _kernels(
+            lambda q, k, v, tb, ctx: attention.paged_decode_attention(
+                q, k, v, tb, ctx, mesh=mesh4, layer=1),
+            heads(B), kvq, kvq, i32(B, M), i32(B)) == 1
 
 
 def _gpt2_medium_params(sharding, layers):
@@ -328,18 +357,20 @@ def test_serving_program_works_on_the_pool_in_place(one_chip, monkeypatch,
 
 def test_decode_step_at_the_serve_cell_size(one_chip, monkeypatch):
     """The whole `decode_step` of `gpt2_medium.serve_closed32` (24 layers,
-    32 rows, 256 blocks): 24 kernels, each `paged_attn_decode`, and
-    temporaries under one layer's pool: the decode entry takes q and
-    returns its output as the [B, H*Dh] rows they are, so its wrapper
-    adds no padded, transposed or re-laid operand (PR 27; the parent's
-    8-row streams and [H, T, Dh] transposes were 22.8 MB here)."""
+    32 rows, 256 blocks): 24 kernels, each `paged_attn_decode`, and no
+    temporaries: the decode entry takes q and returns its output as the
+    [B, H*Dh] rows they are, so its wrapper adds no padded, transposed or
+    re-laid operand (PR 27; the parent's 8-row streams and [H, T, Dh]
+    transposes were 22.8 MB here)."""
     layers, blocks = 24, 256
     compiled = _compile_serving_program(one_chip, monkeypatch, "step",
                                         False, layers, blocks)
-    assert _kernel_names_in(compiled.as_text()) \
-        == ["paged_attn_decode"] * layers
-    one_layer_pool = 2 * blocks * BS * H * DH * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_pool
+    text = compiled.as_text()
+    assert _kernel_names_in(text) == ["paged_attn_decode"] * layers
+    # the 24 launches share one work list (PR 31), and it costs the program
+    # no temporary: 0 bytes, as at the parent
+    assert len(_work_list_fusions(text, 32 * M)) == WORK_LIST_FUSIONS
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def _sds(one_chip):
@@ -525,3 +556,6 @@ def test_grouped_head_programs_work_on_pools_and_tails_in_place(
                               text.split("\n", 1)[0])) == 5
         assert compiled.memory_analysis().temp_size_in_bytes \
             < one_layer_pool
+    # both layers' decode launches step over one work list (PR 31)
+    assert len(_work_list_fusions(step.as_text(), 128 * 40)) \
+        == WORK_LIST_FUSIONS

@@ -48,19 +48,30 @@ def _mixed_stream_case(seed=0):
 def _decode_cases():
     """(id, int8 pool, pool stack with a traced layer / one layer's pool,
     heads, head size, block, tables, contexts).  The first is the case of
-    the bit-equality test this one replaces; the others walk a block's
+    the bit-equality test this one replaces; the next walk a block's
     edges: a pad row (0), one token, exactly a block, one past it, the
-    whole table."""
+    whole table; the last are the launches whose grid (the live (row,
+    block) pairs since PR 31) is shortest and longest for its rows: one
+    live row among idle ones, and every row full."""
     first = ("dense-one_layer-4heads", False, False, 4, 8, 4,
              [[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 2]], [11, 0, 16])
     tables = [[0, 0, 0], [1, 0, 0], [2, 0, 0], [3, 4, 0], [5, 6, 7]]
     lens = [0, 1, 8, 9, 24]
-    return [first] + [
+    edges = [
         (f"{'int8' if quant else 'dense'}-"
          f"{'stack' if stacked else 'one_layer'}-{h}heads",
          quant, stacked, h, 16, 8, tables, lens)
         for quant in (False, True) for stacked in (True, False)
         for h in (16, 8)]
+    one_live = ([[0, 0, 0], [0, 0, 0], [1, 2, 3], [0, 0, 0]], [0, 0, 17, 0])
+    all_full = ([[1, 2, 3], [4, 5, 6], [7, 8, 9]], [24, 24, 24])
+    shapes = [
+        (f"{'int8' if quant else 'dense'}-stack-8heads-{name}",
+         quant, True, 8, 16, 8, *case)
+        for quant in (False, True)
+        for name, case in (("one_live_row", one_live),
+                           ("every_row_full", all_full))]
+    return [first] + edges + shapes
 
 
 DECODE_CASES = _decode_cases()
@@ -155,12 +166,41 @@ class TestUnifiedKernel:
             lens - 1, q_tile=qt, interpret=True)[::qt]
         gathered = attention.paged_decode_attention(q, k1, v1, tables, lens)
         out, seen = np.asarray(out), np.asarray(lens) > 0
-        assert seen.any() and not seen.all()
+        assert seen.any()
         np.testing.assert_allclose(out, np.asarray(tiled), atol=2e-4)
         np.testing.assert_allclose(out[seen], np.asarray(gathered)[seen],
                                    atol=2e-4)
         assert np.abs(out[seen]).max(axis=(1, 2)).all()
         assert not out[~seen].any()  # ctx_len 0: a pad row, zeros
+
+    @pytest.mark.parametrize("lens,width", [
+        ([0, 1, 8, 9, 24], 3), ([0, 0, 17, 0], 3), ([24, 24, 24], 3),
+        ([0, 0, 0, 0], 2), ([5], 1), ([30, 3], 3), ([7, 300, 128], 40)],
+        ids=["edges", "one_live_row", "every_row_full", "all_idle",
+             "one_row", "past_the_table", "wide_table"])
+    def test_decode_work_list_is_the_live_row_block_pairs(self, lens, width):
+        """The decode launch's grid (PR 31): a step a live block of a row,
+        one for a row that has none (it still flushes its zeros), never
+        more than the table is wide; rows ascend and a row's columns run
+        0 .. n - 1, so its first step initialises and its last flushes;
+        what lies past the used length stays inside the table."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import unified_attention as ua
+
+        bs = 8
+        rows, cols, used = ua.decode_work_list(jnp.asarray(lens, jnp.int32),
+                                               bs, width)
+        rows, cols, used = np.asarray(rows), np.asarray(cols), int(used)
+        n = [min(width, max(1, -(-c // bs))) for c in lens]
+        assert used == sum(n)
+        assert rows.shape == cols.shape == (len(lens) * width,)
+        assert rows.dtype == cols.dtype == np.int32
+        assert rows[:used].tolist() == [b for b, k in enumerate(n)
+                                        for _ in range(k)]
+        assert cols[:used].tolist() == [c for k in n for c in range(k)]
+        assert (rows >= 0).all() and (rows < len(lens)).all()
+        assert (cols >= 0).all() and (cols < width).all()
 
 
 def _serve(model, prompts, sampling_fn=None, timeout=300, **kw):

@@ -82,6 +82,39 @@ def test_decode_attention_with_grouped_heads(path, hq, hkv, d):
         np.testing.assert_allclose(np.asarray(got[r]), want, atol=2e-5)
 
 
+@pytest.mark.parametrize("lengths", [
+    (0, 1, BS, BS + 1, 3 * BS), (0, 0, 2 * BS + 1, 0), (3 * BS,) * 3],
+    ids=["block_edges", "one_live_row", "every_row_full"])
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4)], ids=["8on2", "4on4"])
+def test_decode_kernel_steps_over_live_blocks_with_grouped_heads(hq, hkv,
+                                                                 lengths):
+    """The decode kernel's grid is the launch's live (row, block) pairs
+    (PR 31), at group 4 and group 1, the layer a traced scalar as in a
+    program: a pad row, one token, a block and one past it, a full table,
+    one live row among idle ones, every row full: plain attention's last
+    row, zeros where a row sees nothing; and the gather path agrees."""
+    d, width = 16, 3
+    live = [r for r, n in enumerate(lengths) if n]      # rows that see any
+    seqs, kc, vc, packed = paged([lengths[r] for r in live], hq, hkv, d,
+                                 seed=2)
+    tables = np.zeros((len(lengths), width), np.int32)
+    q = np.ones((len(lengths), hq, d), np.float32)  # a pad row's: whatever
+    for i, r in enumerate(live):
+        tables[r, :packed.shape[1]] = np.asarray(packed[i])
+        q[r] = seqs[i][0][-1]
+    q, tables = jnp.asarray(q), jnp.asarray(tables)
+    ctx = jnp.asarray(np.array(lengths, np.int32))
+    got = np.asarray(jax.jit(lambda ly: ua.paged_decode_attention_kernel(
+        q, kc, vc, tables, ctx, ly, interpret=True))(jnp.int32(1)))
+    gathered = np.asarray(attention.paged_decode_attention(
+        q, kc, vc, tables, ctx, layer=1))
+    assert not got[[r for r, n in enumerate(lengths) if not n]].any()
+    for i, r in enumerate(live):
+        want = plain_attention(*seqs[i], hq // hkv)[-1]
+        np.testing.assert_allclose(got[r], want, atol=2e-5)
+        np.testing.assert_allclose(got[r], gathered[r], atol=2e-5)
+
+
 @pytest.mark.parametrize("hq,hkv,d", [(8, 2, 16), (4, 2, 32), (4, 4, 16)],
                          ids=["8on2", "4on2", "4on4"])
 @pytest.mark.parametrize("path", ["kernel", "xla"])
