@@ -302,7 +302,12 @@ class PagedKVCache:
         state "S" in float32 and conv tails for KDA layers; conv and
         value tails alone for CCA layers), indexed by a slot that a
         sequence takes with its first block and gives back in `free`
-        (slot 0 is the trash slot).  A slot is not zeroed on the device
+        (slot 0 is the trash slot).  Where the layout has no pool
+        (`pool_layers` 0: every layer keeps its sequence in the store)
+        `k_blocks` is an array of no rows ([0, num_blocks, block_size,
+        0]), a sequence takes no block however long it grows
+        (`blocks_for`), and a table row is [state slot] alone.  A slot is
+        not zeroed on the device
         when it changes hands: a program that starts a sequence at
         position 0 starts from zero state whatever the slot holds, and
         until another sequence takes the slot it keeps its last
@@ -427,14 +432,26 @@ class PagedKVCache:
     def for_description(cls, desc, *, block_size, num_blocks, dtype,
                         max_slots):
         """The cache a `nn.decode_blocks.DecoderDescription` needs: a
-        latent pool, or a K/V pool by the description's K/V heads, with a
-        store of `max_slots` slots."""
+        latent pool (one "head" as wide as a row), a K/V pool by the
+        description's K/V heads, or no pool at all (no layer, no width),
+        with a store of `max_slots` slots."""
         layout = desc.cache_layout()
-        heads = desc.cca.kv_heads if desc.values else 1
-        return cls(layout["pool_layers"], heads,
-                   layout["row_width"] // heads,
+        if not layout["pool_layers"]:
+            heads, head_dim = 1, 0
+        elif layout["values"]:
+            heads = desc.cca.kv_heads
+            head_dim = layout["row_width"] // heads
+        else:
+            heads, head_dim = 1, layout["row_width"]
+        return cls(layout["pool_layers"], heads, head_dim,
                    block_size=block_size, num_blocks=num_blocks,
                    dtype=dtype, state_layout=layout, state_slots=max_slots)
+
+    def blocks_for(self, num_tokens):
+        """Blocks a sequence of `num_tokens` tokens takes: none where the
+        cache has no pool."""
+        return blocks_for(num_tokens, self.block_size) \
+            if self.num_layers else 0
 
     # ---- the recurrent-state store's slots ----------------------------
     @property
@@ -997,8 +1014,7 @@ class PagedKVCache:
         need = []
         total = 0
         for seq_id, n in updates:
-            grow = blocks_for(n, self.block_size) \
-                - len(self._tables.get(seq_id, ()))
+            grow = self.blocks_for(n) - len(self._tables.get(seq_id, ()))
             need.append(max(0, grow))
             total += max(0, grow)
         if total > len(self._free) + len(self._retained):
@@ -1461,6 +1477,9 @@ class PagedKVCache:
     def stats(self):
         used = self.num_blocks - 1 - len(self._free) - len(self._retained)
         held = sum(self._lens.values())
+        # {store array: bytes a slot holds over all its layers}
+        entries = {name: int(a.nbytes) // a.shape[1]
+                   for name, a in (self.state or {}).items()}
         return {
             "block_size": self.block_size,
             "num_blocks": self.num_blocks - 1,  # usable (trash excluded)
@@ -1515,9 +1534,13 @@ class PagedKVCache:
             # is identical with and without a tier attached
             "tier": self._tier_stats(),
             # the recurrent-state store (zeros when the cache has none)
+            # and what a slot holds, by array, so that a reader counts
+            # state traffic without the model
             "state": {"slots": self.state_slots,
                       "used_slots": len(self._state_of),
-                      "peak_used_slots": self._peak_state},
+                      "peak_used_slots": self._peak_state,
+                      "bytes_per_slot": sum(entries.values()),
+                      "entries": entries},
         }
 
     def _tier_stats(self):

@@ -1226,13 +1226,20 @@ class PagedGenerationServer:
         dt = params["ln_f.weight" if self._desc is None
                     else self._desc.final_norm].dtype
         self.enable_prefix_cache = bool(enable_prefix_cache)
+        # a description none of whose layers pages anything has no pool:
+        # its sequences take no block and a table row is [state slot]
+        # alone, so admission goes by state slots
+        pooled = self._desc is None or self._desc.pooled
         self._m_width = blocks_for(
-            self.max_prompt_len + self.max_new + slack, self.block_size)
+            self.max_prompt_len + self.max_new + slack,
+            self.block_size) if pooled else 0
         if num_blocks is None:  # worst case: every slot at full horizon
             # (+1 CoW spare per slot when prefix caching is on, so the
-            # default pool still fits max_slots worst-case requests)
+            # default pool still fits max_slots worst-case requests; the
+            # trash block and one more where there is no pool)
             spare = 1 if self.enable_prefix_cache else 0
-            num_blocks = self.max_slots * (self._m_width + spare) + 1
+            num_blocks = max(
+                2, self.max_slots * (self._m_width + spare) + 1)
         if sharding is not None and sharding.dp > 1:
             # the pool's block axis shards over dp: round the array dim
             # up so the explicit placement divides evenly (the extra
@@ -1289,7 +1296,6 @@ class PagedGenerationServer:
                 self._desc, block_size=self.block_size,
                 num_blocks=int(num_blocks), dtype=dt,
                 max_slots=self.max_slots)
-        self._blocks_for = blocks_for
         # sharded serving (serving_dist round): a ShardedEngineConfig
         # (or True for defaults) places the snapshotted/quantized
         # weights and the pool arrays on the mesh and hands the decoder
@@ -2851,11 +2857,12 @@ class PagedGenerationServer:
         trace_id / hop / cause (+ the replica name on a fleet).
 
         on_routing: optional callable `(position, picks, state_slot)`
-        for a model with routed experts, invoked from the engine thread
-        after every dispatch that fed this request's tokens: `picks`
-        [expert layers, n, k] int32 are the experts the routers chose
-        for the n tokens from `position` on (a preempted request's
-        positions come again), `state_slot` the slot of the recurrent-
+        for a model served from its own description, invoked from the
+        engine thread after every dispatch that fed this request's
+        tokens: `picks` [expert layers, n, k] int32 are the experts the
+        routers chose for the n tokens from `position` on (None for a
+        model without routed experts; a preempted request's positions
+        come again), `state_slot` the slot of the recurrent-
         state store the sequence holds (0 without one); the slot keeps
         the sequence's last state until another sequence takes it (one
         step past it where a stop token or stop string ended the
@@ -3302,10 +3309,12 @@ class PagedGenerationServer:
                 "wall_s": dt,
             }
             out["kv_cache"] = self.cache.stats()
-            # the recurrent-state store beside the pool (zeros without)
+            # the recurrent-state store beside the pool (zeros without),
+            # and the bytes a slot holds, by array
             out["state"] = {
                 k: out["kv_cache"]["state"][k]
-                for k in ("slots", "peak_used_slots")}
+                for k in ("slots", "peak_used_slots", "bytes_per_slot",
+                          "entries")}
         # per-tenant cost attribution (ISSUE 17): evaluated OUTSIDE
         # the engine lock (the ledger has its own) — zeroed congruent
         # schema when attribution is off, reset-coherent
@@ -3512,10 +3521,12 @@ class PagedGenerationServer:
                  and self._slots[i]["req"].on_routing is not None]
         if not asked:
             return
-        picks = np.asarray(routed["picks"])    # [expert layers, rows, k]
+        # [expert layers, rows, k]; None without expert layers
+        picks = None if routed is None else np.asarray(routed["picks"])
         for s, p0, r0, n in asked:
             try:
-                s["req"].on_routing(int(p0), picks[:, r0:r0 + n],
+                s["req"].on_routing(int(p0), None if picks is None
+                                    else picks[:, r0:r0 + n],
                                     self.cache.state_slot(s["seq"]))
             except Exception:  # noqa: BLE001 — a callback never stops the loop
                 _logger.exception("on_routing callback raised")
@@ -3531,9 +3542,9 @@ class PagedGenerationServer:
         identical to the original reservation."""
         prompt = req.resume_ids if req.resume_ids is not None else req.ids
         remaining = req.budget - len(req.gen0)
-        return self._blocks_for(
-            prompt.size + remaining + self._overrun,
-            self.block_size) + (1 if self.enable_prefix_cache else 0)
+        return self.cache.blocks_for(
+            prompt.size + remaining + self._overrun) \
+            + (1 if self.enable_prefix_cache else 0)
 
     def _install_slot_locked(self, i, req, worst):
         """Shared admission body: bind `req` to slot `i` (reservation
@@ -3871,7 +3882,7 @@ class PagedGenerationServer:
                     # cache they can reach, and the jit re-specializes per
                     # (T, width) pair — still logarithmically many
                     mcap = 1
-                    need = max(self._blocks_for(start + n, self.block_size)
+                    need = max(self.cache.blocks_for(start + n)
                                for _, start, n, _ in plan)
                     while mcap < need:
                         mcap *= 2
@@ -3940,6 +3951,7 @@ class PagedGenerationServer:
                     if self._holds(p[0], rec["rows"][p[0]])]
             if routed is not None:
                 self._note_routed(routed["counts"])
+            if self._desc is not None:
                 self._tell_routing([(i, start, o, n)
                                     for i, start, n, o in plan], routed)
             self._dispatch_ok([self._slots[i]["req"].rid
@@ -5065,6 +5077,7 @@ class PagedGenerationServer:
             live = [i for i, seq in rows.items() if self._holds(i, seq)]
             if routed is not None:
                 self._note_routed(routed["counts"])
+            if self._desc is not None:
                 self._tell_routing([(i, rec["pos"][i], i, 1)
                                     for i in live], routed)
             self._dispatch_ok([self._slots[i]["req"].rid for i in live])

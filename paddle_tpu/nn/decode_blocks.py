@@ -1,21 +1,25 @@
 """The serving programs of a decoder of RMSNorm pre-norm blocks with no
 position table, whose layers mix by latent attention ("mla": a paged
 latent pool, absorbed decode), by a recurrent state ("kda": a slot of the
-state store) or by compressed convolutional attention ("cca": paged K and
+state store), by compressed convolutional attention ("cca": paged K and
 V rows of fewer K/V heads than query heads, rotary positions on part of a
-head, and per-sequence conv tails in the store) and feed forward through a
+head, and per-sequence conv tails in the store) or by power retention of
+degree 2 ("power": grouped query heads with per-head q/k norms and rotary
+positions on a whole head, a gate a K/V head, and a second-degree
+feature-map state in the store; no paged pool) and feed forward through a
 dense SwiGLU ("dense"), routed experts with a shared one under a sigmoid
 router ("experts") or routed experts alone under an MLP router with a
 softmax whose state goes from layer to layer ("mlp_routed").  The head
 after the final RMSNorm is its own matrix or the embedding's (`tied_head`);
 a sublayer's sum with the residual stream may carry learned scalings
 (`residual_scaling`).  `DecoderDescription` holds that layout's sizes and
-is what `models.kimi_linear.KimiLinear.decoder_description()` and
-`models.zaya.Zaya.decoder_description()` hand the engine;
+is what `models.kimi_linear.KimiLinear.decoder_description()`,
+`models.zaya.Zaya.decoder_description()` and
+`models.brumby.Brumby.decoder_description()` hand the engine;
 `build_block_programs` builds its `packed_prefill` and `decode_step` from
 the parameter names of those models (`embed.weight`, `layers.<i>.{norm_1,
-norm_2,res_1.*,res_2.*,kda.*,mla.*,cca.*,mlp.*,moe.*}`, `norm_f.weight`,
-`lm_head.weight`).
+norm_2,res_1.*,res_2.*,kda.*,mla.*,cca.*,power.*,mlp.*,moe.*}`,
+`norm_f.weight`, `lm_head.weight`).
 
 This is a second layout beside GPT-2's, not a description GPT-2 is an
 instance of: `nn.decode` keeps GPT-2's six-field tuple and its own
@@ -30,10 +34,16 @@ description with "cca" layers: `cache_layout()["values"]`), and the
 slot-indexed store `state` (`cache_layout()["store"]`: {"S": [L_kda,
 slots, H, D, D] float32, "conv": [L_kda, slots, K-1, 3*H*D]} for KDA
 layers; {"conv0", "conv1", "v_prev"}: the last inputs of CCA's two
-convolutions and of its shifted value, for CCA layers), its own argument
+convolutions and of its shifted value, for CCA layers; {"P": [L_power,
+slots, Hkv, D, Dh] float32, "Z": [L_power, slots, Hkv, D] float32}: the
+retention state and its normaliser, for power layers), its own argument
 and its own result; all donated and written in place.  A row of `tables`
 is [state slot | block table]: column 0 names the sequence's slot of the
-store (0: the trash slot, as block 0 is the trash block).  Beside tokens,
+store (0: the trash slot, as block 0 is the trash block).  A description
+none of whose layers pages anything (`cache_layout()["pool_layers"]` 0:
+every layer "kda" or "power") has NO POOL: `kc` is an array of no rows
+that the programs hand through, a table row is [state slot] alone, and no
+program reads a block column.  Beside tokens,
 pool and store a program returns `routed`: what its expert layers did in
 this dispatch ({"counts": [expert layers, 4] int32, the counters of
 `parallel.moe.dispatch_experts`; "picks": [expert layers, rows, k] int32,
@@ -45,7 +55,7 @@ from __future__ import annotations
 import functools
 from dataclasses import dataclass
 
-MIXERS = ("mla", "kda", "cca")
+MIXERS = ("mla", "kda", "cca", "power")
 FFNS = ("dense", "experts", "mlp_routed")
 
 
@@ -84,6 +94,24 @@ class CCADescription:
 
 
 @dataclass(frozen=True)
+class PowerDescription:
+    """The sizes of a "power" mixer (`ops/power_retention.py`)."""
+    heads: int                 # query heads
+    kv_heads: int              # K/V heads: one state and one gate each
+    head_dim: int
+    theta: float               # rotary base; the whole head rotates
+    tile: int                  # channels a tile of the feature map
+    chunk: int                 # positions a prefill chunk
+
+    @property
+    def state_dim(self):
+        """D: rows of a K/V head's state."""
+        from ..ops.power_retention import state_dim
+
+        return state_dim(self.head_dim, self.tile)
+
+
+@dataclass(frozen=True)
 class DecoderDescription:
     hidden: int
     vocab: int
@@ -105,6 +133,7 @@ class DecoderDescription:
     renormalize: bool = True
     scaling: float = 1.0
     cca: CCADescription = None
+    power: PowerDescription = None
     router_width: int = 0      # mlp_routed: the router MLP's width
     tied_head: bool = False    # the head is the embedding's matrix
     residual_scaling: bool = False   # layers.<i>.res_{1,2}.*
@@ -117,6 +146,11 @@ class DecoderDescription:
             raise ValueError("cca layers take the description's `cca` "
                              "sizes and the one paged pool: no mla layer "
                              "beside them")
+        if self.count("power") and (self.power is None
+                                    or self.count("kda")):
+            raise ValueError("power layers take the description's `power` "
+                             "sizes and its one chunk length: no kda "
+                             "layer beside them")
 
     def count(self, mixer):
         return sum(1 for l in self.layers if l.mixer == mixer)
@@ -127,25 +161,42 @@ class DecoderDescription:
         return bool(self.count("cca"))
 
     @property
+    def pooled(self):
+        """Whether a layer pages anything: a description of "kda" and
+        "power" layers alone keeps every sequence in the store."""
+        return bool(self.count("mla") or self.count("cca"))
+
+    @property
     def query_heads(self):
-        return self.cca.heads if self.values else self.heads
+        if self.values:
+            return self.cca.heads
+        return self.power.heads if self.count("power") else self.heads
+
+    @property
+    def chunk(self):
+        """Positions a chunk of the chunked layers (`chunked`)."""
+        return self.power.chunk if self.count("power") else self.kda_chunk
 
     @property
     def chunked(self):
-        """Whether a layer works in chunks of `kda_chunk` positions."""
-        return bool(self.count("kda") or self.count("mla"))
+        """Whether a layer works in chunks of `chunk` positions."""
+        return bool(self.count("kda") or self.count("mla")
+                    or self.count("power"))
 
     @property
     def pack_multiple(self):
         """A packed stream's regions must be aligned to this many tokens
-        (a KDA chunk and an MLA tile are wholly one sequence's)."""
-        return self.kda_chunk if self.chunked else 1
+        (a KDA or power chunk and an MLA tile are wholly one
+        sequence's)."""
+        return self.chunk if self.chunked else 1
 
     def cache_layout(self):
         """The device arrays a cache for this description holds: a pool of
         `pool_layers` layers of rows `row_width` wide (K rows, and V rows
         beside them where `values`), and the slot-indexed `store`: {name:
-        (layers, shape a slot, dtype or None for the model's)}."""
+        (layers, shape a slot, dtype or None for the model's)}.  Where no
+        layer pages anything there is no pool: `pool_layers` 0 and
+        `row_width` 0."""
         store = {}
         if self.count("kda"):
             store["S"] = (self.count("kda"), (self.kda_heads, self.kda_dim,
@@ -155,7 +206,15 @@ class DecoderDescription:
         for name, shape in (self.cca.tails() if self.count("cca")
                             else {}).items():
             store[name] = (self.count("cca"), shape, None)
-        if self.values:
+        if self.count("power"):
+            pw = self.power
+            store["P"] = (self.count("power"), (pw.kv_heads, pw.state_dim,
+                                                pw.head_dim), "float32")
+            store["Z"] = (self.count("power"), (pw.kv_heads, pw.state_dim),
+                          "float32")
+        if not self.pooled:
+            pool = {"pool_layers": 0, "values": False, "row_width": 0}
+        elif self.values:
             pool = {"pool_layers": self.count("cca"), "values": True,
                     "row_width": self.cca.kv_heads * self.cca.head_dim}
         else:
@@ -181,6 +240,7 @@ def _block_fns(desc):
     from ..ops import cca as _cca
     from ..ops import kda as _kda
     from ..ops import mla as _mla
+    from ..ops import power_retention as _power
     from ..ops.rotary import apply_rotary
     from ..parallel.moe import dispatch_experts, routed_expert_ffn
 
@@ -466,8 +526,59 @@ def _block_fns(desc):
                 q, kc, vc, ctx["btab"], ctx["ctx"], scale=cca_scale,
                 layer=li))
 
-    packed = {"kda": kda_packed, "mla": mla_packed, "cca": cca_packed}
-    step = {"kda": kda_step, "mla": mla_step, "cca": cca_step}
+    # ---- power retention -------------------------------------------------
+    def power_rows(p, pre, a, pos, valid):
+        """From the normed rows a [N, E] at positions pos [N]: (q
+        [N, Hq, D], k, v [N, Hkv, D], gamma [N, Hkv]) float32: the
+        projections, an RMSNorm of every q and k head, rotary on the whole
+        head, the log of a sigmoid gate a K/V head; a row that is not
+        `valid` made inert (k = 0, gamma = 0)."""
+        pw, n = desc.power, a.shape[0]
+
+        def heads(w, h, norm=None):
+            x = (a @ p[pre + w]).reshape(n, h, pw.head_dim)
+            if norm is None:
+                return x.astype(f32)
+            return apply_rotary(rms(x, p[pre + norm]).astype(f32), pos,
+                                pw.head_dim, pw.theta)
+
+        q = heads("q_proj.weight", pw.heads, "q_norm.weight")
+        k = heads("k_proj.weight", pw.kv_heads, "k_norm.weight")
+        v = heads("v_proj.weight", pw.kv_heads)
+        gamma = jax.nn.log_sigmoid((a @ p[pre + "gate_proj.weight"]
+                                    ).astype(f32))
+        return (q, jnp.where(valid[:, None, None], k, 0.0), v,
+                jnp.where(valid[:, None], gamma, 0.0))
+
+    def power_output(p, pre, a, o):
+        return o.astype(a.dtype).reshape(o.shape[0], -1) \
+            @ p[pre + "o_proj.weight"]
+
+    def power_packed(p, i, a, ctx, kc, vc, state):
+        pre, li = f"layers.{i}.power.", kind_index[i]
+        q, k, v, gamma = power_rows(p, pre, a, ctx["pos"], ctx["valid"])
+        with jax.named_scope("power_prefill"):
+            o, s_new, z_new = _power.power_chunked_prefill(
+                state["P"], state["Z"], li, ctx["tile_slot"],
+                ctx["tile_p0"] == 0, q, k, v, gamma,
+                chunk=desc.power.chunk, tile=desc.power.tile,
+                readout_dtype=a.dtype)
+        return power_output(p, pre, a, o), kc, vc, \
+            {**state, "P": s_new, "Z": z_new}
+
+    def power_step(p, i, a, ctx, kc, vc, state):
+        pre, li = f"layers.{i}.power.", kind_index[i]
+        q, k, v, gamma = power_rows(p, pre, a, ctx["pos"], ctx["valid"])
+        o, s_new, z_new = _power.power_recurrent_step(
+            state["P"], state["Z"], li, ctx["slot"], q, k, v, gamma,
+            tile=desc.power.tile)
+        return power_output(p, pre, a, o), kc, vc, \
+            {**state, "P": s_new, "Z": z_new}
+
+    packed = {"kda": kda_packed, "mla": mla_packed, "cca": cca_packed,
+              "power": power_packed}
+    step = {"kda": kda_step, "mla": mla_step, "cca": cca_step,
+            "power": power_step}
 
     def joined(p, pre, x, y):
         """A sublayer's output y with the residual stream x."""
@@ -525,7 +636,7 @@ def build_block_programs(desc, block_size, return_logits, mode):
     from ..sampling import processors as _proc
     from .decode import _make_readout
 
-    BS, C = int(block_size), desc.kda_chunk
+    BS, C = int(block_size), desc.chunk
     _sampled, penalties = mode
     fn = _block_fns(desc)
     readout = _make_readout(None, lambda x: x, mode, _proc)
@@ -557,10 +668,12 @@ def build_block_programs(desc, block_size, return_logits, mode):
             tile_carry = tile_live & (tile_p0 != start_row[tile_row])
             tiles = {"tile_row": tile_row, "tile_p0": tile_p0,
                      "tile_carry": tile_carry}
+        paged = {}
+        if desc.pooled:   # where each token's row lies in the pool
+            paged = {"blk": jnp.where(valid, btab[seg, p0 // BS], 0),
+                     "off": p0 % BS}
         ctx = {
-            "valid": valid, "pos": pos, "seg": seg, "btab": btab,
-            "blk": jnp.where(valid, btab[seg, p0 // BS], 0),
-            "off": p0 % BS,
+            "valid": valid, "pos": pos, "seg": seg, "btab": btab, **paged,
             "slot_row": tables[:, 0], "start_row": start_row,
             "start": start_row[seg],
             "end_row": jax.ops.segment_max(jnp.where(valid, pos, -1), seg,
@@ -594,11 +707,14 @@ def build_block_programs(desc, block_size, return_logits, mode):
         if prev is not None:   # as `nn.decode`'s step: a negative tok
             tok = jnp.where(tok < 0, prev, tok)   # goes on from `prev`
         btab = tables[:, 1:]
+        paged = {}
+        if desc.pooled:
+            paged = {"blk": jnp.where(active,
+                                      btab[jnp.arange(B), pos // BS], 0),
+                     "off": pos % BS}
         ctx = {
-            "valid": active, "btab": btab,
+            "valid": active, "btab": btab, **paged,
             "slot": jnp.where(active, tables[:, 0], 0),
-            "blk": jnp.where(active, btab[jnp.arange(B), pos // BS], 0),
-            "off": pos % BS,
             "ctx": jnp.where(active, pos + 1, 0),
             "pos": pos,
         }

@@ -323,7 +323,13 @@ def test_state_slots_live_and_die_with_their_sequences(tiny):
     cache.allocate("c", 1)
     assert cache.state_slot("c") == slot_a              # the slot, reused
     st = cache.stats()["state"]
-    assert st == {"slots": 2, "used_slots": 2, "peak_used_slots": 2}
+    # what a slot holds over the 3 KDA layers: float32 S and conv tails
+    entries = {name: a.nbytes // a.shape[1]
+               for name, a in cache.state.items()}
+    assert entries == {"S": 12288, "conv": 6912}
+    assert st == {"slots": 2, "used_slots": 2, "peak_used_slots": 2,
+                  "bytes_per_slot": sum(entries.values()),
+                  "entries": entries}
 
 
 def test_a_reused_slot_starts_from_zero_state(tiny):
@@ -335,7 +341,10 @@ def test_a_reused_slot_starts_from_zero_state(tiny):
     (alone,), _ = serve(model, [b], max_slots=1)
     (_first, after), stats = serve(model, [a, b], max_slots=1)
     assert (after == alone).all()
-    assert stats["state"] == {"slots": 1, "peak_used_slots": 1}
+    assert (stats["state"]["slots"], stats["state"]["peak_used_slots"]) \
+        == (1, 1)
+    assert stats["state"]["bytes_per_slot"] \
+        == sum(stats["state"]["entries"].values()) == 19200
 
 
 REFUSED = [

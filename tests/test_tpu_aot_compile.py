@@ -559,3 +559,87 @@ def test_grouped_head_programs_work_on_pools_and_tails_in_place(
     # both layers' decode launches step over one work list (PR 31)
     assert len(_work_list_fusions(step.as_text(), 128 * 40)) \
         == WORK_LIST_FUSIONS
+
+
+@pytest.mark.parametrize("tile", [32, 16])
+def test_power_decode_kernel_compiles(one_chip, tile):
+    """Brumby's launch: 16 rows, 40 query heads on 8 K/V heads of 128, a
+    K/V head's [D, 128] float32 state a grid step (5.2 MB at tiles of 32,
+    4.7 MB at 16, read and written: over the default scoped VMEM limit,
+    under the kernel's own), the store aliased in and out: the
+    transposes, the one-row loads and the sublane reductions lower."""
+    from paddle_tpu.ops.pallas.power_decode import power_decode_kernel
+    from paddle_tpu.ops.power_retention import state_dim
+
+    s = _sds(one_chip)
+    f32 = jnp.float32
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda st, sl, q, k, v, a: power_decode_kernel(
+                st, 1, sl, q, k, v, a, tile=tile),
+            donate_argnums=(0,)).lower(
+            s((2, 17, 8, state_dim(128, tile), 128), f32),
+            s((16,), jnp.int32), s((16, 40, 128), f32),
+            s((16, 8, 128), f32), s((16, 8, 128), f32),
+            s((16, 8), f32)).compile()
+    assert _kernel_names_in(compiled.as_text()) == ["power_decode"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_poolless_programs_work_on_the_store_in_place(one_chip,
+                                                      monkeypatch):
+    """Brumby-14B's two serving programs at the published widths, 2 layers
+    deep, 16 rows, a table of [state slot] alone: a `power_decode` a layer
+    by name in the decode step and no kernel in the packed prefill (its
+    chunked form is XLA), the two store arrays (and the pool of no rows)
+    aliased to the outputs, and temporaries under half a GB beside a
+    store entry of 0.7 GB a layer: no copy of the store, in the scan over
+    chunks and heads either (PR 25's check).  A chunk is a whole dispatch
+    of 512 tokens, so that is the one packed bucket; the scan has 8 steps
+    a chunk (one a K/V head) and stays a loop: a scan of ONE step is
+    inlined and the compiler then re-lays the whole store around the
+    program (5.3 GB of temporaries: PR 32, a scan over chunks alone at a
+    chunk of 128 in the 128-token bucket)."""
+    import re
+
+    from paddle_tpu.models.brumby import Brumby, BrumbyConfig
+    from paddle_tpu.nn.decode_blocks import build_block_programs
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    s = _sds(one_chip)
+    layers, rows, tokens = 2, 16, 512
+    model = Brumby(BrumbyConfig(vocab_size=1024, held_layers=1),
+                   dtype="bfloat16")
+    params = {}
+    for k, v in model.functional_state()[0].items():
+        for i in range(layers if k.startswith("layers.0.") else 1):
+            params[k.replace("layers.0.", f"layers.{i}.")] = s(v.shape,
+                                                               v.dtype)
+    params["embed.weight"] = s((151936, 5120), jnp.bfloat16)
+    params["lm_head.weight"] = s((5120, 151936), jnp.bfloat16)
+    model.cfg = BrumbyConfig(held_layers=layers)
+    desc = model.decoder_description()
+    lay = desc.cache_layout()
+    assert lay["pool_layers"] == 0
+    pool = s((0, 2, BS, 0), jnp.bfloat16)
+    store = {name: s((n, rows + 1) + shape, dt)
+             for name, (n, shape, dt) in lay["store"].items()}
+    packed, step = build_block_programs(desc, BS, False, (False, False))
+    i32 = lambda *sh: s(sh, jnp.int32)
+    with jax.default_matmul_precision("default"):
+        c_step = jax.jit(step, donate_argnums=(5, 6)).lower(
+            params, i32(rows), i32(rows), s((rows,), jnp.bool_),
+            i32(rows, 1), pool, store, {"stop": i32(rows, 1)},
+            i32(rows)).compile()
+        c_packed = jax.jit(packed, donate_argnums=(6, 7)).lower(
+            params, i32(tokens), i32(tokens), i32(tokens), i32(rows, 1),
+            i32(rows), pool, store, {"stop": i32(rows, 1)}).compile()
+    assert desc.chunk == desc.pack_multiple == tokens
+    for compiled, kernels in ((c_step, ["power_decode"] * layers),
+                              (c_packed, [])):
+        text = compiled.as_text()
+        assert _kernel_names_in(text) == kernels
+        assert len(re.findall(r"(?:may|must)-alias",
+                              text.split("\n", 1)[0])) == 3
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
