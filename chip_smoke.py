@@ -201,6 +201,7 @@ def phase_train(cfg, seed, batch, seq, steps, on_tpu):
     from paddle_tpu import optimizer as opt_mod
     from paddle_tpu.io import DataLoader
     from paddle_tpu.io.worker import _mp_context
+    from paddle_tpu.ops.attention import flash_attention_path
 
     paddle.seed(seed)
     optimizer = opt_mod.AdamW(learning_rate=1e-4, weight_decay=0.01)
@@ -237,6 +238,13 @@ def phase_train(cfg, seed, batch, seq, steps, on_tpu):
                 f"{3 * cfg.num_layers})")
             need(not on_tpu or n_kernels > 0,
                  "no flash kernel in the compiled train step")
+            path = flash_attention_path(
+                cfg.hidden_size // cfg.num_heads, cfg.num_heads, seq, seq,
+                batch, token_major=True)
+            log(f"[train] attention path: {path}")
+            need(not on_tpu or path == "pallas/token_major",
+                 f"GPT2Block's attention takes the {path} path, not the "
+                 f"flash kernels on the projection's own layout")
         t0 = time.perf_counter()
         loss, params, opt_state = compiled(params, opt_state, data, key)
         losses.append(float(loss))  # the host read is the barrier
@@ -477,12 +485,16 @@ def phase_hybrid_train(cfg, seed, batch, seq, on_tpu):
     compiled = hstep.compile_for(params, data, opt_state).lower(
         params, opt_state, data, key).compile()
     n_kernels = kernel_count(compiled.as_text())
+    path = flash_attention_path(cfg.hidden_size // cfg.num_heads,
+                                cfg.num_heads, seq, seq, batch, mesh,
+                                token_major=True)
     log(f"[4chip/train] dp2 x mp2 step compiled in "
         f"{time.perf_counter() - t0:.1f}s on mesh {dict(mesh.shape)}; "
         f"flash tpu_custom_call count {n_kernels} (attention path: "
-        f"{'pallas/shard_map over dp,mp' if n_kernels else 'xla'})")
-    need(not on_tpu or n_kernels > 0,
-         "no flash kernel in the dp2 x mp2 train step")
+        f"{path})")
+    need(not on_tpu or (n_kernels > 0 and path == "pallas/shard_map"),
+         f"no flash kernel in the dp2 x mp2 train step (attention path "
+         f"{path})")
     hybrid = []
     for _ in range(3):
         loss, params, opt_state = compiled(params, opt_state, data, key)

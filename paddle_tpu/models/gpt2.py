@@ -2,7 +2,8 @@
 
 Reference config: "GPT-2 medium with fused_attention_op → Pallas flash-attn,
 pipeline-parallel Fleet" (BASELINE.json). TPU-first construction:
-  * attention → ops.scaled_dot_product_attention (Pallas flash-attn on TPU)
+  * attention → ops.token_major_attention (Pallas flash-attn on TPU, on the
+    fused projection's own layout)
   * pre-LN blocks, tied embeddings, bf16-friendly
   * `build_train_step` returns a pure (params, batch, key) -> loss function
     for pjit/fleet hybrid-parallel execution; `jax.checkpoint` per block when
@@ -72,15 +73,12 @@ class GPT2Block(nn.Layer):
 
     def forward(self, x, attn_mask=None):
         a = self.ln_1(x)
-        b, s = a.shape[0], a.shape[1]
-        nh, hd = self.num_heads, self.head_dim
-        qkv = ops.reshape(self.qkv_proj(a), [b, s, 3, nh, hd])
-        qkv = ops.transpose(qkv, [2, 0, 3, 1, 4])  # [3, B, H, S, D]
-        q, k, v = qkv[0], qkv[1], qkv[2]
-        o, _ = ops.scaled_dot_product_attention(
-            q, k, v, attn_mask=attn_mask, is_causal=True,
+        # the fused projection goes to attention as the GEMM wrote it, and
+        # the output to out_proj as attention wrote it: no head-major copy
+        o = ops.token_major_attention(
+            self.qkv_proj(a), num_heads=self.num_heads, attn_mask=attn_mask,
+            is_causal=True,
             dropout_p=self.attn_dropout if self.training else 0.0)
-        o = ops.reshape(ops.transpose(o, [0, 2, 1, 3]), [b, s, nh * hd])
         x = x + self.dropout(self.out_proj(o))
         m = self.ln_2(x)
         m = self.fc2(ops.gelu(self.fc1(m), approximate=True))

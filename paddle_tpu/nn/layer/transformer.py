@@ -3,8 +3,9 @@
 Reference: python/paddle/nn/layer/transformer.py (MultiHeadAttention,
 TransformerEncoder/Decoder, Transformer) + the fused attention ops the
 north-star targets. TPU-first: attention routes through
-ops.scaled_dot_product_attention which dispatches to the Pallas flash-attention
-kernel on TPU (ops/pallas/flash_attention.py) and a fused XLA path elsewhere.
+ops.token_major_attention (ops.scaled_dot_product_attention with a cache or a
+weights output), which dispatch to the Pallas flash-attention kernels on TPU
+(ops/pallas/flash_attention.py) and a fused XLA path elsewhere.
 """
 from __future__ import annotations
 
@@ -61,7 +62,16 @@ class MultiHeadAttention(Layer):
     def forward(self, query, key=None, value=None, attn_mask=None, cache=None):
         key = query if key is None else key
         value = key if value is None else value
-        q = self._split_heads(self.q_proj(query))
+        q = self.q_proj(query)
+        if cache is None and not self.need_weights:
+            # the projections go to attention as the GEMMs wrote them, and
+            # its output to out_proj as it lies: no head-major copy
+            return self.out_proj(ops.token_major_attention(
+                q, self.k_proj(key), self.v_proj(value),
+                num_heads=self.num_heads,
+                attn_mask=_convert_attention_mask(attn_mask, q.dtype),
+                dropout_p=self.dropout if self.training else 0.0))
+        q = self._split_heads(q)
         if isinstance(cache, self.StaticCache):
             k, v = cache.k, cache.v
         else:

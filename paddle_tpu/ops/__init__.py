@@ -11,7 +11,7 @@ from ._registry import OPS, apply_op, as_jax, defop, raw  # noqa: F401
 from .attention import (  # noqa: F401
     fused_feedforward, fused_multi_head_attention,
     paged_decode_attention, ragged_prefill_attention,
-    scaled_dot_product_attention,
+    scaled_dot_product_attention, token_major_attention,
 )
 from .control import case, cond, fori_loop, scan, switch_case, while_loop  # noqa: F401
 from .creation import *  # noqa: F401,F403

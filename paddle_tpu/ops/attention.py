@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec, get_abstract_mesh
 
-from ._registry import defop
+from ._registry import defop, raw
 
 
 def _on_tpu():
@@ -139,71 +139,103 @@ def _xla_attention(q, k, v, mask=None, scale=None, causal=False):
     return out, w
 
 
+def _flash_plan(head_dim, num_heads, q_len, k_len, batch, mesh,
+                token_major):
+    """(path, mesh to shard_map over | None, token-major operands go to the
+    kernels as they are) for `flash_attention_path`'s arguments."""
+    from .pallas.flash_attention import heads_per_block
+
+    if not _on_tpu() or head_dim not in (32, 64, 128, 256) \
+            or q_len < 128 or q_len % 128 or k_len % 128:
+        return "xla", None, False
+    mesh = _manual_mesh(mesh)
+    if mesh is not None:
+        # the canonical (dp, mp) axes of parallel/mesh.py, and whole
+        # batches and heads per device; any other mesh takes the XLA
+        # path, which GSPMD can partition
+        if not {"dp", "mp"} <= set(mesh.axis_names) \
+                or batch % mesh.shape["dp"] or num_heads % mesh.shape["mp"]:
+            return "xla", None, False
+        num_heads //= mesh.shape["mp"]
+    # heads that do not fill the kernels' lane blocks (an odd count at
+    # Dh = 64) go through the head-major border, which appends zero heads
+    direct = token_major and num_heads % heads_per_block(head_dim) == 0
+    if mesh is not None:
+        return "pallas/shard_map", mesh, direct
+    return ("pallas/token_major" if direct else "pallas"), None, direct
+
+
+def flash_attention_path(head_dim, num_heads, q_len, k_len, batch=1,
+                         mesh=None, token_major=False):
+    """Which implementation the unmasked (or per-key-biased), dropout-free
+    attention ops take for these shapes on this backend:
+    "pallas/token_major" (the flash kernels on the projections' own
+    [B, S, H*Dh] operands: `token_major_attention` when the heads fill the
+    kernels' lane blocks), "pallas" (the same kernels behind the head-major
+    border: `scaled_dot_product_attention`), "pallas/shard_map" (either, per
+    device under `mesh`: batch over dp, heads over mp) or "xla".  Chosen by
+    the platform, the shapes and the mesh alone — a lowering error in the
+    chosen path raises, it never selects another path."""
+    return _flash_plan(head_dim, num_heads, q_len, k_len, batch, mesh,
+                       token_major)[0]
+
+
+def _per_key_bias(attn_mask, batch, k_len):
+    """A [B | 1, 1, 1, Sk] additive mask (the padding-mask form every
+    BERT-class encoder builds) as the PER-KEY bias [B, Sk] the kernels
+    stream natively; None for any other mask."""
+    mask = raw(attn_mask)
+    if mask is None or getattr(mask, "ndim", 0) != 4 \
+            or mask.shape[1] != 1 or mask.shape[2] != 1 \
+            or mask.shape[0] not in (1, batch) or mask.shape[-1] != k_len:
+        return None
+    return jnp.broadcast_to(mask[:, 0, 0, :], (batch, k_len))
+
+
+def _flash_plan_for(attn_mask, key_bias, dropout_p, return_weights, *shape,
+                    token_major=False):
+    """`_flash_plan` of an op call: the XLA path unless the call is one the
+    kernels can serve (no mask or a per-key bias, no dropout, no weights
+    output)."""
+    if (attn_mask is not None and key_bias is None) or dropout_p != 0.0 \
+            or return_weights:
+        return "xla", None, False
+    from ..parallel.mesh import current_mesh
+    return _flash_plan(*shape, current_mesh(), token_major)
+
+
 @defop(stochastic=True)
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, scale=None,
                                  return_weights=False, key=None):
     """q,k,v: [B, H, S, D] (head-major). Dispatches to flash attention when
-    profitable; the weights output is only materialized when requested."""
+    profitable (`flash_attention_path`); the weights output is only
+    materialized when requested."""
     # The Pallas kernels stream K/V (fwd, dq) and Q/dO (dkv) blockwise over
     # an arbitrary grid dim with online-softmax state in VMEM scratch, so
     # per-step residency is a few blocks regardless of sequence length —
     # no VMEM-driven length cap. (The fused one-pass backward, which does
-    # pin full Q/dO, self-gates on sq in _fa_bwd.)
-    # a [B,1,1,Sk] additive mask (the padding-mask form every BERT-class
-    # encoder builds) is a PER-KEY bias the kernel streams natively
-    mask_v = attn_mask
-    if mask_v is not None and hasattr(mask_v, "_value"):
-        mask_v = mask_v._value
-    key_bias = None
-    if mask_v is not None and getattr(mask_v, "ndim", 0) == 4 \
-            and mask_v.shape[1] == 1 and mask_v.shape[2] == 1 \
-            and mask_v.shape[0] in (1, q.shape[0]) \
-            and mask_v.shape[-1] == k.shape[-2]:
-        key_bias = mask_v[:, 0, 0, :]
-        if mask_v.shape[0] == 1 and q.shape[0] != 1:  # broadcast batch
-            import jax.numpy as _jnp
-            key_bias = _jnp.broadcast_to(key_bias,
-                                         (q.shape[0], key_bias.shape[-1]))
-    use_flash = (_on_tpu()
-                 and (attn_mask is None or key_bias is not None)
-                 and dropout_p == 0.0
-                 and not return_weights and q.shape[-2] >= 128
-                 and q.shape[-1] in (32, 64, 128, 256)
-                 and q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0)
-    mesh = None
-    if use_flash:
-        from ..parallel.mesh import current_mesh
-        mesh = _manual_mesh(current_mesh())
-        if mesh is not None:
-            # the canonical (dp, mp) axes of parallel/mesh.py, and whole
-            # batches and heads per device; any other mesh takes the XLA
-            # path, which GSPMD can partition
-            use_flash = ({"dp", "mp"} <= set(mesh.axis_names)
-                         and q.shape[0] % mesh.shape["dp"] == 0
-                         and q.shape[1] % mesh.shape["mp"] == 0)
-    if use_flash:
+    # pin full Q/dO, self-gates on sq.)
+    key_bias = _per_key_bias(attn_mask, q.shape[0], k.shape[-2])
+    path, mesh, _ = _flash_plan_for(
+        attn_mask, key_bias, dropout_p, return_weights, q.shape[-1],
+        q.shape[1], q.shape[-2], k.shape[-2], q.shape[0])
+    if path != "xla":
         from .pallas.flash_attention import (flash_attention,
                                              flash_attention_bias)
-        # prescale Q once ([B,H,S,D] pass) instead of scaling every
-        # score tile in fwd + bwd recompute (S^2-proportional VPU work);
-        # the chain rule through the prescale restores dq's scale
-        sc = (q.shape[-1] ** -0.5) if scale is None else scale
         # pallas_call abstractification rejects Tensor wrappers (JAX
         # dropped __jax_array__ support there), while plain jnp ops
         # accept them — unwrap, or the grad trace loses the kernel
-        from ._registry import raw
-        qv, kv, vv = raw(q), raw(k), raw(v)
-        args = ((qv * sc).astype(qv.dtype), kv, vv)
+        args = (raw(q), raw(k), raw(v))
         bh = PartitionSpec("dp", "mp", None, None)
         if key_bias is None:
             fn = functools.partial(flash_attention, causal=is_causal,
-                                   scale=1.0)
+                                   scale=scale)
             specs = (bh,) * 3
         else:
             fn = functools.partial(flash_attention_bias, causal=is_causal,
-                                   scale=1.0)
-            args += (raw(key_bias),)
+                                   scale=scale)
+            args += (key_bias,)
             specs = (bh,) * 3 + (PartitionSpec("dp", None),)
         if mesh is not None:  # batch over dp, heads over mp, per device
             fn = jax.shard_map(fn, mesh=mesh, in_specs=specs,
@@ -215,6 +247,68 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
         w_d = jnp.where(keep, w / (1.0 - dropout_p), 0.0)
         out = jnp.einsum("bhqk,bhkd->bhqd", w_d, v)
     return out, (w if return_weights else None)
+
+
+@defop(stochastic=True)
+def token_major_attention(q, k=None, v=None, num_heads=1, attn_mask=None,
+                          dropout_p=0.0, is_causal=False, scale=None,
+                          key=None):
+    """Attention on the projections' own layout: q [B, Sq, E] and k, v
+    [B, Sk, E] with the `num_heads` heads side by side, as the projection
+    GEMMs write them — or q the fused projection [B, S, 3E] (q, k, v side
+    by side) with k = v = None. Returns [B, Sq, E], as the output
+    projection reads it.
+
+    Where `flash_attention_path(..., token_major=True)` says so, the flash
+    kernels read and write these operands where they lie, and no relayout
+    pass stands around them, forward or backward. Everywhere else the heads
+    are split and `scaled_dot_product_attention` does the work."""
+    q, k, v = raw(q), raw(k), raw(v)
+    fused = k is None
+    b, sq = q.shape[0], q.shape[1]
+    e = q.shape[-1] // 3 if fused else q.shape[-1]
+    sk = sq if fused else k.shape[1]
+    hd = e // num_heads
+    key_bias = _per_key_bias(attn_mask, b, sk)
+    _, mesh, direct = _flash_plan_for(
+        attn_mask, key_bias, dropout_p, False, hd, num_heads, sq, sk, b,
+        token_major=True)
+    if not direct:
+        if fused:
+            q, k, v = jnp.moveaxis(q.reshape(b, sq, 3, num_heads, hd), 2, 0)
+        q, k, v = (x.reshape(b, -1, num_heads, hd).transpose(0, 2, 1, 3)
+                   for x in (q, k, v))
+        out, _ = scaled_dot_product_attention.__raw_fn__(
+            q, k, v, attn_mask, dropout_p, is_causal, scale, key=key)
+        return out.transpose(0, 2, 1, 3).reshape(b, sq, e)
+    from .pallas.flash_attention import flash_attention_token_major
+    if mesh is None:
+        return flash_attention_token_major(q, k, v, key_bias, num_heads,
+                                           is_causal, scale)
+    # batch over dp, heads over mp, per device; of the fused projection
+    # each device holds its heads' q, k and v
+    heads = num_heads // mesh.shape["mp"]
+    lanes = PartitionSpec("dp", None, "mp")
+    if fused:
+        args = (q.reshape(b, sq, 3, e),)
+        specs = (PartitionSpec("dp", None, None, "mp"),)
+    else:
+        args, specs = (q, k, v), (lanes,) * 3
+    if key_bias is not None:
+        args += (key_bias,)
+        specs += (PartitionSpec("dp", None),)
+
+    def per_device(*xs):
+        bias = xs[-1] if key_bias is not None else None
+        if fused:
+            return flash_attention_token_major(
+                xs[0].reshape(xs[0].shape[:2] + (-1,)), None, None, bias,
+                heads, is_causal, scale)
+        return flash_attention_token_major(*xs[:3], bias, heads, is_causal,
+                                           scale)
+
+    return jax.shard_map(per_device, mesh=mesh, in_specs=specs,
+                         out_specs=lanes, check_vma=False)(*args)
 
 
 def _is_quantized_kv(kv):

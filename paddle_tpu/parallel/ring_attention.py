@@ -161,16 +161,18 @@ def _ring_chunked(q, k, v, axis_name, causal, scale):
 def _block_fwd(q, k, v, scale, rel, interpret):
     """Normalized (o, lse[B,H,S]) of q against one ring block, via the
     streaming Pallas forward. rel selects full/diag-causal/none masking."""
-    from ..ops.pallas.flash_attention import LSE_LANES, _flash_fwd_lse
+    from ..ops.pallas.flash_attention import _flash_fwd_lse, stat_rows
     b, h, s, d = q.shape
 
+    def run(causal):
+        o, lse = _flash_fwd_lse(q, k, v, scale, causal, 512, 512, interpret)
+        return o.astype(jnp.float32), stat_rows(lse, h, d)
+
     def full(_):
-        o, lse = _flash_fwd_lse(q, k, v, scale, False, 512, 512, interpret)
-        return o.astype(jnp.float32), lse[:, :, 0].reshape(b, h, s)
+        return run(False)
 
     def diag(_):
-        o, lse = _flash_fwd_lse(q, k, v, scale, True, 512, 512, interpret)
-        return o.astype(jnp.float32), lse[:, :, 0].reshape(b, h, s)
+        return run(True)
 
     def none(_):
         return (jnp.zeros((b, h, s, d), jnp.float32),
@@ -247,15 +249,13 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret):
 
 
 def _ring_flash_bwd(axis_name, causal, scale, interpret, res, g):
-    from ..ops.pallas.flash_attention import LSE_LANES
+    from ..ops.pallas.flash_attention import stat_tiles
     q, k, v, out, lse = res
     n = _axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s, d = q.shape
     perm = [(j, (j + 1) % n) for j in range(n)]
-    # _flash_bwd consumes lse in its [b*h, s, LSE_LANES] layout
-    lse_lanes = jnp.broadcast_to(lse.reshape(b * h, s, 1),
-                                 (b * h, s, LSE_LANES))
+    lse_lanes = stat_tiles(lse, d)  # as _flash_bwd takes it
 
     def body(carry, i):
         dq_acc, dk_trav, dv_trav, k_cur, v_cur = carry

@@ -532,6 +532,10 @@ SPECS = {
         _sdpa_fn,
         [_any((1, 2, 3, 4)), _any((1, 2, 3, 4), 3),
          _any((1, 2, 3, 4), 4)]),
+    "token_major_attention": lambda: (
+        lambda qkv: OP("token_major_attention")(qkv, num_heads=2,
+                                                is_causal=True),
+        [_any((1, 3, 24))]),
     "fused_multi_head_attention": lambda: (
         lambda x, qkv_w, out_w: OP("fused_multi_head_attention")(
             x, qkv_w, None, out_w, None, 2),

@@ -15,6 +15,8 @@ never at import, in a skipif, in parametrize arguments or in conftest.py —
 so every xdist worker collects the same tests and only the worker that is
 handed this file loads libtpu.  The compiles run in this process.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -469,27 +471,164 @@ def test_stateful_decode_step_works_on_both_caches_in_place(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_state
 
 
-def test_flash_under_dp_mp_mesh_compiles(mesh4, monkeypatch):
-    """Training under Fleet dp x mp: `scaled_dot_product_attention` traced
-    with a current mesh runs the flash kernels per device (batch over dp,
-    heads over mp) instead of handing GSPMD a kernel it cannot split."""
+@pytest.mark.parametrize("entry", ["head_major", "token_major",
+                                   "token_major_fused"])
+def test_flash_under_dp_mp_mesh_compiles(mesh4, monkeypatch, entry):
+    """Training under Fleet dp x mp: the attention ops traced with a
+    current mesh run the flash kernels per device (batch over dp, heads
+    over mp) instead of handing GSPMD a kernel it cannot split — head-major
+    operands through `scaled_dot_product_attention`, and the projections as
+    they lie (three of them, or the fused one with every device's heads of
+    q, k and v) through `token_major_attention`."""
     from paddle_tpu.core.autograd import functional_trace
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.ops import attention
     from paddle_tpu.parallel.mesh import mesh_guard
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    x = jax.ShapeDtypeStruct(
-        (8, 16, 1024, 64), jnp.bfloat16,
-        sharding=NamedSharding(mesh4, P("dp", "mp", None, None)))
+    assert attention.flash_attention_path(
+        DH, H, 1024, 1024, 8, mesh4,
+        token_major=entry != "head_major") == "pallas/shard_map"
+    # 8 sequences do not split over a dp of 3, nor 16 heads over an mp of 3
+    assert attention.flash_attention_path(DH, 15, 1024, 1024, 8,
+                                          mesh4) == "xla"
 
-    def loss(q, k, v):
+    def sds(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=NamedSharding(mesh4, spec))
+
+    if entry == "head_major":
+        args = (sds((8, H, 1024, DH), P("dp", "mp", None, None)),) * 3
+    elif entry == "token_major":
+        args = (sds((8, 1024, H * DH), P("dp", None, "mp")),) * 3
+    else:
+        args = (sds((8, 1024, 3 * H * DH), P("dp", None, None)),)
+
+    def loss(*qkv):
         with mesh_guard(mesh4), functional_trace():
-            out, _ = attention.scaled_dot_product_attention(
-                Tensor(q), Tensor(k), Tensor(v), is_causal=True)
+            if entry == "head_major":
+                out, _ = attention.scaled_dot_product_attention(
+                    *map(Tensor, qkv), is_causal=True)
+            else:
+                out = attention.token_major_attention(
+                    *map(Tensor, qkv), num_heads=H, is_causal=True)
         return out._value.astype(jnp.float32).sum()
 
-    assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+    assert _kernels(jax.grad(loss, argnums=tuple(range(len(args)))),
+                    *args) == 3
+
+
+_VIEWS = {"bitcast", "get-tuple-element", "tuple", "copy-start", "copy-done",
+          "slice-start", "slice-done", "ConcatBitcast"}
+_ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u16": 2, "u32": 4, "pred": 1}
+
+
+def _passes_around_kernels(text, q_bytes):
+    """The ops of a compiled program's entry computation that produce a
+    kernel's operand or consume its result, hold an array of `q_bytes` or
+    more, and are neither a kernel, a GEMM fusion nor a parameter: each is
+    a pass of its own over that array (a `copy`, a transpose, a pad, a
+    slice, a concatenate, a prescale).  Views and XLA's own moves between
+    memory spaces (`copy-start`/`-done`, sliced prefetches) are looked
+    through."""
+    def largest(shape):
+        sizes = [0]
+        for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape):
+            if dt in _ITEM:
+                sizes.append(_ITEM[dt] * int(np.prod(
+                    [int(d) for d in dims.split(",") if d])))
+        return max(sizes)
+
+    gemms = {m.group(1) for m in re.finditer(
+        r"\n(%[\w.\-]+) \([^\n]*\{\n(.*?)\n\}", text, re.S)
+        if " convolution(" in m.group(2)}
+    ins, users = {}, {}
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\((.*)", ln)
+        if not m:
+            continue
+        name, shape, kind, rest = m.groups()
+        if kind == "custom-call":
+            kind = re.search(r'custom_call_target="([^"]+)"', rest).group(1)
+        elif kind == "fusion" and re.search(r"calls=(%[\w.\-]+)",
+                                            rest).group(1) in gemms:
+            kind = "gemm"
+        operands = re.findall(r"%[\w.\-]+", re.split(
+            r", (?:calls|custom_call_target|kind)=", rest)[0])
+        ins[name] = (kind, largest(shape), operands)
+        for o in operands:
+            users.setdefault(o, []).append(name)
+
+    def beyond_views(name, step):
+        found, todo = [], [name]
+        while todo:
+            for n in step(todo.pop()):
+                (todo if ins[n][0] in _VIEWS else found).append(n)
+        return found
+
+    passes = set()
+    for name in [n for n, i in ins.items() if i[0] == "tpu_custom_call"]:
+        for n in beyond_views(name, lambda n: [o for o in ins[n][2]
+                                               if o in ins]) \
+                + beyond_views(name, lambda n: users.get(n, [])):
+            kind, size, _ = ins[n]
+            if size >= q_bytes and kind not in ("tpu_custom_call", "gemm",
+                                                "parameter"):
+                passes.add(f"{kind} {n}")
+    return sorted(passes)
+
+
+@pytest.mark.parametrize("layer", ["gpt2_block", "encoder_layer"])
+def test_no_relayout_stands_around_the_flash_kernels(one_chip, monkeypatch,
+                                                     layer):
+    """One GPT2Block at 8 x 1,024 and one nn.TransformerEncoderLayer at
+    16 x 512 (16 heads of 64, bf16), forward + backward, compiled for the
+    described chip: the three kernels under their names, fed by the
+    projection GEMMs and feeding the gradient GEMMs with NOTHING between
+    them that moves a q-sized array (16.8 MB); the fused projection's
+    gradient comes out of the fused backward as one array.  Before PR 33
+    eight such passes stood around a layer's kernels (split heads, prescale
+    q, merge heads; dO to head-major, dq, dk, dv back, a pad-and-add into
+    d(qkv), the prescale's chain rule): 25 ms of a 189 ms train step."""
+    from paddle_tpu import nn
+    from paddle_tpu.core.autograd import functional_trace
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.models.gpt2 import GPT2Block, GPT2Config
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    if layer == "gpt2_block":
+        batch, seq = 8, 1024
+        net = GPT2Block(GPT2Config(hidden_size=H * DH, num_heads=H,
+                                   num_layers=1, dropout=0.0))
+    else:
+        batch, seq = 16, 512
+        net = nn.TransformerEncoderLayer(H * DH, H, 4 * H * DH, dropout=0.0,
+                                         activation="gelu")
+    assert attention.flash_attention_path(
+        DH, H, seq, seq, batch, token_major=True) == "pallas/token_major"
+    net.train()
+    net.to(dtype="bfloat16")
+    s = _sds(one_chip)
+    params = {k: s(v.shape, v.dtype)
+              for k, v in net.functional_state()[0].items()}
+
+    def loss(p, x):
+        saved = net.functional_state()
+        net.load_functional_state(p, None)
+        try:
+            with functional_trace():
+                return net(Tensor(x))._value.astype(jnp.float32).sum()
+        finally:
+            net.load_functional_state(*saved)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, s((batch, seq, H * DH), jnp.bfloat16)).compile().as_text()
+    assert _kernel_names_in(text) == ["flash_bwd_delta", "flash_bwd_fused",
+                                      "flash_fwd"]
+    assert text.count("tpu_custom_call") == 3
+    assert _passes_around_kernels(text, batch * seq * H * DH * 2) == []
 
 
 def _zaya_programs(one_chip, layers, rows, blocks, width, tokens=512):
