@@ -28,6 +28,7 @@ import itertools
 import math
 from collections import deque
 import os
+import statistics
 import threading
 import time
 from concurrent.futures import Future
@@ -37,6 +38,7 @@ import numpy as np
 
 from ..observability import compile_tracker as _compile_tracker
 from ..observability import flight_recorder as _flight
+from ..observability import gc_tracker as _gc_tracker
 from ..observability import log as _obs_log
 from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
@@ -242,6 +244,23 @@ HEALTH_CODES = {"ok": 0.0, "degraded": 1.0, "stalled": 2.0}
 
 #: dispatches whose expert counters `stats()["experts"]["dispatches"]` keeps
 EXPERT_RING = 4096
+#: what the default loop issues, as `stats()["dispatch_ahead"]` splits
+#: `found_idle` and the rounds' `kind` names them
+DISPATCH_KINDS = ("decode", "prefill", "verify")
+#: rounds whose lengths `stats()["round_phases"]["round_ms"]` is read from
+#: (96 s of the fastest cell's 170 rounds a second)
+ROUND_RING = 16384
+#: the longest rounds that keep their phases (`round_ms["slowest"]`)
+SLOWEST_KEPT = 8
+# A round stood still, and is logged at WARNING, when it is longer than
+# both SLOW_ROUND_MS and SLOW_ROUND_X_MEDIAN times the median of the
+# newest SLOW_ROUND_MEDIAN_OF rounds (at least SLOW_ROUND_AFTER of them
+# since the reset); at most one line every SLOW_ROUND_LOG_EVERY_S.
+SLOW_ROUND_MS = 50.0
+SLOW_ROUND_X_MEDIAN = 8
+SLOW_ROUND_MEDIAN_OF = 256
+SLOW_ROUND_AFTER = 8
+SLOW_ROUND_LOG_EVERY_S = 1.0
 
 # What the paged engine's thread does in a round, one vocabulary for
 # every loop (split, unified, unified async):
@@ -269,23 +288,35 @@ class _RoundPhases:
     boundary (a `dispatch` phase left without an exception). A round is
     one iteration of the engine's loop that dispatched something;
     `round` numbers them for the life of the engine (the dispatch
-    spans' `round` attribute). Readers and `reset` come from other
-    threads: the clock is read under the lock, so that a boundary never
-    lies before the reset it follows."""
+    spans' `round` attribute). Every round's `(at_s, ms, kind)` goes to
+    a ring of the newest ROUND_RING (`round_ms`: percentiles, by kind);
+    the SLOWEST_KEPT longest keep their phases, the seconds the
+    collector ran inside them and the programs compiled inside them
+    (`round_ms["slowest"]`; its head is `longest_round`); a round in
+    which a dispatch landed on a device that had run dry (`starved`)
+    adds its phases to `starved_seconds`. Readers and `reset` come from
+    other threads: the
+    clock is read under the lock, so that a boundary never lies before
+    the reset it follows."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._stack = []   # open phases, innermost last
         self._t = None     # the last boundary; None: no thread running
         self.round = 0
+        self._warned_at = -SLOW_ROUND_LOG_EVERY_S
         self.reset()
 
     def reset(self):
         with self._lock:
             now = time.perf_counter()
             self._seconds = dict.fromkeys(ROUND_PHASES + ("other",), 0.0)
+            self._starved_seconds = dict(self._seconds)
+            self._starved_rounds = 0
             self._dispatches = 0
-            self._longest = None
+            self._ring = deque(maxlen=ROUND_RING)
+            self._slowest = []   # longest first, at most SLOWEST_KEPT
+            self._compile_mark = _compile_tracker.mark()
             self._t_reset = now
             self._open_round(now)
             if self._t is not None:
@@ -295,6 +326,8 @@ class _RoundPhases:
         self._round = dict.fromkeys(self._seconds, 0.0)
         self._round_t0 = now
         self._kinds = []
+        self._starved = False
+        self._gc0 = _gc_tracker.seconds()
 
     def _boundary(self):
         """Caller holds the lock. Returns the boundary's instant."""
@@ -326,22 +359,67 @@ class _RoundPhases:
             if kind not in self._kinds:
                 self._kinds.append(kind)
 
+    def starved(self):
+        """A dispatch of the round in progress landed on a device that
+        had run dry."""
+        with self._lock:
+            self._starved = True
+
     def close_round(self):
         """Between two iterations of the engine's loop: if the one that
-        ended dispatched anything it was a round, and the longest round
-        of the window keeps its phases."""
+        ended dispatched anything it was a round: its length goes to the
+        ring, its phases to `starved_seconds` if it starved the device
+        and to `slowest` if it is among the longest; one that stood
+        still is logged."""
+        slow = None
         with self._lock:
             now = self._boundary()
             if self._kinds:
                 ms = (now - self._round_t0) * 1e3
-                if self._longest is None or ms > self._longest["ms"]:
-                    self._longest = {
+                kind = "+".join(self._kinds)
+                if self._starved:
+                    self._starved_rounds += 1
+                    for k, v in self._round.items():
+                        self._starved_seconds[k] += v
+                slowest = self._slowest
+                among = (len(slowest) < SLOWEST_KEPT
+                         or ms > slowest[-1]["ms"])
+                stood = (ms > SLOW_ROUND_MS
+                         and len(self._ring) >= SLOW_ROUND_AFTER
+                         and now - self._warned_at >= SLOW_ROUND_LOG_EVERY_S
+                         and ms > SLOW_ROUND_X_MEDIAN * statistics.median(
+                             e[1] for e in itertools.islice(
+                                 reversed(self._ring), SLOW_ROUND_MEDIAN_OF)))
+                if among or stood:
+                    rec = {
                         "ms": ms, "at_s": self._round_t0 - self._t_reset,
-                        "kind": "+".join(self._kinds), "round": self.round,
+                        "kind": kind, "round": self.round,
                         "phases_ms": {k: v * 1e3
-                                      for k, v in self._round.items()}}
+                                      for k, v in self._round.items()},
+                        "gc_ms": (_gc_tracker.seconds() - self._gc0) * 1e3,
+                        # read from the compile log only here, for the
+                        # few rounds that are kept: an event's `ts` is
+                        # the instant its compile ended
+                        "compiles": sum(
+                            ev["ts"] >= self._round_t0 for ev in
+                            _compile_tracker.events_since(
+                                self._compile_mark))}
+                    if among:
+                        slowest.append(rec)
+                        slowest.sort(key=lambda r: -r["ms"])
+                        del slowest[SLOWEST_KEPT:]
+                    if stood:
+                        self._warned_at, slow = now, rec
+                self._ring.append((self._round_t0 - self._t_reset, ms, kind))
                 self.round += 1
             self._open_round(now)
+        if slow is not None:
+            top = sorted(slow["phases_ms"].items(), key=lambda kv: -kv[1])
+            _logger.warning(
+                "[slow round] %.1f ms (%s) round %d: %s, gc %.1f, "
+                "compiles %d", slow["ms"], slow["kind"], slow["round"],
+                ", ".join(f"{k} {v:.1f}" for k, v in top[:2]),
+                slow["gc_ms"], slow["compiles"])
 
     def snapshot(self):
         with self._lock:
@@ -349,11 +427,36 @@ class _RoundPhases:
             if self._t is not None:  # the phase open right now
                 cur = self._stack[-1] if self._stack else "other"
                 seconds[cur] += time.perf_counter() - self._t
-            longest = self._longest or {
-                "ms": 0.0, "at_s": 0.0, "kind": "", "round": None,
-                "phases_ms": dict.fromkeys(seconds, 0.0)}
-            return {"seconds": seconds, "dispatches": self._dispatches,
-                    "longest_round": dict(longest)}
+            slowest = [dict(r, phases_ms=dict(r["phases_ms"]))
+                       for r in self._slowest]
+            ring = list(self._ring)
+            out = {"seconds": seconds, "dispatches": self._dispatches,
+                   "starved_seconds": dict(self._starved_seconds),
+                   "starved_rounds": self._starved_rounds}
+        # the ring is sorted outside the lock: the engine thread takes
+        # it at every boundary
+        by_kind = {}
+        for _at, ms, kind in ring:
+            by_kind.setdefault(kind, []).append(ms)
+        out["round_ms"] = dict(
+            _percentiles([e[1] for e in ring]),
+            by_kind={k: _percentiles(v) for k, v in sorted(by_kind.items())},
+            slowest=slowest)
+        out["longest_round"] = dict(slowest[0]) if slowest else {
+            "ms": 0.0, "at_s": 0.0, "kind": "", "round": None,
+            "phases_ms": dict.fromkeys(seconds, 0.0), "gc_ms": 0.0,
+            "compiles": 0}
+        return out
+
+
+def _percentiles(ms):
+    """`{"count", "p50_ms", "p99_ms"}` of a list of round lengths, by
+    the rank `stats()` reads its latencies at."""
+    ms = sorted(ms)
+    n = len(ms)
+    return {"count": n,
+            "p50_ms": ms[min(n - 1, int(0.50 * n))] if n else 0.0,
+            "p99_ms": ms[min(n - 1, int(0.99 * n))] if n else 0.0}
 
 
 class _Phase:
@@ -1419,6 +1522,14 @@ class PagedGenerationServer:
         self._drains: dict[str, int] = {}
         self._late_rows = 0
         self._charged_to = 0.0
+        # did the device run dry (`_probe_device`): one output array of
+        # the newest dispatch issued and not yet read (None: everything
+        # issued was read); the dispatches that landed with one in
+        # flight and those of them that found it finished, by the kind
+        # issued
+        self._newest_out = None
+        self._probed = dict.fromkeys(DISPATCH_KINDS, 0)
+        self._found_idle = dict.fromkeys(DISPATCH_KINDS, 0)
         # steady-state device-argument reuse (async window rounds): the
         # whole plan argument set is round-invariant per (slots, seqs,
         # drafts) signature — caching the uploaded arrays is most of
@@ -1572,6 +1683,7 @@ class PagedGenerationServer:
         _compile_tracker.register_in_flight_probe(self._ops_in_flight)
         _compile_tracker.add_listener(self._on_compile_event)
         self._compile_mark = _compile_tracker.mark()
+        _gc_tracker.install()   # a round reads the collector's seconds
         if expose_port is not None:
             # asking for a scrape endpoint IS opting into metrics — a
             # /metrics page of zeros because the registry gate stayed
@@ -1848,9 +1960,8 @@ class PagedGenerationServer:
         """Note the dispatch about to run (compile-charge target) and,
         once, the decoder's wire-byte level: from there on every byte
         is charged at the next read (`_charge_dispatch` moves the
-        level), also those of a dispatch issued before that read."""
-        if self._ledger is None:
-            return
+        level), also those of a dispatch issued before that read. The
+        caller has a ledger: without one no `parts` are built."""
         self._attr_parts = parts
         if self._decoder.tp_degree > 1 and self._wire_mark is None:
             self._wire_mark = self._decoder.wire_stats()["bytes_total"]
@@ -2186,6 +2297,7 @@ class PagedGenerationServer:
         rids = [self._slots[i]["req"].rid for i in slot_idx
                 if self._slots[i] is not None]
         self._engine_exception(where, e, rids)
+        self._newest_out = None   # what was in flight is read or given up
         if self._recovery is None:
             for i in slot_idx:
                 s = self._slots[i]
@@ -2995,6 +3107,7 @@ class PagedGenerationServer:
         if self._thread is not None:
             self._thread.join(timeout=120)
             self._thread = None
+            self._log_rounds()
         with self._lock:
             pending = list(self._queue)
             self._queue.clear()
@@ -3012,6 +3125,41 @@ class PagedGenerationServer:
             # queued requests failed above stay journal-live on
             # purpose: a restarted server may still re-admit them
             self._journal.flush()
+
+    def _log_rounds(self):
+        """One INFO line as the engine stops: the rounds since the last
+        reset by kind, how often a dispatch found the device idle, and
+        the host's phases in the rounds that starved it beside all
+        rounds. How a run with no profiler and no reader of `stats()`
+        (a `--trace 0` benchmark run) leaves the counters behind."""
+        rp = self._phases.snapshot()
+        rounds = rp["round_ms"]
+        if not rounds["count"]:
+            return
+        with self._lock:
+            probed, found = dict(self._probed), dict(self._found_idle)
+
+        def a_round(seconds, n):
+            return ", ".join(f"{k} {1e3 * v / n:.2f}"
+                             for k, v in seconds.items() if v)
+
+        kinds = "; ".join(
+            f"{k} {v['count']}: {v['p50_ms']:.1f} / {v['p99_ms']:.1f}"
+            for k, v in rounds["by_kind"].items())
+        idle = ", ".join(f"{k} {found[k]} of {probed[k]}"
+                         for k in DISPATCH_KINDS if probed[k])
+        line = (f"[rounds] {rounds['count']} rounds since the reset, ms "
+                f"p50 / p99 {rounds['p50_ms']:.1f} / "
+                f"{rounds['p99_ms']:.1f} ({kinds}); dispatches that found "
+                f"the device idle when they landed: {sum(found.values())} "
+                f"of {sum(probed.values())} issued with one in flight"
+                + (f" ({idle})" if idle else ""))
+        if rp["starved_rounds"]:
+            line += (f"; ms a round in the {rp['starved_rounds']} rounds "
+                     f"that starved it: "
+                     f"{a_round(rp['starved_seconds'], rp['starved_rounds'])}"
+                     f"; in all: {a_round(rp['seconds'], rounds['count'])}")
+        _logger.info("%s", line)
 
     def reset_stats(self):
         """Zero the measurement window — latency AND the TTFT samples
@@ -3049,6 +3197,8 @@ class PagedGenerationServer:
             self._decode_ahead = 0
             self._drains = {}
             self._late_rows = 0
+            self._probed = dict.fromkeys(DISPATCH_KINDS, 0)
+            self._found_idle = dict.fromkeys(DISPATCH_KINDS, 0)
             self._phases.reset()
             self._compile_mark = _compile_tracker.mark()
             self._last_error = None  # a fresh window is healthy again
@@ -3239,6 +3389,17 @@ class PagedGenerationServer:
                                     / (self._decode_issued or 1)),
                     "drains": dict(self._drains),
                     "dropped_rows": self._late_rows,
+                    # did the device run dry (`_probe_device`):
+                    # dispatches that landed while another was unread,
+                    # and those of them that found that one finished,
+                    # by the kind issued; zeros where every dispatch is
+                    # read where it is issued
+                    "probed": sum(self._probed.values()),
+                    "probed_by_kind": dict(self._probed),
+                    "found_idle": dict(self._found_idle),
+                    "found_idle_share": (
+                        sum(self._found_idle.values())
+                        / (sum(self._probed.values()) or 1)),
                 },
                 # the expert layers' counters (zeros for a model with
                 # none), summed over the layers of every dispatch that
@@ -3847,19 +4008,22 @@ class PagedGenerationServer:
                     free_blocks=self.cache.available_block_count)
             if self._sp_degree > 1:
                 self._note_sp_peak(T)
-            parts = self._cost_parts(
-                [(self._slots[i]["req"], n) for i, _start, n, _o in plan])
-            self._attr_begin(parts)
+            parts = None
+            if self._ledger is not None:
+                parts = self._cost_parts(
+                    [(self._slots[i]["req"], n)
+                     for i, _start, n, _o in plan])
+                self._attr_begin(parts)
         self._phases.kind("prefill")
         t0 = time.perf_counter()
         try:
-            with _tracing.span(
-                    "prefill_chunk", packed=T, segments=len(plan),
-                    tokens=int(sum(p[2] for p in plan)),
-                    round=self._phases.round,
-                    request_ids=[self._slots[i]["req"].rid
-                                 for i, *_ in plan]
-                    if _tracing.enabled() else (), **self._rattr()):
+            span = _tracing.span(
+                "prefill_chunk", packed=T, segments=len(plan),
+                tokens=int(sum(p[2] for p in plan)),
+                round=self._phases.round,
+                request_ids=[self._slots[i]["req"].rid for i, *_ in plan]
+                if _tracing.enabled() else (), **self._rattr())
+            with span:
                 with self._phase("plan"):
                     self._maybe_fault("slow_dispatch")
                     self._maybe_fault("ensure_many")
@@ -3919,9 +4083,11 @@ class PagedGenerationServer:
                             jnp.asarray(sample_idx), self.cache.k_blocks,
                             self.cache.v_blocks, sp_args, sp_mode,
                             state=self.cache.state)
+                    self._probe_device("prefill", span)
                     # the pool, the store and the sampler's counts chain
                     # from program to program on the device
                     routed = self._chain(rest)
+                    self._newest_out = tok
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-the-chunk path)
             self._dispatch_failure("prefill", e,
@@ -3943,6 +4109,7 @@ class PagedGenerationServer:
                 tok_h = np.asarray(tok)
                 stopped_h = np.asarray(stopped)
                 routed = self._read_routed(routed)
+                self._was_read(rec)
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             self._read_failure(rec, e)
             return
@@ -4049,6 +4216,52 @@ class PagedGenerationServer:
         self._dispatch_failure(
             rec["where"], e,
             [i for i, seq in rows.items() if self._holds(i, seq)])
+
+    # ---- did the device run dry (ISSUE 34) -------------------------------
+    @staticmethod
+    def _is_ready(out):
+        """Whether the program that writes `out` has finished, without
+        waiting for it."""
+        try:
+            return bool(out.is_ready())
+        except Exception:  # noqa: BLE001 — a program that failed is not
+            return True    # running; the read that follows reports it
+
+    def _probe_device(self, kind, span):
+        """In a `dispatch` phase of the default loop, when the jit call
+        has returned and the new program is queued: has the newest
+        dispatch issued before it, and not yet read, finished? Programs
+        run in order on the chip, so if it has, everything queued before
+        this dispatch has, and the device stood idle until this one
+        landed: counted in `stats()["dispatch_ahead"]` by the `kind`
+        issued, the round marked as one that starved the device
+        (`round_phases.starved_seconds`), `found_idle=0|1` on the
+        dispatch span. One `is_ready()` a dispatch, and it is asked here
+        and not at the top of the phase because that is where the device
+        runs dry: during the host's uploads and call (at the top the
+        chip of PR 34 was busy in all but 2-7 of ~9,900 dispatches). The
+        flag turns true some tenths of a millisecond after the device's
+        last op ends, so the count is a floor. With nothing unread
+        (after a drain; a drafter or k steps a dispatch, which read what
+        they issue at once) there is nothing to ask and nothing is
+        counted: the device is idle by construction, and the span says
+        so all the same (`found_idle=1`)."""
+        out = self._newest_out
+        idle = out is None or self._is_ready(out)
+        if out is not None:
+            with self._lock:
+                self._probed[kind] += 1
+                if idle:
+                    self._found_idle[kind] += 1
+            if idle:
+                self._phases.starved()
+        span.set(found_idle=int(idle))
+
+    def _was_read(self, rec):
+        """The dispatch `rec` was read back: if it was the newest one
+        issued, nothing is in flight."""
+        if rec["out"][0] is self._newest_out:
+            self._newest_out = None
 
     def _charge_read(self, rec, t_now):
         """Charge a dispatch's residents the wall time from its issue
@@ -4613,12 +4826,15 @@ class PagedGenerationServer:
             # chunk rows weigh their fed tokens, step rows their verify
             # positions (drafts + the step token) — the same work split
             # the packed program computes
-            parts = self._cost_parts(
-                [(self._slots[row["slot"]]["req"],
-                  row["n"] if row["kind"] == "chunk"
-                  else row["drafts"].size + 1) for row in rows])
+            parts = None
+            if self._ledger is not None:
+                parts = self._cost_parts(
+                    [(self._slots[row["slot"]]["req"],
+                      row["n"] if row["kind"] == "chunk"
+                      else row["drafts"].size + 1) for row in rows])
+                self._attr_begin(parts)
             plan["cost_parts"] = parts  # _process_round charges its sync
-            self._attr_begin(parts)     # wait to the same rows
+            # wait to the same rows
         self._phases.kind("unified")
         t0 = time.perf_counter()
         try:
@@ -4998,16 +5214,19 @@ class PagedGenerationServer:
                     "decode_dispatch", slots=len(rows), k=k,
                     sampled=bool(sp_mode[0]),
                     free_blocks=self.cache.available_block_count)
-            parts = self._cost_parts(
-                [(self._slots[i]["req"], k) for i in rows])
-            self._attr_begin(parts)
+            parts = None
+            if self._ledger is not None:
+                parts = self._cost_parts(
+                    [(self._slots[i]["req"], k) for i in rows])
+                self._attr_begin(parts)
         self._phases.kind("decode")
         t0 = time.perf_counter()
         try:
-            with _tracing.span(
-                    "decode_dispatch", k=k, round=self._phases.round,
-                    request_ids=[self._slots[i]["req"].rid for i in rows]
-                    if _tracing.enabled() else (), **self._rattr()):
+            span = _tracing.span(
+                "decode_dispatch", k=k, round=self._phases.round,
+                request_ids=[self._slots[i]["req"].rid for i in rows]
+                if _tracing.enabled() else (), **self._rattr())
+            with span:
                 with self._phase("plan"):
                     self._maybe_fault("slow_dispatch")
                     self._maybe_fault("ensure_many")
@@ -5041,9 +5260,11 @@ class PagedGenerationServer:
                                 jnp.asarray(pos), jnp.asarray(act),
                                 tables, self.cache.k_blocks,
                                 self.cache.v_blocks, sp_args)
+                    self._probe_device("decode", span)
                     # the pool, the store and the sampler's counts chain
                     # from program to program on the device
                     routed = self._chain(rest)
+                    self._newest_out = toks
         except Exception as e:  # noqa: BLE001 — the recovery ladder
             # (or, with recovery off, the legacy fail-all path)
             self._dispatch_failure("decode", e, list(rows))
@@ -5067,6 +5288,7 @@ class PagedGenerationServer:
                 toks = np.asarray(toks)        # [S], or [k, S]
                 stops = np.asarray(stopped)
                 routed = self._read_routed(routed)
+                self._was_read(rec)
                 if toks.ndim == 1:
                     toks, stops = toks[None], stops[None]  # [1, S]
         except Exception as e:  # noqa: BLE001 — the recovery ladder
@@ -5190,19 +5412,22 @@ class PagedGenerationServer:
                     "verify_dispatch", rows=plan.rows, proposed=proposed,
                     free_blocks=self.cache.available_block_count)
             P = plan.dlen.shape[0]
-            parts = self._cost_parts(
-                [(self._slots[i]["req"], plan.drafts[r].size + 1)
-                 for r, i in enumerate(plan.slots)])
-            self._attr_begin(parts)
+            parts = None
+            if self._ledger is not None:
+                parts = self._cost_parts(
+                    [(self._slots[i]["req"], plan.drafts[r].size + 1)
+                     for r, i in enumerate(plan.slots)])
+                self._attr_begin(parts)
         self._phases.kind("verify")
         t0 = time.perf_counter()
         try:
-            with _tracing.span(
-                    "verify_dispatch", segments=plan.rows,
-                    proposed=proposed, round=self._phases.round,
-                    request_ids=[self._slots[i]["req"].rid
-                                 for i in plan.slots]
-                    if _tracing.enabled() else (), **self._rattr()):
+            span = _tracing.span(
+                "verify_dispatch", segments=plan.rows,
+                proposed=proposed, round=self._phases.round,
+                request_ids=[self._slots[i]["req"].rid
+                             for i in plan.slots]
+                if _tracing.enabled() else (), **self._rattr())
+            with span:
                 with self._phase("plan"):
                     self._maybe_fault("slow_dispatch")
                     self._maybe_fault("ensure_many")
@@ -5233,6 +5458,7 @@ class PagedGenerationServer:
                             jnp.asarray(plan.sample_idx),
                             jnp.asarray(plan.dlen), self.cache.k_blocks,
                             self.cache.v_blocks, sp_args, sp_mode)
+                    self._probe_device("verify", span)
                 with self._phase("read_back"):
                     vtok_h = np.asarray(vtok)
                     acc_h = np.asarray(accepted)

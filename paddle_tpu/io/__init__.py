@@ -11,11 +11,18 @@ import itertools
 import math
 import queue
 import threading
+import time
+from collections import deque
 
 import numpy as np
 
 from ..core import rng as rng_mod
 from ..core.tensor import Tensor
+from ..observability import gc_tracker as _gc_tracker
+from ..observability import log as _obs_log
+from ..observability import tracing as _tracing
+
+_logger = _obs_log.get_logger(__name__)
 
 
 class Dataset:
@@ -253,6 +260,59 @@ def _warn_loader_fallback(what, e):
                       stacklevel=3)
 
 
+#: waits `loader_stats()` keeps of an iterator: the newest LOADER_RING
+LOADER_RING = 16384
+#: a wait this long is logged at WARNING (not an iterator's first, which
+#: is its workers starting)
+LOADER_SLOW_WAIT_S = 1.0
+
+
+class _LoaderWaits:
+    """What the consumer of ONE iterator of a DataLoader waited for its
+    batches: `loader_stats()`. Kept apart from the loader, so that the
+    record outlives it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.batches = 0
+        self.wait_s = deque(maxlen=LOADER_RING)
+        self.gap_s = deque(maxlen=LOADER_RING)
+        self.wait_max_s = 0.0
+        self.gap_max_s = 0.0
+
+    def note(self, wait_s, gap_s):
+        with self._lock:
+            self.batches += 1
+            self.wait_s.append(wait_s)
+            self.gap_s.append(gap_s)
+            self.wait_max_s = max(self.wait_max_s, wait_s)
+            self.gap_max_s = max(self.gap_max_s, gap_s)
+
+    def snapshot(self):
+        with self._lock:
+            return {"batches": self.batches, "wait_s": list(self.wait_s),
+                    "gap_s": list(self.gap_s),
+                    "wait_max_s": self.wait_max_s,
+                    "gap_max_s": self.gap_max_s}
+
+
+_newest_waits = _LoaderWaits()   # of the newest iterator of any loader
+
+
+def loader_stats():
+    """What the newest DataLoader iterator's consumer waited, always on:
+    `batches` handed out; for the newest LOADER_RING of them `wait_s`,
+    the seconds each `next()` took, and `gap_s`, the seconds between the
+    batch before being handed out and this `next()` (the consumer's
+    step, as the loader sees it; 0 for the first); `wait_max_s` and
+    `gap_max_s` over all of them. Stays readable after the iterator and
+    its loader are gone. It is the record of ONE iterator, the newest of
+    the process: a program that iterates a second loader (an evaluation
+    set) or the same one again reads that one's here, and each loader's
+    own through `DataLoader.wait_stats()`."""
+    return _newest_waits.snapshot()
+
+
 class DataLoader:
     def __init__(self, dataset, feed_list=None, places=None,
                  return_list=True, batch_sampler=None, batch_size=1,
@@ -280,6 +340,8 @@ class DataLoader:
             self.batch_sampler = BatchSampler(dataset, shuffle=shuffle,
                                               batch_size=batch_size,
                                               drop_last=drop_last)
+        self._waits = _LoaderWaits()   # of this loader's newest iterator
+        _gc_tracker.install()   # a wait reads the collector's seconds
 
     def __len__(self):
         if self._iterable_mode:
@@ -313,10 +375,46 @@ class DataLoader:
                 yield self.collate_fn([self.dataset[i] for i in idx_batch])
 
     def __iter__(self):
-        if self.num_workers <= 0:
-            yield from self._iter_batches()
-            return
-        yield from self._multiprocess_iter()
+        global _newest_waits
+        _newest_waits = self._waits = waits = _LoaderWaits()
+        return self._hand_out(
+            self._iter_batches() if self.num_workers <= 0
+            else self._multiprocess_iter(), waits)
+
+    def wait_stats(self):
+        """`loader_stats()` of this loader's newest iterator, whatever
+        other loaders the process iterates."""
+        return self._waits.snapshot()
+
+    @staticmethod
+    def _hand_out(batches, waits):
+        """Every path's batches go to the consumer through here: each
+        `next()` is timed (`pt:loader_wait`, `loader_stats()`), and one
+        that stood still is logged with the step before it and the
+        seconds the collector ran inside it."""
+        t_out = None
+        try:
+            while True:
+                t0 = time.perf_counter()
+                gc0 = _gc_tracker.seconds()
+                with _tracing.span("loader_wait"):
+                    try:
+                        batch = next(batches)
+                    except StopIteration:
+                        return
+                t1 = time.perf_counter()
+                wait, gap = t1 - t0, 0.0 if t_out is None else t0 - t_out
+                waits.note(wait, gap)
+                if wait > LOADER_SLOW_WAIT_S and t_out is not None:
+                    _logger.warning(
+                        "[slow loader] waited %.3f s for batch %d (the "
+                        "step before it took %.3f s; gc %.3f s inside "
+                        "the wait)", wait, waits.batches - 1, gap,
+                        _gc_tracker.seconds() - gc0)
+                t_out = t1
+                yield batch
+        finally:
+            batches.close()
 
     def _multiprocess_iter(self):
         """Worker processes do __getitem__ + collate (ref:

@@ -21,6 +21,11 @@ Pillars, shared by serving and training:
     boundaries (`serving_xla_compiles_total{program,in_flight,shard}`),
     always on, with a window API the benchmark's cells use to prove
     measurement windows compile-clean.
+  * `gc_tracker` — the collector's twin of `compile_tracker`
+    (ISSUE 34): a `gc.callbacks` hook installed with the first engine
+    or DataLoader, a running total of collection seconds that a
+    serving round and a loader wait read at their two ends, and a
+    `pt:gc` span a collection.
   * `flight_recorder` — bounded ring buffer of structured engine
     events + the stall watchdog that auto-dumps it (no-op when
     disabled, like all telemetry).
@@ -59,6 +64,7 @@ from __future__ import annotations
 
 from . import attribution, capacity  # noqa: F401
 from . import compile_tracker, exporter, flight_recorder  # noqa: F401
+from . import gc_tracker  # noqa: F401
 from . import log, metrics, slo, timeline, trace_context  # noqa: F401
 from . import tracing  # noqa: F401
 from .attribution import (CostReport, ResourceLedger,  # noqa: F401

@@ -263,6 +263,14 @@ class _Span:
             self._ev = self._tracer._open(self._name, self._attrs)
         return self._ev
 
+    def set(self, **attrs):
+        """Attributes learned while the span is open (the readiness
+        probe of a dispatch span, ISSUE 34): stats of the annotation
+        and, when tracing is on, keys of the event."""
+        self._ann.set_metadata(**attrs)
+        if self._ev is not None:
+            self._ev.update(attrs)
+
     def __exit__(self, *exc):
         if self._ev is not None:
             self._tracer._close(self._ev)

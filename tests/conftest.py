@@ -52,6 +52,28 @@ def _seed():
     yield
 
 
+@pytest.fixture
+def library_log():
+    """The records the library logger takes while the test runs (it does
+    not propagate to the root logger, so `caplog` sees none of them)."""
+    import logging
+
+    from paddle_tpu.observability import log
+
+    records = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            records.append(record)
+
+    handler = Keep()
+    log.get_logger().addHandler(handler)
+    try:
+        yield records
+    finally:
+        log.get_logger().removeHandler(handler)
+
+
 # The tier-1 suite compiles >1000 jitted programs in ONE process; every
 # live XLA CPU executable holds several mmap'd code regions, and the
 # kernel's vm.max_map_count ceiling (65530 default) turns the ~900th

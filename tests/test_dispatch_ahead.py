@@ -412,9 +412,12 @@ def test_reset_stats_zeroes_the_counters(tiny_model):
         srv.submit(p, max_new_tokens=6).result(timeout=300)
         assert srv.stats()["dispatch_ahead"]["issued_ahead"] > 0
         srv.reset_stats()
+        by_kind = {"decode": 0, "prefill": 0, "verify": 0}
         assert srv.stats()["dispatch_ahead"] == {
             "decode_dispatches": 0, "issued_ahead": 0, "ahead_share": 0.0,
-            "drains": {}, "dropped_rows": 0}
+            "drains": {}, "dropped_rows": 0, "probed": 0,
+            "probed_by_kind": by_kind, "found_idle": by_kind,
+            "found_idle_share": 0.0}
     finally:
         srv.stop()
 

@@ -960,8 +960,17 @@ class TestJournalRecoveryWithPrefixCache:
         a = _server(m, max_new_tokens=8, enable_prefix_cache=True,
                     journal=str(jp))
         seen = []
+
+        def on_token(t, _reason):
+            seen.append(t)
+            # the engine's thread: hold each token long enough that the
+            # export below finds the session still mid-decode (at a
+            # millisecond a token the 5 left were over before it ran:
+            # the test failed one time in two, alone, at the parent too)
+            time.sleep(0.02)
+
         a.start()
-        fut = a.submit(prompt, on_token=lambda t, r: seen.append(t))
+        fut = a.submit(prompt, on_token=on_token)
         deadline = time.monotonic() + 120
         while time.monotonic() < deadline and len(seen) < 3:
             time.sleep(0.002)
