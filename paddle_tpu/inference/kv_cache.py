@@ -434,7 +434,9 @@ class PagedKVCache:
         """The cache a `nn.decode_blocks.DecoderDescription` needs: a
         latent pool (one "head" as wide as a row), a K/V pool by the
         description's K/V heads, or no pool at all (no layer, no width),
-        with a store of `max_slots` slots."""
+        with a store of `max_slots` slots.  A description of "mla" layers
+        alone has an empty store: its slots hold no array and cost no
+        byte, one a server slot, so admission goes by blocks alone."""
         layout = desc.cache_layout()
         if not layout["pool_layers"]:
             heads, head_dim = 1, 0
@@ -468,6 +470,12 @@ class PagedKVCache:
     def state_slot(self, seq_id):
         """The store slot `seq_id` holds (0, the trash slot, if none)."""
         return self._state_of.get(seq_id, 0)
+
+    def state_slot_holder(self, slot):
+        """The sequence that holds store slot `slot` (None if none): with
+        `block_table`, where a sequence's pool rows lie."""
+        return next((s for s, held in self._state_of.items()
+                     if held == slot), None)
 
     # ---- pool bookkeeping (host-side) ---------------------------------
     @property

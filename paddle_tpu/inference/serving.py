@@ -1439,8 +1439,8 @@ class PagedGenerationServer:
             num_heads=heads, head_dim=cfg.hidden_size // heads,
             kv_quant=kv_dtype == "int8",
             itemsize=jnp.dtype(dt).itemsize)
-        # the paged decode kernel's (fan, launches a step) where this
-        # engine's decode steps run it: stats()["decode_kernel"]
+        # the decode attention kernel's (name, fan, launches a step)
+        # where this engine's decode steps run one: stats()["decode_kernel"]
         self._dk_plan = self._decode_kernel_plan(
             heads, sharding.tp if sharding is not None else 1)
         # per-slot sampling state (round 10): struct-of-arrays param
@@ -3168,9 +3168,9 @@ class PagedGenerationServer:
         _logger.info("%s", line)
         launches, live, steps = self._dk_counts
         if steps:
-            _logger.info("[decode kernel] fan %d: %d live blocks in %d grid "
-                         "steps of %d launches, %.3f blocks a step",
-                         self._dk_plan[0], live, steps, launches,
+            _logger.info("[decode kernel] %s fan %d: %d live blocks in %d "
+                         "grid steps of %d launches, %.3f blocks a step",
+                         *self._dk_plan[:2], live, steps, launches,
                          live / steps)
 
     def reset_stats(self):
@@ -3414,14 +3414,17 @@ class PagedGenerationServer:
                         sum(self._found_idle.values())
                         / (sum(self._probed.values()) or 1)),
                 },
-                # the paged decode kernel over the window's decode
+                # the decode attention kernel over the window's decode
                 # dispatches, counted on the host from the contexts the
-                # plan holds: blocks of a row a grid step (`fan`; 0 where
-                # decode runs another path), launches (one a pool layer),
-                # the rows' live blocks and the grid steps they took —
-                # live_blocks / steps is how often a step takes several
+                # plan holds: which (`kernel`: "paged_attn_decode",
+                # "mla_decode", None where decode runs no kernel), blocks
+                # of a row a grid step (`fan`; 0 without a kernel),
+                # launches (one a pool layer), the rows' live blocks and
+                # the grid steps they took — live_blocks / steps is how
+                # often a step takes several, and how few are dead
                 "decode_kernel": {
-                    "fan": (self._dk_plan or (0, 0))[0],
+                    "kernel": (self._dk_plan or (None,))[0],
+                    "fan": (self._dk_plan or (None, 0))[1],
                     "launches": self._dk_counts[0],
                     "live_blocks": self._dk_counts[1],
                     "steps": self._dk_counts[2],
@@ -5191,38 +5194,53 @@ class PagedGenerationServer:
             self._read_decode(rec)
 
     def _decode_kernel_plan(self, heads, tp):
-        """(fan, launches a step) of the paged decode kernel where this
-        engine's decode steps run it, else None: a latent pool runs
-        `mla_decode`, a description without a pool no attention at all,
-        and shapes the kernel's gate refuses (or a host without the chip)
-        the XLA gather.  The fan is the kernel's own (`pool_decode_fan`,
-        heads split over tp)."""
-        from ..ops.attention import paged_attention_path
+        """(kernel, fan, launches a step) of the decode attention kernel
+        where this engine's decode steps run one, else None: a latent pool
+        runs `mla_decode` on the chip (its fan fixed, a launch an "mla"
+        layer), a K/V pool the paged decode kernel (its fan
+        `pool_decode_fan`'s, heads split over tp), a description without
+        a pool no attention at all, and shapes the kernel's gate refuses
+        (or a host without the chip) the XLA gather."""
+        from ..ops.attention import _on_tpu, paged_attention_path
         from ..ops.pallas.unified_attention import pool_decode_fan
 
         c = self.cache
+        if self._desc is not None and self._desc.count("mla"):
+            from ..ops.pallas.mla_decode import FAN
+
+            return ("mla_decode", FAN, c.num_layers) if _on_tpu() else None
         if self._desc is not None and not self._desc.count("cca"):
             return None
         if paged_attention_path(c.head_dim, c.block_size, heads,
                                 mesh=self._mesh,
                                 kv_heads=c.num_heads) == "xla":
             return None
-        return (pool_decode_fan(c.k_blocks, c.v_blocks, self._m_width,
+        return ("paged_attn_decode",
+                pool_decode_fan(c.k_blocks, c.v_blocks, self._m_width,
                                 layer=0, shards=tp), c.num_layers)
 
     def _count_decode_kernel(self, pos, act, k):
         """Add one decode dispatch of `k` steps to stats()["decode_kernel"]:
         a launch a pool layer and step, each over every row of the table,
-        its blocks and grid steps as the kernel lays them out
-        (`decode_steps`; an idle row's context is 0).  Under the lock."""
+        its blocks and grid steps as the kernel lays them out (an idle
+        row's context is 0).  The paged decode kernel steps over a row's
+        blocks alone (`decode_steps`: 1 for an idle row); `mla_decode`'s
+        grid is (rows, table width / fan), so every row, idle or not,
+        takes ceil(width / fan) steps, and an idle row holds no block.
+        Under the lock."""
         from ..ops.pallas.unified_attention import decode_steps
 
         if self._dk_plan is None:
             return
-        fan, layers = self._dk_plan
+        kernel, fan, layers = self._dk_plan
         for j in range(k):
-            n, steps = decode_steps(np.where(act, pos + 1 + j, 0),
-                                    self.block_size, self._m_width, fan)
+            ctx = np.where(act, pos + 1 + j, 0)
+            if kernel == "mla_decode":
+                n = -(-ctx // self.block_size)
+                steps = np.full_like(n, -(-self._m_width // fan))
+            else:
+                n, steps = decode_steps(ctx, self.block_size, self._m_width,
+                                        fan)
             self._dk_counts[0] += layers
             self._dk_counts[1] += layers * int(n.sum())
             self._dk_counts[2] += layers * int(steps.sum())

@@ -202,12 +202,15 @@ class _Router(nn.Layer):
 
 
 class _MoE(nn.Layer):
-    def __init__(self, cfg, dt):
+    """Router, the experts held here and the shared experts: `shared` of
+    them are one SwiGLU `shared` experts wide, as their sum is."""
+
+    def __init__(self, cfg, dt, shared=1):
         super().__init__()
         e, f = cfg.hidden_size, cfg.moe_intermediate_size
         self.router = _Router(cfg, dt)
         self.experts = _Experts(cfg.held_experts[1], e, f, cfg.init_std, dt)
-        self.shared = _SwiGLU(e, f, cfg.init_std, dt)
+        self.shared = _SwiGLU(e, shared * f, cfg.init_std, dt)
 
 
 class _Block(nn.Layer):

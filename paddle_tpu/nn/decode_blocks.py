@@ -1,6 +1,9 @@
 """The serving programs of a decoder of RMSNorm pre-norm blocks with no
 position table, whose layers mix by latent attention ("mla": a paged
-latent pool, absorbed decode), by a recurrent state ("kda": a slot of the
+latent pool, absorbed decode; rotary positions on the decoupled `pe`
+channels of every query head and of the one shared key head where the
+description gives `mla_theta`, none where it does not), by a recurrent
+state ("kda": a slot of the
 state store), by compressed convolutional attention ("cca": paged K and
 V rows of fewer K/V heads than query heads, rotary positions on part of a
 head, and per-sequence conv tails in the store) or by power retention of
@@ -14,6 +17,7 @@ after the final RMSNorm is its own matrix or the embedding's (`tied_head`);
 a sublayer's sum with the residual stream may carry learned scalings
 (`residual_scaling`).  `DecoderDescription` holds that layout's sizes and
 is what `models.kimi_linear.KimiLinear.decoder_description()`,
+`models.kanana2.Kanana2.decoder_description()`,
 `models.zaya.Zaya.decoder_description()` and
 `models.brumby.Brumby.decoder_description()` hand the engine;
 `build_block_programs` builds its `packed_prefill` and `decode_step` from
@@ -41,7 +45,8 @@ and its own result; all donated and written in place.  A row of `tables`
 is [state slot | block table]: column 0 names the sequence's slot of the
 store (0: the trash slot, as block 0 is the trash block).  A description
 none of whose layers pages anything (`cache_layout()["pool_layers"]` 0:
-every layer "kda" or "power") has NO POOL: `kc` is an array of no rows
+every layer "kda" or "power") has NO POOL; one of "mla" layers alone has
+an empty store, and its state slots hold nothing: `kc` is an array of no rows
 that the programs hand through, a table row is [state slot] alone, and no
 program reads a block column.  Beside tokens,
 pool and store a program returns `routed`: what its expert layers did in
@@ -119,7 +124,7 @@ class DecoderDescription:
     layers: tuple              # of LayerDescription
     heads: int = 0             # mla query heads
     nope_dim: int = 0          # mla: q/k width without position part
-    pe_dim: int = 0            # mla: the part that would carry position
+    pe_dim: int = 0            # mla: the part that carries position
     v_dim: int = 0             # mla
     lora: int = 0              # mla: the latent's width
     kda_heads: int = 0
@@ -137,6 +142,8 @@ class DecoderDescription:
     router_width: int = 0      # mlp_routed: the router MLP's width
     tied_head: bool = False    # the head is the embedding's matrix
     residual_scaling: bool = False   # layers.<i>.res_{1,2}.*
+    mla_theta: float = None    # mla: rotary base of pe (None: no position)
+    mla_interleaved: bool = False    # mla: pe pairs (2i, 2i+1), not halves
 
     #: the parameter whose dtype is the model's (the engine asks)
     final_norm = "norm_f.weight"
@@ -413,20 +420,28 @@ def _block_fns(desc):
         return kda_output(p, pre, a, o), kc, vc, {"S": s_new, "conv": conv}
 
     # ---- MLA ------------------------------------------------------------
-    def mla_rows(p, pre, a, blk, off, kc, li):
-        """Write each row's latent into the pool; return the absorbed
-        queries [N, H, lora+pe] and the value half of W_kvb."""
+    def mla_rows(p, pre, a, pos, blk, off, kc, li):
+        """Write each row's latent into the pool, its `pe` part turned by
+        the row's position pos [N] where the description says so; return
+        the absorbed queries [N, H, lora+pe] and the value half of
+        W_kvb."""
         n = a.shape[0]
         q = (a @ p[pre + "q_proj.weight"]).reshape(n, H, NOPE + PE)
         kva = a @ p[pre + "kva_proj.weight"]
+        q_pe, k_pe = q[..., NOPE:], kva[:, LORA:]
+        if desc.mla_theta is not None:    # one key head shared by all
+            q_pe, k_pe = (apply_rotary(t, pos, PE, desc.mla_theta,
+                                       desc.mla_interleaved)
+                          for t in (q_pe, k_pe[:, None]))
+            k_pe = k_pe[:, 0]
         pad = kc.shape[-1] - LORA - PE     # rows are a lane multiple wide
         lat = jnp.concatenate(
-            [rms(kva[:, :LORA], p[pre + "kv_norm.weight"]), kva[:, LORA:],
+            [rms(kva[:, :LORA], p[pre + "kv_norm.weight"]), k_pe,
              jnp.zeros((n, pad), a.dtype)], axis=-1)
         kc = kc.at[li, blk, off].set(lat.astype(kc.dtype))
         wkvb = p[pre + "kvb_proj.weight"].reshape(LORA, H, NOPE + VD)
         q_abs = jnp.einsum("nhd,lhd->nhl", q[..., :NOPE], wkvb[..., :NOPE])
-        return (jnp.concatenate([q_abs, q[..., NOPE:],
+        return (jnp.concatenate([q_abs, q_pe,
                                  jnp.zeros((n, H, pad), a.dtype)], axis=-1),
                 kc, wkvb[..., NOPE:])
 
@@ -436,8 +451,8 @@ def _block_fns(desc):
 
     def mla_packed(p, i, a, ctx, kc, vc, state):
         pre, li = f"layers.{i}.mla.", kind_index[i]
-        q_lat, kc, w_vb = mla_rows(p, pre, a, ctx["blk"], ctx["off"], kc,
-                                   li)
+        q_lat, kc, w_vb = mla_rows(p, pre, a, ctx["pos"], ctx["blk"],
+                                   ctx["off"], kc, li)
         o_lat = _mla.mla_prefill_attention(
             q_lat, kc, li, ctx["btab"], ctx["tile_row"], ctx["pos"],
             scale=mla_scale, tile=desc.kda_chunk, lora=LORA)
@@ -445,8 +460,8 @@ def _block_fns(desc):
 
     def mla_step(p, i, a, ctx, kc, vc, state):
         pre, li = f"layers.{i}.mla.", kind_index[i]
-        q_lat, kc, w_vb = mla_rows(p, pre, a, ctx["blk"], ctx["off"], kc,
-                                   li)
+        q_lat, kc, w_vb = mla_rows(p, pre, a, ctx["pos"], ctx["blk"],
+                                   ctx["off"], kc, li)
         o_lat = _mla.mla_decode_attention(
             q_lat, kc, li, ctx["btab"], ctx["ctx"], scale=mla_scale,
             lora=LORA)

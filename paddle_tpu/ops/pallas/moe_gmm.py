@@ -5,8 +5,9 @@ Rows arrive sorted by expert and padded so that every tile of `tm` rows
 belongs to one expert (`tile_expert`, scalar-prefetched): the tile's
 x [tm, D] meets that expert's gate and up [D, F] and down [F, D] where
 they lie in the stacked weights [E, D, F] / [E, F, D], a slab of `tf`
-intermediate channels a grid step, and the tile's output accumulates in
-VMEM across the slabs.  So an expert's 3 D F weights are streamed once
+intermediate channels a grid step (F_SLAB, or the widest lane multiple
+under it that divides F: 384 of an expert 768 wide), and the tile's output
+accumulates in VMEM across the slabs.  So an expert's 3 D F weights are streamed once
 for each tile of rows it has (once, at decode), whatever the routing:
 dropless, and nothing is computed for an expert no row chose.
 
@@ -64,6 +65,8 @@ def moe_gmm_kernel(x_rows, gate, up, down, tile_expert, n_valid, *, tm,
     r, d = x_rows.shape
     e, _, f_dim = gate.shape
     tf = min(F_SLAB, f_dim)
+    while f_dim % tf and tf > 128:
+        tf -= 128
     if r % tm or f_dim % tf:
         raise ValueError(f"moe_gmm: {r} rows / tile {tm}, {f_dim} "
                          f"channels / slab {tf}")

@@ -430,8 +430,9 @@ def test_the_decode_kernel_counter_reads_its_steps(tiny_model, tiny_kimi,
     stood in for while the engine is built, the XLA path serving here), a
     launch a pool layer and step, a row of n blocks (1 for an idle row)
     ceil(n / fan) grid steps at the fan its block's bytes give; zeros after
-    a reset; fan 0 and no counts where decode runs no such kernel (a host
-    without the chip, a latent pool)."""
+    a reset; no kernel, fan 0 and no counts where decode runs none (a host
+    without the chip).  A latent pool's engine names `mla_decode` (its
+    counts: tests/test_kanana2.py)."""
     from paddle_tpu.models.gpt2 import GPT2, GPT2Config
     from paddle_tpu.ops import attention
     from paddle_tpu.ops.pallas import unified_attention as ua
@@ -459,15 +460,18 @@ def test_the_decode_kernel_counter_reads_its_steps(tiny_model, tiny_kimi,
         d = st["dispatch_ahead"]["decode_dispatches"]
         # one row at 201-207 positions (2 blocks: 1 step), one idle row
         assert d > 0 and st["decode_kernel"] == {
-            "fan": 2, "launches": 2 * d, "live_blocks": 2 * d * (2 + 1),
-            "steps": 2 * d * (1 + 1)}
+            "kernel": "paged_attn_decode", "fan": 2, "launches": 2 * d,
+            "live_blocks": 2 * d * (2 + 1), "steps": 2 * d * (1 + 1)}
         srv.reset_stats()
         assert srv.stats()["decode_kernel"] == {
-            "fan": 2, "launches": 0, "live_blocks": 0, "steps": 0}
+            "kernel": "paged_attn_decode", "fan": 2, "launches": 0,
+            "live_blocks": 0, "steps": 0}
     finally:
         srv.stop()
-    off = {"fan": 0, "launches": 0, "live_blocks": 0, "steps": 0}
-    assert idle.stats()["decode_kernel"] == off          # mla_decode's pool
+    off = {"kernel": None, "fan": 0, "launches": 0, "live_blocks": 0,
+           "steps": 0}
+    assert idle.stats()["decode_kernel"] == dict(      # mla_decode's pool
+        off, kernel="mla_decode", fan=4)
     _, st, _ = serve("ahead", tiny_model[0],
                      [(prompts_of(cfg, (6,))[0], dict(max_new_tokens=4))])
     assert st["decode_kernel"] == off                   # the XLA path here

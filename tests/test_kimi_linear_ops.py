@@ -158,14 +158,18 @@ def test_mla_absorbed_decode_is_the_unabsorbed_attention(form):
         close(got[b], unabsorbed(c, q[b], lat, int(ctx[b])))
 
 
-def test_mla_prefill_tiles_attend_their_own_rows_causally():
+# positions a step of the prefill's online softmax: one block of 8, two
+# (the table's 3 columns end inside the second step), the default (all 3)
+@pytest.mark.parametrize("step", [8, 16, mla.PREFILL_STEP_POSITIONS])
+def test_mla_prefill_tiles_attend_their_own_rows_causally(monkeypatch, step):
+    monkeypatch.setattr(mla, "PREFILL_STEP_POSITIONS", step)
     c = mla_case(1)
-    tile, lens = 4, (7, 10)             # two sequences, tiles of 4 tokens
-    tables = jnp.asarray([[3, 5], [7, 2]], jnp.int32)
-    pos = np.full((20,), -1, np.int32)
-    pos[0:7], pos[8:18] = np.arange(7), np.arange(10)
-    tile_row = jnp.asarray([0, 0, 1, 1, 1])
-    q = c["f"](20, c["heads"], c["nope"] + c["pe"])
+    tile, lens = 4, (7, 19)             # two sequences, tiles of 4 tokens
+    tables = jnp.asarray([[3, 5, 9], [7, 2, 11]], jnp.int32)
+    pos = np.full((28,), -1, np.int32)
+    pos[0:7], pos[8:27] = np.arange(7), np.arange(19)
+    tile_row = jnp.asarray([0, 0, 1, 1, 1, 1, 1])
+    q = c["f"](28, c["heads"], c["nope"] + c["pe"])
     o_lat = mla.mla_prefill_attention(
         absorb(c, q), c["pool"], 0, tables, tile_row, jnp.asarray(pos),
         scale=(c["nope"] + c["pe"]) ** -0.5, tile=tile, lora=c["lora"])

@@ -806,3 +806,157 @@ def test_poolless_programs_work_on_the_store_in_place(one_chip,
         assert len(re.findall(r"(?:may|must)-alias",
                               text.split("\n", 1)[0])) == 3
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_latent_programs_work_on_the_pool_in_place(one_chip, monkeypatch):
+    """kanana-2-30b-a3b's two serving programs at the published widths, 2
+    layers deep (the dense layer and an expert layer of all 128 experts),
+    64 rows at the cell's table of 152 blocks, the cell's largest packed
+    bucket of 2,048 tokens: an `mla_decode` a layer and a `moe_gmm` an
+    expert layer by name in the decode step, the `moe_gmm` alone in the
+    packed prefill (the latent's prefill, rotary included, is XLA), the
+    latent pool (and the empty store: no array) aliased to the outputs,
+    and temporaries under one layer's pool (5,120 blocks of 128 rows of
+    640: no copy, no re-laid stack), beside the packed prefill's float32
+    score tile of a step."""
+    import re
+
+    from paddle_tpu.models.kanana2 import Kanana2, Kanana2Config
+    from paddle_tpu.nn.decode_blocks import build_block_programs
+    from paddle_tpu.ops import attention, mla
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    s = _sds(one_chip)
+    layers, rows, tokens, blocks, width = 2, 64, 2048, 5120, 152
+    # the widths at 8 experts and a small vocabulary, then the published
+    # shapes of what those two cut
+    model = Kanana2(Kanana2Config(vocab_size=1024, n_routed_experts=8,
+                                  held_layers=layers), dtype="bfloat16")
+    params = {k: s(v.shape, v.dtype)
+              for k, v in model.functional_state()[0].items()}
+    for k in ("experts.gate", "experts.up", "experts.down"):
+        v = params[f"layers.1.moe.{k}"]
+        params[f"layers.1.moe.{k}"] = s((128,) + v.shape[1:], v.dtype)
+    params["layers.1.moe.router.weight"] = s((2048, 128), jnp.bfloat16)
+    params["layers.1.moe.router.bias"] = s((128,), jnp.float32)
+    params["embed.weight"] = s((128256, 2048), jnp.bfloat16)
+    params["lm_head.weight"] = s((2048, 128256), jnp.bfloat16)
+    model.cfg = Kanana2Config(held_layers=layers)
+    desc = model.decoder_description()
+    lay = desc.cache_layout()
+    assert lay["store"] == {} and lay["row_width"] == 640
+    pool = s((layers, blocks, BS, 640), jnp.bfloat16)
+    packed, step = build_block_programs(desc, BS, False, (False, False))
+    i32 = lambda *sh: s(sh, jnp.int32)
+    with jax.default_matmul_precision("default"):
+        c_step = jax.jit(step, donate_argnums=(5, 6)).lower(
+            params, i32(rows), i32(rows), s((rows,), jnp.bool_),
+            i32(rows, 1 + width), pool, {}, {"stop": i32(rows, 1)},
+            i32(rows)).compile()
+        c_packed = jax.jit(packed, donate_argnums=(6, 7)).lower(
+            params, i32(tokens), i32(tokens), i32(tokens),
+            i32(rows, 1 + width), i32(rows), pool, {},
+            {"stop": i32(rows, 1)}).compile()
+    one_layer_pool = blocks * BS * 640 * 2
+    scores = tokens * 32 * mla.PREFILL_STEP_POSITIONS * 4
+    for compiled, kernels, tile in (
+            (c_step, ["mla_decode"] * layers + ["moe_gmm"], 0),
+            (c_packed, ["moe_gmm"], scores)):
+        text = compiled.as_text()
+        assert sorted(_kernel_names_in(text)) == sorted(kernels)
+        assert len(re.findall(r"(?:may|must)-alias",
+                              text.split("\n", 1)[0])) == 1
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < one_layer_pool + tile, \
+            compiled.memory_analysis().temp_size_in_bytes
+
+
+def _shared_model(family):
+    """A model of each description that runs the code the latent's rotary
+    shares (`mla_rows`, `_MoE`, `apply_rotary`, `moe_gmm`'s slab), at its
+    published widths, 2 to 5 layers deep and 8 experts wide: (model, rows,
+    table width, packed tokens, pool blocks)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.brumby import Brumby, BrumbyConfig
+    from paddle_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
+    from paddle_tpu.models.zaya import Zaya, ZayaConfig
+
+    paddle.seed(1)
+    if family == "kimi":
+        return (KimiLinear(KimiLinearConfig(vocab_size=8192, held_layers=5,
+                                            held_experts=(0, 8)),
+                           dtype="bfloat16"), 16, 8, 512, 64)
+    if family == "zaya":
+        return (Zaya(ZayaConfig(vocab_size=8192, held_layers=2),
+                     dtype="bfloat16"), 16, 8, 512, 64)
+    return (Brumby(BrumbyConfig(vocab_size=1024, held_layers=2),
+                   dtype="bfloat16"), 16, 0, 512, 2)
+
+
+# (family, program) -> (instructions, fusions, kernels by name) of the
+# program the described v5e compiles.  These are the counts the programs
+# compiled to before the latent attention took rotary positions, a
+# description could hold latent rows alone and the shared FFN took a
+# width: those changes leave every other description's programs as they
+# were, instruction for instruction.  A change that moves a count says why:
+# Kimi's packed prefill took 10,368 and 647 until the latent prefill's
+# online softmax took several pool blocks a step (here the table's 8,
+# `mla.PREFILL_STEP_POSITIONS`): the wider gather and the step's flat key
+# positions.
+SHARED_PROGRAMS = {
+    ("kimi", "decode_step"): (
+        6532, 421, {"kda_decode": 4, "mla_decode": 1, "moe_gmm": 4}),
+    ("kimi", "packed_prefill"): (10434, 651, {"moe_gmm": 4}),
+    ("zaya", "decode_step"): (
+        4287, 250, {"moe_gmm": 2, "paged_attn_decode": 2}),
+    ("zaya", "packed_prefill"): (
+        5776, 288, {"moe_gmm": 2, "paged_attn_prefill": 2}),
+    ("brumby", "decode_step"): (1479, 119, {"power_decode": 2}),
+    ("brumby", "packed_prefill"): (2009, 169, {}),
+}
+
+
+@pytest.mark.parametrize("family,program", sorted(SHARED_PROGRAMS))
+def test_programs_that_share_the_latent_code_compile_as_before(
+        one_chip, monkeypatch, family, program):
+    """Kimi's, ZAYA's and Brumby's `decode_step` and `packed_prefill`, as
+    shapes on the described chip: as many instructions and fusions as
+    before, and the same kernels."""
+    from paddle_tpu.nn.decode_blocks import build_block_programs
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    s = _sds(one_chip)
+    i32 = lambda *sh: s(sh, jnp.int32)
+    model, rows, width, tokens, blocks = _shared_model(family)
+    desc = model.decoder_description()
+    params = {k: s(v.shape, v.dtype)
+              for k, v in model.functional_state()[0].items()}
+    lay = desc.cache_layout()
+    pool = s((lay["pool_layers"], blocks, BS, lay["row_width"]),
+             jnp.bfloat16)
+    store = {n: s((layers, rows + 1) + shape, dt or jnp.bfloat16)
+             for n, (layers, shape, dt) in lay["store"].items()}
+    caches = (pool, pool, store) if lay["values"] else (pool, store)
+    packed, step = build_block_programs(desc, BS, False, (False, False))
+    with jax.default_matmul_precision("default"):
+        if program == "decode_step":
+            lowered = jax.jit(step, donate_argnums=tuple(
+                range(5, 5 + len(caches)))).lower(
+                params, i32(rows), i32(rows), s((rows,), jnp.bool_),
+                i32(rows, 1 + width), *caches, {"stop": i32(rows, 1)},
+                i32(rows))
+        else:
+            lowered = jax.jit(packed, donate_argnums=tuple(
+                range(6, 6 + len(caches)))).lower(
+                params, i32(tokens), i32(tokens), i32(tokens),
+                i32(rows, 1 + width), i32(rows), *caches,
+                {"stop": i32(rows, 1)})
+        text = lowered.compile().as_text()
+    instructions = len(re.findall(r"^\s*(?:ROOT )?%\S+ = ", text, re.M))
+    fusions = len(re.findall(r"^\s*(?:ROOT )?%\S+ = .*? fusion\(", text,
+                             re.M))
+    names = _kernel_names_in(text)
+    kernels = {n: names.count(n) for n in set(names)}
+    assert (instructions, fusions, kernels) == SHARED_PROGRAMS[
+        (family, program)]
