@@ -5223,24 +5223,17 @@ class PagedGenerationServer:
         """Add one decode dispatch of `k` steps to stats()["decode_kernel"]:
         a launch a pool layer and step, each over every row of the table,
         its blocks and grid steps as the kernel lays them out (an idle
-        row's context is 0).  The paged decode kernel steps over a row's
-        blocks alone (`decode_steps`: 1 for an idle row); `mla_decode`'s
-        grid is (rows, table width / fan), so every row, idle or not,
-        takes ceil(width / fan) steps, and an idle row holds no block.
-        Under the lock."""
+        row's context is 0).  Both kernels step over the launch's work
+        list, a row's blocks alone (`decode_steps`: an idle row one block
+        and one step).  Under the lock."""
         from ..ops.pallas.unified_attention import decode_steps
 
         if self._dk_plan is None:
             return
-        kernel, fan, layers = self._dk_plan
+        _, fan, layers = self._dk_plan
         for j in range(k):
             ctx = np.where(act, pos + 1 + j, 0)
-            if kernel == "mla_decode":
-                n = -(-ctx // self.block_size)
-                steps = np.full_like(n, -(-self._m_width // fan))
-            else:
-                n, steps = decode_steps(ctx, self.block_size, self._m_width,
-                                        fan)
+            n, steps = decode_steps(ctx, self.block_size, self._m_width, fan)
             self._dk_counts[0] += layers
             self._dk_counts[1] += layers * int(n.sum())
             self._dk_counts[2] += layers * int(steps.sum())

@@ -15,6 +15,13 @@ cells' geometries.
     128, table width 40, 128 rows, a live step moves 128 KB), contexts from
     `benchmark/traffic/reason_closed128.json` (a prompt plus a uniform share of
     its output so far) and `deep` (three quarters of the table and more).
+  * `kimi`, `kanana`: the latent kernel `mla_decode` (32 heads against a pool
+    of [128, 640] bf16 latent blocks, 576 used: a live block moves 160 KB) at
+    the Kimi cell's geometry (128 rows, table width 76) and the Kanana cell's
+    (64 rows, width 152), contexts from their traffic files
+    (`decode_closed128.json`, `longdoc_closed64.json`) as `reason` takes
+    them, and every context 1; the XLA gather path of `ops/mla.py` is the
+    reference and the `xla-gather` column.
 
 The rows of every context 1 price a launch's fixed part. `live` is the
 launch's live (row, block) pairs, `fan` the blocks of a row a grid step
@@ -23,7 +30,8 @@ table width until PR 31; the live pairs, one for a row that has none, until
 PR 35; since then ceil(live blocks / fan) a row). Each case is timed at
 every fan in FANS up to the table's width, forced in place of
 `decode_fan`, and the row the rule picks is marked `*`: one run is the
-sweep behind `DECODE_STEP_BYTES` and the fans the rule takes. Each reading
+sweep behind `DECODE_STEP_BYTES` and the fans the rule takes; for the
+latent kernel the fan is `mla_decode.FAN`, forced likewise. Each reading
 is the median of 5 dispatches of 48 chained calls (the output feeds the
 next query, so nothing is hoisted), ending in block_until_ready. To compare
 two trees, copy this file over the other tree's and run it there, in the
@@ -45,6 +53,10 @@ BS = 128
 # query heads, K/V heads, head size, table width, pool blocks
 GEOMETRY = {"gpt2": (16, 16, 64, 8, 256), "zaya": (8, 2, 128, 40, 2560),
             "gpt2tp2": (8, 8, 64, 8, 256)}
+# latent geometries: rows, table width, pool blocks, traffic file
+LATENT = {"kimi": (128, 76, 6144, "decode_closed128.json"),
+          "kanana": (64, 152, 5120, "longdoc_closed64.json")}
+HEADS, ROW, LORA, QK = 32, 640, 512, 192   # a latent row: 512 + 64 + pad
 CALLS, READINGS = 48, 5
 STREAM_TILE = 8  # one f32 sublane group: the smallest tile the dots lower at
 GATHER_ROWS = 8  # rows of a 128-row launch the gather reference is taken on
@@ -67,10 +79,11 @@ def contexts(rs, mix, B, M):
     if mix == "cell":      # closed32: a prompt and 0-111 tokens of output
         prompts = _lengths(rs, "closed32.json", "prompt_len", B)
         return (prompts + rs.randint(0, 112, B)).astype(np.int64)
-    if mix == "reason":    # a prompt and a uniform share of its own output
-        done = rs.uniform(0, 1, B) * _lengths(rs, "reason_closed128.json",
-                                              "new_tokens", B)
-        prompts = _lengths(rs, "reason_closed128.json", "prompt_len", B)
+    if mix.endswith(".json") or mix == "reason":
+        # a prompt and a uniform share of its own output
+        traffic = "reason_closed128.json" if mix == "reason" else mix
+        done = rs.uniform(0, 1, B) * _lengths(rs, traffic, "new_tokens", B)
+        prompts = _lengths(rs, traffic, "prompt_len", B)
         return np.minimum(prompts + done, M * BS).astype(np.int64)
     return np.ones((B,), np.int64)  # "ctx=1"
 
@@ -125,26 +138,29 @@ def main(geometries):
         finally:
             attention._on_tpu = saved
 
-    def per_call_us(fn, q, k, v, tables, lens):
+    def per_call_us(fn, q, *args):
         # nothing a call reads may be the same from one call to the next,
         # or XLA moves the gather out of the loop and keeps what it
         # gathered in VMEM (PR 27's first reading: 33.5 MB "read" in 32
-        # us): the pool and the tables are arguments (closed over they
+        # us): the pools and the tables are arguments (closed over they
         # are constants, folded at compile time), and call i takes the
-        # table rolled by i rows, which is the same work
-        def chained(q, k, v, tables, lens):
+        # table rolled by i rows, which is the same work; `fn` returns
+        # the next call's query
+        def chained(q, *args):
+            *pools, tables, lens = args
+
             def call(i, x):
-                return fn(x, k, v, jnp.roll(tables, i, 0),
+                return fn(x, *pools, jnp.roll(tables, i, 0),
                           jnp.roll(lens, i, 0)).astype(x.dtype)
 
             return jax.lax.fori_loop(0, CALLS, call, q)
 
         loop = jax.jit(chained)
-        loop(q, k, v, tables, lens).block_until_ready()
+        loop(q, *args).block_until_ready()
         ts = []
         for _ in range(READINGS):
             t0 = time.perf_counter()
-            loop(q, k, v, tables, lens).block_until_ready()
+            loop(q, *args).block_until_ready()
             ts.append(time.perf_counter() - t0)
         return np.median(ts) / CALLS * 1e6
 
@@ -160,10 +176,6 @@ def main(geometries):
           "(row, block) pairs under a context, grid = the decode kernel's "
           "steps, us/live = decode over live; |err| is the decode kernel's "
           "largest distance from xla-gather", flush=True)
-    print("| geometry | pool | B | contexts | mean ctx | live | fan | grid | "
-          "xla-gather | tile8 | decode | us/live | us/step | max err |\n"
-          "|---|---|---|---|---|---|---|---|---|---|---|---|---|---|",
-          flush=True)
     cases = [("gpt2", p, B, mix) for p in ("dense", "int8") for B in (8, 32)
              for mix in ("300-1024", "cell")]
     cases += [("gpt2", "dense", 32, "ctx=1"), ("gpt2", "int8", 32, "ctx=1")]
@@ -172,6 +184,11 @@ def main(geometries):
     cases += [("gpt2tp2", p, 32, mix) for p in ("dense", "int8")
               for mix in ("300-1024", "cell", "ctx=1")]
     cases = [c for c in cases if c[0] in geometries]
+    if cases:
+        print("| geometry | pool | B | contexts | mean ctx | live | fan | "
+              "grid | xla-gather | tile8 | decode | us/live | us/step | "
+              "max err |\n|---|---|---|---|---|---|---|---|---|---|---|---|"
+              "---|---|", flush=True)
     held = {}
     has_fan = hasattr(ua, "decode_fan")       # this tree's kernel takes k
     rule_of = getattr(ua, "decode_fan", None)
@@ -210,7 +227,75 @@ def main(geometries):
             older = ["", ""]
         if has_fan:
             ua.decode_fan = rule_of
+    latent([g for g in geometries if g in LATENT], rs, per_call_us)
+
+
+def latent(geometries, rs, per_call_us):
+    """`mla_decode` at every fan in FANS, forced in place of
+    `mla_decode.FAN`, beside the XLA gather path, at the latent
+    geometries; GB/s is the live blocks' bytes over the kernel's time."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention, mla
+    from paddle_tpu.ops.pallas import mla_decode
+    from paddle_tpu.ops.pallas import unified_attention as ua
+
+    if not geometries:
+        return
+    kw = dict(scale=QK ** -0.5, lora=LORA)
+    walks = hasattr(mla_decode, "decode_work_list")   # a work-list grid
+
+    def decode(q, pool, tables, lens):   # returns the next call's query
+        o = mla_decode.mla_decode_kernel(q, pool, 0, tables, lens, **kw)
+        return jnp.concatenate([o, q[..., LORA:]], -1)
+
+    def gather(q, pool, tables, lens):   # the XLA path, chosen by hand
+        saved, attention._on_tpu = attention._on_tpu, lambda: False
+        try:
+            o = mla.mla_decode_attention(q, pool, 0, tables, lens, **kw)
+        finally:
+            attention._on_tpu = saved
+        return jnp.concatenate([o, q[..., LORA:]], -1)
+
+    print("| geometry | B | contexts | mean ctx | live | fan | grid | "
+          "xla-gather | mla_decode | us/live | us/step | GB/s | max err |\n"
+          "|---|---|---|---|---|---|---|---|---|---|---|---|---|",
+          flush=True)
+    rule = mla_decode.FAN
+    for geometry in geometries:
+        B, M, N, traffic = LATENT[geometry]
+        pool = jax.random.normal(jax.random.PRNGKey(0), (1, N, BS, ROW),
+                                 jnp.bfloat16)
+        for mix in (traffic, "ctx=1"):
+            ctx = contexts(rs, mix, B, M)
+            live = np.arange(M)[None, :] * BS < ctx[:, None]
+            tables = jnp.asarray(
+                np.where(live, rs.randint(1, N, (B, M)), 0), jnp.int32)
+            lens = jnp.asarray(ctx, jnp.int32)
+            q = jnp.asarray(rs.randn(B, HEADS, ROW), jnp.bfloat16)
+            few = min(B, GATHER_ROWS)
+            ref = jax.jit(gather)(q[:few], pool, tables[:few], lens[:few])
+            xla = f"{per_call_us(gather, q, pool, tables, lens):.0f}"
+            for fan in [f for f in FANS if f <= M]:
+                mla_decode.FAN = fan
+                jax.clear_caches()   # a kernel that reads FAN as it traces
+                out = jax.jit(decode)(q, pool, tables, lens)
+                us = per_call_us(decode, q, pool, tables, lens)
+                err = float(jnp.max(jnp.abs(
+                    out[:few, :, :LORA].astype(jnp.float32)
+                    - ref[..., :LORA].astype(jnp.float32))))
+                grid = (int(ua.decode_steps(ctx, BS, M, fan)[1].sum())
+                        if walks else B * -(-M // fan))
+                n = int(live.sum())
+                print(f"| {geometry} | {B} | {mix} | {ctx.mean():.0f} | {n} | "
+                      f"{fan}{'*' if fan == rule else ''} | {grid} | {xla} | "
+                      f"{us:.1f} | {us / n:.3f} | {us / grid:.3f} | "
+                      f"{n * BS * ROW * 2 / us / 1e3:.0f} | {err:.4f} |",
+                      flush=True)
+                xla = ""
+            mla_decode.FAN = rule
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or list(GEOMETRY))
+    main(sys.argv[1:] or list(GEOMETRY) + list(LATENT))

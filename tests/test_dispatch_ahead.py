@@ -471,7 +471,7 @@ def test_the_decode_kernel_counter_reads_its_steps(tiny_model, tiny_kimi,
     off = {"kernel": None, "fan": 0, "launches": 0, "live_blocks": 0,
            "steps": 0}
     assert idle.stats()["decode_kernel"] == dict(      # mla_decode's pool
-        off, kernel="mla_decode", fan=4)
+        off, kernel="mla_decode", fan=8)
     _, st, _ = serve("ahead", tiny_model[0],
                      [(prompts_of(cfg, (6,))[0], dict(max_new_tokens=4))])
     assert st["decode_kernel"] == off                   # the XLA path here

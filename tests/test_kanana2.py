@@ -326,9 +326,9 @@ def test_served_through_a_pool_that_holds_one_request_at_a_time(tiny):
 def test_the_decode_kernel_counter_counts_mla_decode(tiny, monkeypatch):
     """On an engine whose decode steps run `mla_decode` (the chip stood in
     for while the engine is built, the XLA path serving here): a launch an
-    "mla" layer and step; every row of the table, idle or not, takes
-    ceil(width / FAN) grid steps (the kernel's grid is rows x width / FAN),
-    and a row's live blocks are those under its context."""
+    "mla" layer and step; the kernel's grid is the launch's work list, so a
+    row of n live blocks takes ceil(n / FAN) grid steps and the idle row
+    one step over one block, as `decode_steps` lays them out."""
     from paddle_tpu.ops import attention
     from paddle_tpu.ops.pallas.mla_decode import FAN
 
@@ -338,7 +338,6 @@ def test_the_decode_kernel_counter_counts_mla_decode(tiny, monkeypatch):
     with monkeypatch.context() as m:
         m.setattr(attention, "_on_tpu", lambda: True)
         server = PagedGenerationServer(model, **opts)
-    width = -(-48 // BS)                               # the table: 6 blocks
     server.start()
     try:
         (p,) = prompts_of(cfg, (21,), seed=8)
@@ -347,7 +346,7 @@ def test_the_decode_kernel_counter_counts_mla_decode(tiny, monkeypatch):
     finally:
         server.stop()
     d = st["dispatch_ahead"]["decode_dispatches"]
-    # positions 21-23 fed one a step: 3 blocks each, the idle row none
+    # positions 21-23 fed one a step: 3 blocks each, the idle row one
     assert d == 3 and st["decode_kernel"] == {
         "kernel": "mla_decode", "fan": FAN, "launches": 4 * d,
-        "live_blocks": 4 * d * 3, "steps": 4 * d * 2 * -(-width // FAN)}
+        "live_blocks": 4 * d * (3 + 1), "steps": 4 * d * (-(-3 // FAN) + 1)}

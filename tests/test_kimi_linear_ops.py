@@ -135,27 +135,38 @@ def absorb(c, q):
                     c["w_kvb"][..., :c["nope"]]), q[..., c["nope"]:]], -1)
 
 
-@pytest.mark.parametrize("form", ["xla", "kernel"])
-def test_mla_absorbed_decode_is_the_unabsorbed_attention(form):
-    c = mla_case()
-    q = c["f"](3, c["heads"], c["nope"] + c["pe"])
-    tables = jnp.asarray(c["r"].permutation(np.arange(1, 12))[:9]
-                         .reshape(3, 3), jnp.int32)
-    ctx = jnp.asarray([13, 24, 1], jnp.int32)
-    scale = (c["nope"] + c["pe"]) ** -0.5
-    if form == "xla":
-        o_lat = mla.mla_decode_attention(absorb(c, q), c["pool"], 1, tables,
-                                         ctx, scale=scale, lora=c["lora"])
-    else:
-        from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
+# the kernel at each fan (blocks of a row a grid step, monkeypatched); the
+# table is 9 blocks wide, so a row of the whole table ends inside its last
+# step at each, and a step of 8 reaches past the table's end
+@pytest.mark.parametrize("form,fan", [
+    pytest.param("xla", 4, id="xla"), pytest.param("kernel", 4, id="kernel"),
+    pytest.param("kernel", 1, id="kernel-fan1"),
+    pytest.param("kernel", 2, id="kernel-fan2"),
+    pytest.param("kernel", 8, id="kernel-fan8")])
+def test_mla_absorbed_decode_is_the_unabsorbed_attention(monkeypatch, form,
+                                                         fan):
+    from paddle_tpu.ops.pallas import mla_decode
 
-        o_lat = mla_decode_kernel(absorb(c, q), c["pool"], 1, tables, ctx,
-                                  scale=scale, lora=c["lora"],
-                                  interpret=True)
+    monkeypatch.setattr(mla_decode, "FAN", fan)
+    c = mla_case()
+    bs, width = c["bs"], 9
+    # an idle row, one position, a step's blocks exactly, one position
+    # more, the table's full width, and one that ends inside a block
+    ctx = np.asarray([0, 1, fan * bs, fan * bs + 1, width * bs, 29])
+    b = len(ctx)
+    q = c["f"](b, c["heads"], c["nope"] + c["pe"])
+    tables = jnp.asarray(c["r"].integers(0, 12, (b, width)), jnp.int32)
+    args = (absorb(c, q), c["pool"], 1, tables, jnp.asarray(ctx, jnp.int32))
+    kw = dict(scale=(c["nope"] + c["pe"]) ** -0.5, lora=c["lora"])
+    if form == "xla":
+        o_lat = mla.mla_decode_attention(*args, **kw)
+    else:
+        o_lat = mla_decode.mla_decode_kernel(*args, **kw, interpret=True)
+        assert (np.asarray(o_lat[0]) == 0).all()      # the idle row: zeros
     got = jnp.einsum("bhl,lhv->bhv", o_lat, c["w_kvb"][..., c["nope"]:])
-    for b in range(3):
-        lat = c["pool"][1, tables[b]].reshape(-1, c["lora"] + c["pe"])
-        close(got[b], unabsorbed(c, q[b], lat, int(ctx[b])))
+    for row in range(1, b):
+        lat = c["pool"][1, tables[row]].reshape(-1, c["lora"] + c["pe"])
+        close(got[row], unabsorbed(c, q[row], lat, int(ctx[row])))
 
 
 # positions a step of the prefill's online softmax: one block of 8, two

@@ -417,18 +417,29 @@ def test_kda_decode_kernel_compiles(one_chip):
         row, row, s((16, 32), jnp.float32)) == 1
 
 
-def test_mla_decode_kernel_compiles(one_chip):
-    """32 heads against [128, 640] blocks of latents (576 used), four table
-    entries a grid step."""
+def _mla_decode_kernels(one_chip, rows, width, layers, blocks):
     from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
 
     s = _sds(one_chip)
-    assert _kernels(
-        lambda q, pool, tb, cx: mla_decode_kernel(q, pool, 0, tb, cx,
-                                                  scale=192 ** -0.5,
+    return _kernels(
+        lambda q, pool, tb, cx: mla_decode_kernel(q, pool, layers - 1, tb,
+                                                  cx, scale=192 ** -0.5,
                                                   lora=512),
-        s((16, 32, 640), jnp.bfloat16), s((1, 64, 128, 640), jnp.bfloat16),
-        s((16, 76), jnp.int32), s((16,), jnp.int32)) == 1
+        s((rows, 32, 640), jnp.bfloat16),
+        s((layers, blocks, 128, 640), jnp.bfloat16),
+        s((rows, width), jnp.int32), s((rows,), jnp.int32))
+
+
+def test_mla_decode_kernel_compiles(one_chip):
+    """32 heads against [128, 640] blocks of latents (576 used), a grid
+    step FAN live blocks of a row from the launch's work list."""
+    assert _mla_decode_kernels(one_chip, 16, 76, 1, 64) == 1
+
+
+def test_mla_decode_kernel_compiles_at_the_kanana_geometry(one_chip):
+    """The Kanana cell's launch: 64 rows under its horizon of 19,456
+    positions (a table 152 wide), the last of 6 layers of 5,120 blocks."""
+    assert _mla_decode_kernels(one_chip, 64, 152, 6, 5120) == 1
 
 
 @pytest.mark.parametrize("tm,rows", [(16, 368), (128, 3072)],
@@ -818,12 +829,14 @@ def test_latent_programs_work_on_the_pool_in_place(one_chip, monkeypatch):
     latent pool (and the empty store: no array) aliased to the outputs,
     and temporaries under one layer's pool (5,120 blocks of 128 rows of
     640: no copy, no re-laid stack), beside the packed prefill's float32
-    score tile of a step."""
+    score tile of a step.  Both layers' `mla_decode` launches walk ONE work
+    list."""
     import re
 
     from paddle_tpu.models.kanana2 import Kanana2, Kanana2Config
     from paddle_tpu.nn.decode_blocks import build_block_programs
     from paddle_tpu.ops import attention, mla
+    from paddle_tpu.ops.pallas.mla_decode import FAN
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     s = _sds(one_chip)
@@ -869,6 +882,9 @@ def test_latent_programs_work_on_the_pool_in_place(one_chip, monkeypatch):
         assert compiled.memory_analysis().temp_size_in_bytes \
             < one_layer_pool + tile, \
             compiled.memory_analysis().temp_size_in_bytes
+    steps = rows * -(-width // FAN)
+    assert len(_work_list_fusions(c_step.as_text(), steps, steps * FAN)) \
+        == WORK_LIST_FUSIONS
 
 
 def _shared_model(family):
@@ -902,10 +918,11 @@ def _shared_model(family):
 # Kimi's packed prefill took 10,368 and 647 until the latent prefill's
 # online softmax took several pool blocks a step (here the table's 8,
 # `mla.PREFILL_STEP_POSITIONS`): the wider gather and the step's flat key
-# positions.
+# positions.  Kimi's decode step took 6,532 and 421 until `mla_decode`
+# walked the launch's work list: the work list's integer ops.
 SHARED_PROGRAMS = {
     ("kimi", "decode_step"): (
-        6532, 421, {"kda_decode": 4, "mla_decode": 1, "moe_gmm": 4}),
+        6803, 430, {"kda_decode": 4, "mla_decode": 1, "moe_gmm": 4}),
     ("kimi", "packed_prefill"): (10434, 651, {"moe_gmm": 4}),
     ("zaya", "decode_step"): (
         4287, 250, {"moe_gmm": 2, "paged_attn_decode": 2}),
