@@ -1,5 +1,6 @@
 """The `train` kind of cell: one compiled step per dispatch, fresh seeded
-batches from io.DataLoader workers, the loss read back every step.
+batches from io.DataLoader workers, each loss read back AHEAD_S seconds of
+steps after its dispatch.
 
 The wiring is chip_smoke.make_train_step's, the only one proven on this
 chip: the family's loss on bf16 casts of float32 master weights, and the
@@ -14,6 +15,7 @@ Traffic parameters (benchmark/traffic/<name>.json):
 """
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import gc
 import math
@@ -24,6 +26,10 @@ import time
 import numpy as np
 
 WARM_STEPS = 2
+# Seconds of steps dispatched ahead of the one whose loss the loop waits
+# for: the device keeps working through a stall of the host up to this
+# long, and the last wait of the window is no longer than this.
+AHEAD_S = 6.0
 
 
 # One bfloat16 ulp, relative, at its widest (8 significant bits).  The step
@@ -85,8 +91,9 @@ def run(ctx):
     n_params = sum(int(np.prod(v.shape)) for v in params.values())
     t_model = time.perf_counter()
 
+    # the window's steps and those in flight when it closes, at the cap
     n_steps = WARM_STEPS + int(math.ceil(
-        seconds * float(traffic["steps_per_s_cap"])))
+        (seconds + AHEAD_S) * float(traffic["steps_per_s_cap"])))
     dataset = bench_data.SeededSequences(
         seed, n_steps * batch, seq, shape["vocab"], family.OBJECTIVE,
         mask_id=cfg.get("assumed", {}).get("mask_token_id", 103))
@@ -133,51 +140,79 @@ def run(ctx):
     loss, params, opt_state = compiled(params, opt_state, batch0, key)
     loss0 = float(loss)
     for _ in range(WARM_STEPS - 1):
-        loss, params, opt_state = compiled(params, opt_state, next_batch(),
-                                           key)
+        data = next_batch()
+        t_warm = time.perf_counter()
+        loss, params, opt_state = compiled(params, opt_state, data, key)
         float(loss)
+        warm_step_s = time.perf_counter() - t_warm
+    ahead = max(1, round(AHEAD_S / warm_step_s))
 
     span = jax.profiler.TraceAnnotation
 
     # ---- the window -----------------------------------------------------
+    # Up to `ahead` steps are in flight: a step's loss is read once `ahead`
+    # newer steps have been dispatched.  When the time is up nothing more is
+    # dispatched, every step sent is waited for, and the clock is read after
+    # that wait: all of that work over all of that time.  A traced run waits
+    # for what is in flight before it starts and stops the profiler, and
+    # leaves out the seconds that start and stop took.
     trace_dir, trace, trace_stall_s = None, None, 0.0
     trace_at = seconds / 3 if ctx["trace"] else None
     trace_until = None
     trace_seconds = float(traffic.get("trace_seconds", 3.0))
-    losses, step_s, wait_s = [], [], []
+    losses, pending, step_s, wait_s = [], collections.deque(), [], []
+
+    def read_all():
+        while pending:
+            losses.append(float(pending.popleft()))
+
+    def finish_trace():
+        nonlocal trace, trace_until, trace_stall_s
+        read_all()
+        tb = time.perf_counter()
+        trace = trace_reduce.finish(trace_dir, read=on_tpu)
+        trace_until = None
+        trace_stall_s += time.perf_counter() - tb
+
     t_w0 = time.perf_counter()
     setup_s = t_w0 - ctx["t_process_start"]
-    t_end = t_w0
-    while t_end - t_w0 < seconds:
+    while time.perf_counter() - t_w0 < seconds:
         ta = time.perf_counter()
         if trace_at is not None and ta - t_w0 >= trace_at:
+            read_all()
+            tb = time.perf_counter()
             trace_dir = trace_reduce.start(ctx["root"], ctx["cell"]["name"])
             trace_at, trace_until = None, time.perf_counter() + trace_seconds
-            trace_stall_s += time.perf_counter() - ta
+            trace_stall_s += time.perf_counter() - tb
             ta = time.perf_counter()
         with span("bench:next(loader)"):
             data = next_batch()
         tb = time.perf_counter()
         with span("bench:step dispatch"):
             loss, params, opt_state = compiled(params, opt_state, data, key)
-        with span("bench:loss read"):
-            losses.append(float(loss))  # the host read is the barrier
-        t_end = time.perf_counter()
+        pending.append(loss)
         wait_s.append(tb - ta)
-        step_s.append(t_end - tb)
-        if trace_until is not None and (t_end >= trace_until
-                                        or t_end - t_w0 >= seconds):
-            trace = trace_reduce.finish(trace_dir, read=on_tpu)
-            trace_until = None
-            trace_stall_s += time.perf_counter() - t_end
-            t_end = time.perf_counter()
-    # all the work over all the time; a traced run leaves out the seconds
-    # the loop stood still to start and to write out the trace
+        if len(pending) > ahead:
+            with span("bench:loss read"):
+                losses.append(float(pending.popleft()))
+            step_s.append(time.perf_counter() - ta)  # a step of the device's
+        # the slice holds about trace_seconds of the device's work: it ends
+        # once what is in flight would carry it past that
+        if trace_until is not None and (
+                time.perf_counter() + len(pending) * warm_step_s
+                >= trace_until):
+            finish_trace()
+    if trace_until is not None:  # the window closed inside the slice
+        finish_trace()
+    read_all()
+    t_end = time.perf_counter()
     window_s = t_end - t_w0 - trace_stall_s
     compiles_in_window, _stages = ctx["compiles_heard"](t_w0, t_end)
     steps = len(losses)
     tokens_per_step = batch * seq
     tokens_per_s = steps * tokens_per_step / window_s
+    if not step_s:  # the window closed before `ahead` steps were in flight
+        step_s = [window_s / steps]
 
     # ---- after the window: shut the loader, check the outputs -----------
     batches.close()
@@ -237,7 +272,9 @@ def run(ctx):
                   f"{statistics.median(step_s) * 1e3:.2f} ms, loader wait "
                   f"median {statistics.median(wait_s) * 1e3:.3f} ms, "
                   f"{tokens_per_s:.1f} tokens/s, set-up {setup_s:.1f}s, "
-                  f"{compiles_in_window} compiles in the window"],
+                  f"{compiles_in_window} compiles in the window, {ahead} "
+                  f"steps in flight ({AHEAD_S:g} s at the warm step's "
+                  f"{warm_step_s * 1e3:.1f} ms)"],
     }
     if ctx["trace"]:
         obs = {

@@ -1,5 +1,6 @@
-"""Trainer: host clock around one compiled step, from the dispatch to the
-loss read back (the barrier), median over the window's steps."""
+"""Trainer: host clock around one turn of the step loop once its steps in
+flight are full (dispatch a step, read the loss of the oldest): the
+device's pace, median over the window's turns."""
 import statistics
 
 

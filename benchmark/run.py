@@ -17,12 +17,14 @@ Everything is found by name, nothing is registered here:
 The run sets up (imports, weights, compile or cache read, warm-up of the
 cell's own shapes: `setup_s`), measures for --seconds, checks the outputs
 against the plain reference after the window, and prints one JSON object
-as the last line of standard output.  --trace 0 reports the cell's
-end-to-end metrics, --trace 1 its per-layer metrics (a few seconds of the
-window are traced).  It needs the chips the cell asks for: on anything else
-it exits non-zero and prints no result.  `--rehearse` runs the same code at
-the tiny presets of the data files on whatever backend JAX has; it prints
-counts, never a result line, and exits with code 3.
+as the last line of standard output; its last key, `checks`, holds the
+numbers `correct` compared beside their limits, which are also the last
+lines of standard error.  --trace 0 reports the cell's end-to-end metrics,
+--trace 1 its per-layer metrics (a few seconds of the window are traced).
+It needs the chips the cell asks for: on anything else it exits non-zero
+and prints no result.  `--rehearse` runs the same code at the tiny presets
+of the data files on whatever backend JAX has; it prints counts, never a
+result line, and exits with code 3.
 """
 from __future__ import annotations
 
@@ -46,8 +48,13 @@ class BenchFailure(Exception):
     """The run cannot give a result; it exits non-zero and prints none."""
 
 
+CHECKS = []  # the kind's "[check]" lines: each number compared beside its limit
+
+
 def log(msg):
     print(msg, flush=True)
+    if msg.startswith("[check] "):
+        CHECKS.append(msg[len("[check] "):])
 
 
 def read_json(*parts):
@@ -253,7 +260,12 @@ def main(argv=None):
             f"attempted {line['attempted']}, failed {line['failed']}, "
             f"correct {line['correct']}, metrics {sorted(values)}")
         return EXIT_REHEARSAL if result["correct"] else 1
+    # what `correct` compared, last: under the line's last key, and as the
+    # last lines of standard error
+    line["checks"] = CHECKS
     print(json.dumps(line), flush=True)
+    for check in CHECKS:
+        print(f"[check] {check}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -79,22 +79,30 @@ def test_only_the_benchmark_is_not_enough(tmp_path):
 
 # ---- BENCHMARK.json to the letter of its contract -------------------------
 
-def test_keys_and_limits():
-    assert set(BENCHMARK) == {"command", "paths", "run_seconds", "configs",
-                              "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCHMARK["run_seconds"] <= 51
-    assert isinstance(BENCHMARK["run_seconds"], int)
-    assert len(json.dumps(BENCHMARK)) < 64 * 1024
-    assert BENCHMARK["command"] == ["python3", "benchmark/run.py"]
-    for p in BENCHMARK["paths"]:
+# Each check is a function of the benchmark's dict (and, for the files, of
+# the checkout that holds them), so that test_benchmark_appending.py runs
+# the same checks on a copy that holds what a later PR appends.
+
+def check_keys_and_limits(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert 1 <= bench["run_seconds"] <= 51
+    assert isinstance(bench["run_seconds"], int)
+    assert len(json.dumps(bench)) < 64 * 1024
+    assert bench["command"] == ["python3", "benchmark/run.py"]
+    for p in bench["paths"]:
         assert re.match(r"^[A-Za-z0-9_.\-/]{1,200}$", p) and ".." not in p
     # full check: 2 + 14 runs a cell, at the full 24 cells
     runs = 2 + 14 * 24
-    assert runs * (BENCHMARK["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+    assert runs * (bench["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
-def test_names_units_and_texts():
-    metrics = BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]
+def test_keys_and_limits():
+    check_keys_and_limits(BENCHMARK)
+
+
+def check_names_units_and_texts(bench):
+    metrics = bench["end_to_end"] + bench["per_layer"]
     names = [m["name"] for m in metrics]
     assert len(set(names)) == len(names)
     for m in metrics:
@@ -102,56 +110,66 @@ def test_names_units_and_texts():
         assert m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for m in BENCHMARK["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    for m in BENCHMARK["per_layer"]:
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
     for group in ("configs", "workloads"):
-        got = [x["name"] for x in BENCHMARK[group]]
+        got = [x["name"] for x in bench[group]]
         assert len(set(got)) == len(got)
-        for x in BENCHMARK[group]:
+        for x in bench[group]:
             assert NAME.match(x["name"])
             assert 1 <= len(x["why"]) <= 200 and "\n" not in x["why"] \
                 and "\t" not in x["why"]
 
 
-def test_every_cell_reports_what_it_must():
-    e2e = {m["name"]: m for m in BENCHMARK["end_to_end"]}
+def test_names_units_and_texts():
+    check_names_units_and_texts(BENCHMARK)
+
+
+def check_every_cell_reports_what_it_must(bench):
+    cells = [w["name"] for w in bench["workloads"]]
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert "workloads" not in e2e["setup_s"] and e2e["setup_s"]["bound"] <= 0.1
-    pairs = [(w["config"], w["traffic"]) for w in BENCHMARK["workloads"]]
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
-    four = [w for w in BENCHMARK["workloads"] if w["chips"] == 4]
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(pairs) // 4)
-    for w in BENCHMARK["workloads"]:
+    for w in bench["workloads"]:
         assert w["chips"] in (1, 4)
         assert NAME.match(w["config"]) and NAME.match(w["traffic"])
-        mine = [m for m in BENCHMARK["end_to_end"]
+        mine = [m for m in bench["end_to_end"]
                 if w["name"] in m.get("workloads", [w["name"]])]
         assert len(mine) >= 2
-        layer = [m for m in BENCHMARK["per_layer"]
+        layer = [m for m in bench["per_layer"]
                  if w["name"] in m.get("workloads", [w["name"]])]
         assert layer
         for m in layer:   # what a layer metric moves is reported there
             assert m["moves"] in {x["name"] for x in mine}, m["name"]
-    for m in BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]:
+    for m in bench["end_to_end"] + bench["per_layer"]:
         for cell in m.get("workloads", []):
-            assert cell in CELLS
+            assert cell in cells
 
 
-def test_files_are_where_the_names_say():
-    used = {w["config"] for w in BENCHMARK["workloads"]}
-    files = [c["file"] for c in BENCHMARK["configs"]]
+def test_every_cell_reports_what_it_must():
+    check_every_cell_reports_what_it_must(BENCHMARK)
+
+
+def check_files(bench, root):
+    """The files that the entries name, in the checkout at `root`."""
+    bench_dir = os.path.join(root, "benchmark")
+    used = {w["config"] for w in bench["workloads"]}
+    files = [c["file"] for c in bench["configs"]]
     assert len(set(files)) == len(files)
-    for c in BENCHMARK["configs"]:
+    for c in bench["configs"]:
         assert c["name"] in used
-        assert c["file"].startswith(tuple(p + "/" for p in
-                                          BENCHMARK["paths"]))
-        with open(os.path.join(ROOT, c["file"])) as f:
+        assert c["file"].startswith(tuple(p + "/" for p in bench["paths"]))
+        with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
         assert len(c["reduced"]) <= 16
@@ -160,27 +178,33 @@ def test_files_are_where_the_names_say():
             assert not key.endswith(("_dim", "_rank", "_size"))
             assert not any(w in key for w in WIDTH_WORDS), key
         assert os.path.exists(os.path.join(
-            BENCH, "families", cfg["family"] + ".py"))
+            bench_dir, "families", cfg["family"] + ".py"))
         assert os.path.exists(os.path.join(
-            BENCH, "reference", cfg["family"] + ".py"))
+            bench_dir, "reference", cfg["family"] + ".py"))
         assert cfg["deployment"]["chips"] == 1 and "assumed" in cfg
-    for w in BENCHMARK["workloads"]:
-        with open(os.path.join(BENCH, "workloads", w["name"] + ".json")) as f:
+    for w in bench["workloads"]:
+        with open(os.path.join(bench_dir, "workloads",
+                               w["name"] + ".json")) as f:
             told = json.load(f)
         assert {k: told[k] for k in ("config", "traffic", "chips")} == \
             {k: w[k] for k in ("config", "traffic", "chips")}
         assert told["why"] and told["who"] and told["sizing"]
-        with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
+        with open(os.path.join(bench_dir, "traffic",
+                               w["traffic"] + ".json")) as f:
             kind = json.load(f)["kind"]
-        assert os.path.exists(os.path.join(BENCH, "kinds", kind + ".py"))
-    for m in BENCHMARK["per_layer"]:
-        assert os.path.exists(os.path.join(BENCH, "metrics",
+        assert os.path.exists(os.path.join(bench_dir, "kinds", kind + ".py"))
+    for m in bench["per_layer"]:
+        assert os.path.exists(os.path.join(bench_dir, "metrics",
                                            m["name"] + ".py")), m["name"]
-    for dirpath, _dirs, names in os.walk(BENCH):
+    for dirpath, _dirs, names in os.walk(bench_dir):
         if "__pycache__" in dirpath:
             continue
         for n in names:
             assert re.match(r"^[A-Za-z0-9_.\-]+$", n), n
+
+
+def test_files_are_where_the_names_say():
+    check_files(BENCHMARK, ROOT)
 
 
 def test_published_sizes():

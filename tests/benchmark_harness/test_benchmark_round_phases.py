@@ -1,6 +1,6 @@
 """The three metrics of the serving engine's round phases against a
 hand-made `obs["stats"]`: what each computes, that each is declared for the
-serve cell, and that a program without the counters (the parent commit of
+serve cells, and that a program without the counters (the parent commit of
 the PR that added them) makes them read nothing, not raise."""
 import json
 import os
@@ -8,10 +8,20 @@ import os
 import pytest
 
 from bench_paths import ROOT
-from run import load_plugin, metrics_of
+from run import load_plugin
 
-NAMES = ("host_ms_per_dispatch.serve", "device_wait_share_pct.serve",
-         "longest_round_ms.serve")
+# name: (unit, better, the end-to-end metric it moves)
+DECLARED = {"host_ms_per_dispatch.serve": ("ms", "lower",
+                                           "serve_tokens_per_s"),
+            "device_wait_share_pct.serve": ("%", "higher",
+                                            "serve_tokens_per_s"),
+            "longest_round_ms.serve": ("ms", "lower", "itl_p95_ms")}
+NAMES = tuple(DECLARED)
+# the cells whose chip runs give the three readers a number
+SERVE_CELLS = ("gpt2_medium.serve_closed32",
+               "kimi_linear_48b_ep2.serve_decode128",
+               "zaya1_8b_l16.serve_reason128", "brumby_14b_l8.serve_decode16",
+               "kanana2_30b_l6.serve_longdoc64")
 SECONDS = {"admit": 0.5, "idle_wait": 2.0, "plan": 1.0, "dispatch": 1.5,
            "read_back": 40.0, "emit": 0.75, "other": 0.25}
 
@@ -71,17 +81,24 @@ def test_a_program_without_the_counters_reads_nothing(name):
     assert load_plugin("metrics", name).read(obs_of(None)) is None
 
 
+def check_declared(bench):
+    """What each entry must hold, wherever it stands in the list: its
+    fields, every serve cell of SERVE_CELLS, and only cells that report
+    the end-to-end metric it moves, so no train cell.  A later PR appends
+    entries after them and its own cells to their `workloads`."""
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    train = set(e2e["train_tokens_per_s"]["workloads"])
+    for name, (unit, better, moves) in DECLARED.items():
+        (m,) = [m for m in bench["per_layer"] if m["name"] == name]
+        assert (m["unit"], m["better"], m["moves"]) == (unit, better, moves)
+        assert m["source"] == "program_counter"
+        assert m["layer"] == "serving engine"
+        assert set(SERVE_CELLS) <= set(m["workloads"])
+        assert set(m["workloads"]) <= set(e2e[moves]["workloads"])
+        assert not set(m["workloads"]) & train
+
+
 def test_the_serve_cell_declares_them():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    mine = {m["name"]: m for m in
-            metrics_of(bench, "per_layer", "gpt2_medium.serve_closed32")}
-    for name in NAMES:
-        m = mine[name]
-        assert m["source"] == "program_counter"
-        assert m["layer"] == "serving engine"
-        assert m["workloads"] == ["gpt2_medium.serve_closed32"]
-    assert mine["longest_round_ms.serve"]["moves"] == "itl_p95_ms"
-    for cell in ("gpt2_medium.train", "bert_large.train"):
-        assert not set(NAMES) & {m["name"] for m in
-                                 metrics_of(bench, "per_layer", cell)}
+    check_declared(bench)

@@ -9,11 +9,12 @@ import sys
 import pytest
 
 from bench_paths import ROOT
-from run import load_plugin, metrics_of
+from run import load_plugin
 
 SERVE_CELLS = ["gpt2_medium.serve_closed32",
                "kimi_linear_48b_ep2.serve_decode128",
-               "zaya1_8b_l16.serve_reason128", "brumby_14b_l8.serve_decode16"]
+               "zaya1_8b_l16.serve_reason128", "brumby_14b_l8.serve_decode16",
+               "kanana2_30b_l6.serve_longdoc64"]
 TRAIN_CELLS = ["gpt2_medium.train", "bert_large.train"]
 PHASES = ("admit", "idle_wait", "plan", "dispatch", "read_back", "emit",
           "other")
@@ -132,7 +133,7 @@ def test_loader_wait_max_ms_reads_the_windows_waits(fake_loader):
                  "gap_s": [0.0, 20.0, 0.169, 0.168, 0.170, 2.45, 0.169],
                  "wait_max_s": 4.2, "gap_max_s": 20.0})
     lines = []
-    obs = {"step_s": [0.169] * 5, "log": lines.append}
+    obs = {"data_wait_s": [0.0004] * 5, "log": lines.append}
     assert read(obs) == pytest.approx(2.1)
     (line,) = lines
     assert "7 batches" in line and "last 5" in line
@@ -164,29 +165,34 @@ def test_a_program_without_the_counters_reads_nothing(fake_loader):
         assert load_plugin("metrics", name).read(obs_of(parent)) is None
     fake_loader(None)                           # io without loader_stats
     assert load_plugin("metrics", "loader_wait_max_ms.train").read(
-        {"step_s": [0.1], "log": lambda _line: None}) is None
+        {"data_wait_s": [0.0004], "log": lambda _line: None}) is None
 
 
-@pytest.mark.parametrize("name, cells, layer, moves, unit", [
+DECLARED = [
     ("device_found_idle_pct.serve", SERVE_CELLS, "serving engine",
      "serve_tokens_per_s", "%"),
     ("round_p99_ms.serve", SERVE_CELLS, "serving engine", "itl_p95_ms",
      "ms"),
     ("loader_wait_max_ms.train", TRAIN_CELLS, "input pipeline",
-     "train_tokens_per_s", "ms")])
+     "train_tokens_per_s", "ms")]
+
+
+def check_declared(bench, name, cells, layer, moves, unit):
+    """What the entry must hold, wherever it stands in the list: its
+    fields, every cell of `cells`, and no cell that does not report the
+    end-to-end metric it moves.  A later PR appends entries after it and
+    its own cells to its `workloads`."""
+    (m,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert {k: v for k, v in m.items() if k != "workloads"} == {
+        "name": name, "unit": unit, "better": "lower",
+        "source": "program_counter", "layer": layer, "moves": moves}
+    reporting = next(e for e in bench["end_to_end"]
+                     if e["name"] == moves)["workloads"]
+    assert set(cells) <= set(m["workloads"]) <= set(reporting)
+
+
+@pytest.mark.parametrize("name, cells, layer, moves, unit", DECLARED)
 def test_the_cells_declare_them(name, cells, layer, moves, unit):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    (m,) = [m for m in bench["per_layer"] if m["name"] == name]
-    assert m == {"name": name, "unit": unit, "better": "lower",
-                 "source": "program_counter", "layer": layer,
-                 "moves": moves, "workloads": cells}
-    # appended: the three are the list's last entries
-    assert m in bench["per_layer"][-3:]
-    reporting = next(e for e in bench["end_to_end"]
-                     if e["name"] == moves)["workloads"]
-    assert set(cells) <= set(reporting)
-    for cell in [w["name"] for w in bench["workloads"]]:
-        listed = name in {x["name"] for x in
-                          metrics_of(bench, "per_layer", cell)}
-        assert listed == (cell in cells)
+    check_declared(bench, name, cells, layer, moves, unit)
